@@ -4,7 +4,14 @@ Reference: `examples/cnn/benchmark.py` — the script that DEFINES the
 reference's headline metric (ResNet-50 images/sec/chip on synthetic
 ImageNet shapes), scaling across DistOpt ranks.
 
-Prints per-step timings and the steady-state throughput.
+Prints per-step timings and the steady-state throughput, named with
+the platform and device kind it ran on. `--dist` wraps the optimizer
+in `opt.DistOpt`, which in ONE process is an identity fence by design
+(`dist/communicator.py`): on a four-chip host it still trains on chip
+0 only. One process drives four chips through
+`Model.compile(plan=ParallelPlan(data=4))` (`parallel/trainer.py`);
+`--dist` is for one-process-per-rank launches (`train_mpi.py`,
+`train_multiprocess.py`).
 """
 import argparse
 import json
@@ -27,6 +34,7 @@ def run(depth=50, batch_size=32, steps=20, warmup=5, image_size=224,
 
     import jax
 
+    device.use_compile_cache()
     dev = device.create_tpu_device()
     dev.SetRandSeed(0)
     if precision == "bf16":
@@ -73,8 +81,10 @@ def run(depth=50, batch_size=32, steps=20, warmup=5, image_size=224,
     med = sorted(times)[len(times) // 2]
     ips = batch_size / med
     if verbose:
+        d = dev.jax_device
         print(f"ResNet-{depth} bs={batch_size} {image_size}x{image_size} "
-              f"{precision}: {ips:.1f} images/sec/chip")
+              f"{precision}: {ips:.1f} images/sec on {d.platform} "
+              f"({d.device_kind})")
     return ips
 
 
@@ -94,5 +104,10 @@ if __name__ == "__main__":
               use_graph=a.graph, precision=a.precision, dist=a.dist,
               verbose=not a.json)
     if a.json:
-        print(json.dumps({"metric": f"resnet{a.depth}_images_per_sec_chip",
-                          "value": round(ips, 2), "unit": "img/s"}))
+        import jax
+
+        d = jax.devices()[0]
+        print(json.dumps({"metric": f"resnet{a.depth}_images_per_sec",
+                          "value": round(ips, 2), "unit": "img/s",
+                          "platform": d.platform,
+                          "device_kind": d.device_kind}))

@@ -135,7 +135,14 @@ if __name__ == "__main__":
     p.add_argument("--graph", action="store_true", default=True)
     p.add_argument("--no-graph", dest="graph", action="store_false")
     p.add_argument("--precision", choices=["fp32", "bf16"], default="fp32")
-    p.add_argument("--dist", action="store_true")
+    p.add_argument("--dist", action="store_true",
+                   help="wrap the optimizer in opt.DistOpt: for "
+                   "one-process-per-rank launches (train_mpi.py, "
+                   "train_multiprocess.py). In ONE process DistOpt is "
+                   "an identity fence — on a four-chip host this flag "
+                   "still trains on chip 0 only; one process drives "
+                   "four chips through Model.compile(plan="
+                   "ParallelPlan(data=4))")
     p.add_argument("--dist-option", default="plain",
                    choices=["plain", "half", "partialUpdate",
                             "sparseTopK", "sparseThreshold"])

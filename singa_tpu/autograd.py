@@ -1682,11 +1682,13 @@ class Attention(Operator):
         # VMEM) for SELF-attention (the kernel assumes Sq == Sk) whose
         # K/V fit the residency budget; cross-attention and longer
         # sequences keep the XLA / ring paths.
+        prec = tensor_mod.get_matmul_precision()
         if (_pk.enabled() and q.shape[2] == k.shape[2]
                 and _pk.attn_supported(q.shape[2], q.shape[3])):
-            return _pk.flash_attention(q, k, v, self.causal, self.scale)
+            return _pk.flash_attention(q, k, v, self.causal, self.scale,
+                                       prec)
         return plain_attention(q, k, v, causal=self.causal,
-                               scale=self.scale)
+                               scale=self.scale, precision=prec)
 
 
 # ---- stateful-ish NN ops --------------------------------------------------

@@ -24,9 +24,12 @@ graphs warm-start too), (b) the abstract argument signature
 key), (c) a snapshot of every step-affecting knob — remat policy, slot
 dtype, BN-stats dtype, grad-accum geometry, step guard, loss scaling,
 XLA profile, AMP compute dtype, matmul precision, optimizer
-hyperparameters — and (d) the platform: jax version, backend, device
-kind, device count, plus mesh extras for sharded steps. A knob change
-changes the key; a stale artifact can never load.
+hyperparameters — (d) the platform: jax version, backend, device
+kind, device count, plus mesh extras for sharded steps — and (e) a
+digest of the `singa_tpu` package's own source files
+(`package_digest`): the op lowerings and the optimizer update are
+framework code, so an edit there is a miss too. A knob change changes
+the key; a stale artifact can never load.
 
 Integrity: every artifact gets a digest manifest sidecar (sha256 +
 size, the `checkpoint.CheckpointManager` idiom). A corrupt/truncated
@@ -52,6 +55,7 @@ arms the bucketing policy (each works without the other).
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -419,6 +423,29 @@ def _args_signature(args) -> Dict:
     return {"tree": str(treedef), "leaves": [leaf(x) for x in leaves]}
 
 
+_PACKAGE_DIR = os.path.dirname(os.path.abspath(__file__))
+
+
+@functools.lru_cache(maxsize=None)
+def package_digest(root: str = _PACKAGE_DIR) -> str:
+    """sha256 over every `.py` file under `root` (relative path +
+    bytes, sorted walk) — the framework half of the export key. Op
+    lowerings, the optimizer update and the step wrappers live in the
+    package, not in the user's model class, so an edit to any of them
+    must be a store miss. Memoized per process: the files a process
+    imported do not change under it."""
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = sorted(d for d in dirnames if d != "__pycache__")
+        for name in sorted(filenames):
+            if name.endswith(".py"):
+                path = os.path.join(dirpath, name)
+                h.update(os.path.relpath(path, root).encode())
+                with open(path, "rb") as f:
+                    h.update(f.read())
+    return h.hexdigest()
+
+
 def step_key(model, opt, kind: str, args,
              extras=None) -> Tuple[str, Dict]:
     """(sha256 hex key, human-readable parts) for one executable.
@@ -428,21 +455,14 @@ def step_key(model, opt, kind: str, args,
     training flag + statics for forwards)."""
     import jax
 
-    dev_kind = ""
-    try:
-        d = jax.devices()[0]
-        dev_kind = f"{d.platform}/{getattr(d, 'device_kind', '')}"
-    except Exception:
-        pass
-    from . import __version__ as singa_version
-
+    d = jax.devices()[0]
+    dev_kind = f"{d.platform}/{d.device_kind}"
     parts = {
         "schema": SCHEMA,
-        # framework version rides the key: op lowerings live in
-        # singa_tpu, not the user model, so an upgrade must orphan the
-        # store. (A dev-install edit without a version bump is the
-        # residual risk — bump SCHEMA or GC the store for those.)
-        "singa_tpu": singa_version,
+        # the framework's own source rides the key (not a version
+        # string nobody bumps): an artifact exported by an older
+        # autograd/opt/ops can never load under the edited code
+        "singa_tpu": package_digest(),
         "kind": kind,
         "model": model.topology_fingerprint(),
         "model_class": type(model).__qualname__,

@@ -744,6 +744,38 @@ def _repo_root() -> str:
     return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def _worker_env() -> Dict[str, str]:
+    """The environment a local worker subprocess starts in: the
+    parent's, plus PYTHONPATH, plus the platform pin.
+
+    One process per chip: a process that has initialised jax on the
+    TPU holds it, and a worker that needs the same device would fail
+    or hang until `spawn_timeout_s`. So a parent that holds the TPU is
+    refused here, at once. A parent that forced a platform through
+    `jax.config` (tier-1 forces cpu) hands it on as JAX_PLATFORMS —
+    children cannot inherit a config update — and asking the config
+    creates no backend, unlike `jax.default_backend()`."""
+    import jax
+
+    from .device import backend_initialized
+
+    if backend_initialized() and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "one process per chip: this process has initialised jax "
+            "on the TPU and holds it, so a ProcReplica worker could "
+            "never get the device. On a TPU host run ONE process with "
+            "transport='engine' — one EngineReplica per device "
+            "(device.create_replica_device(i)). Worker subprocesses "
+            "are for a parent that stays off the TPU (JAX_PLATFORMS="
+            "cpu mechanics runs).")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (_repo_root() + os.pathsep
+                         + env.get("PYTHONPATH", ""))
+    if not env.get("JAX_PLATFORMS") and jax.config.jax_platforms:
+        env["JAX_PLATFORMS"] = jax.config.jax_platforms
+    return env
+
+
 def resolve_factory(spec: Dict):
     """Import the spec's "module:callable" factory (after inserting
     its `sys_path` entries) — the one resolution both transports and
@@ -1024,27 +1056,13 @@ class ProcReplica:
                 # deserializes from the same artifacts the parent
                 # prewarmed
                 spec["export_cache"] = export_cache.directory()
-            env = dict(os.environ)
-            root = _repo_root()
-            env["PYTHONPATH"] = (root + os.pathsep
-                                 + env.get("PYTHONPATH", ""))
-            if not env.get("JAX_PLATFORMS"):
-                # tier-1 hermeticity: the worker must land on the
-                # SAME backend as the parent even when the env var is
-                # unset (the parent may have forced cpu via
-                # jax.config, which children cannot inherit)
-                try:
-                    import jax
-
-                    env["JAX_PLATFORMS"] = jax.default_backend()
-                except Exception:
-                    pass
+            env = _worker_env()
             if spec.get("export_cache"):
                 env["SINGA_TPU_EXPORT_CACHE"] = spec["export_cache"]
             env["SINGA_TPU_FLEET_SPEC"] = json.dumps(spec)
             self._proc = subprocess.Popen(
                 [self._python, "-m", "singa_tpu.fleet_worker"],
-                env=env, cwd=root, stdout=subprocess.DEVNULL)
+                env=env, cwd=_repo_root(), stdout=subprocess.DEVNULL)
             lsock.settimeout(self.spawn_timeout_s)
             try:
                 conn, _ = lsock.accept()
@@ -1211,17 +1229,7 @@ class ProcReplica:
         no spec in its env, so the WELCOME spec-shipping path is
         exercised on every hermetic run — plus the env hygiene any
         launch recipe needs (PYTHONPATH, backend pin, store dir)."""
-        env = dict(os.environ)
-        root = _repo_root()
-        env["PYTHONPATH"] = (root + os.pathsep
-                             + env.get("PYTHONPATH", ""))
-        if not env.get("JAX_PLATFORMS"):
-            try:
-                import jax
-
-                env["JAX_PLATFORMS"] = jax.default_backend()
-            except Exception:
-                pass
+        env = _worker_env()
         store = self.spec.get("export_cache") or export_cache.directory()
         if store:
             env["SINGA_TPU_EXPORT_CACHE"] = store
@@ -1231,7 +1239,7 @@ class ProcReplica:
             [self._python, "-m", "singa_tpu.fleet_worker",
              "--connect", f"{host}:{port}", "--token", self._token,
              "--name", self.name],
-            env=env, cwd=root, stdout=subprocess.DEVNULL)
+            env=env, cwd=_repo_root(), stdout=subprocess.DEVNULL)
 
     def _accept_loop(self, lsock: socket.socket) -> None:
         while self._lsock is lsock:

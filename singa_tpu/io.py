@@ -32,14 +32,21 @@ _lib_lock = threading.Lock()
 
 
 def _load() -> ctypes.CDLL:
-    """Load (building if needed) the native runtime."""
+    """Build and load the native runtime. `make` runs on every first
+    load, not only when the library is absent: it is incremental (a
+    no-op when `native/src` is unchanged), and it is what makes the
+    loaded `.so` the product of the tracked sources rather than a
+    stale file left on this disk."""
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            subprocess.run(["make"], cwd=_NATIVE_DIR, check=True,
-                           capture_output=True)
+        made = subprocess.run(["make"], cwd=_NATIVE_DIR,
+                              capture_output=True, text=True)
+        if made.returncode != 0:
+            raise RuntimeError(
+                f"building {_LIB_PATH} failed (make exit "
+                f"{made.returncode}):\n{made.stderr}")
         lib = ctypes.CDLL(_LIB_PATH)
         lib.st_writer_open.restype = ctypes.c_void_p
         lib.st_writer_open.argtypes = [ctypes.c_char_p, ctypes.c_char_p]

@@ -1479,9 +1479,15 @@ class _JitStep:
 
         def zeros(name, p):
             # honors the optimizer's slot_dtype policy (byte diet):
-            # half-width slots enter the jit signature half-width
+            # half-width slots enter the jit signature half-width.
+            # Born beside its param: an uncommitted jnp.zeros lives on
+            # jax's default device, and (the step's outputs being
+            # committed) gave step 2 a different jit signature from
+            # step 1 — the whole step compiled twice (29 s of a cold
+            # ResNet-50 start on the v5e).
             return jnp.zeros(p.data.shape,
-                             base.slot_store_dtype(name, p))
+                             base.slot_store_dtype(name, p),
+                             device=p.data.sharding)
 
         for p in self.params:
             st = base.states.setdefault(id(p), {})

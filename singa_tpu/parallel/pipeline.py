@@ -52,11 +52,10 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import stats as stats_mod
-from ._compat import _CHECK_KW, shard_map
 
 SCHEDULES = ("gpipe", "1f1b")
 
@@ -239,7 +238,7 @@ def _gpipe_apply(stage_fn, stacked_params, x, mesh, axis_name, m,
         _forward_per_chip(stage_fn, axis_name, pipe, m), mesh=mesh,
         in_specs=(pspec, xspec),
         out_specs=xspec,
-        **_CHECK_KW,
+        check_vma=False,
     )
     return fn(stacked_params, x)
 
@@ -266,7 +265,7 @@ def _build_1f1b(stage_fn, mesh, axis_name, m, batch_axis=None):
         fn = shard_map(
             _forward_per_chip(stage_fn, axis_name, pipe, m),
             mesh=mesh, in_specs=(pspec, xspec), out_specs=xspec,
-            **_CHECK_KW)
+            check_vma=False)
         return fn(params, x)
 
     def bwd_combined(params, x, gy):
@@ -348,7 +347,7 @@ def _build_1f1b(stage_fn, mesh, axis_name, m, batch_axis=None):
             per_chip, mesh=mesh,
             in_specs=(pspec, xspec, xspec),
             out_specs=(pspec, xspec),
-            **_CHECK_KW)
+            check_vma=False)
         return fn(params, x, gy)
 
     @jax.custom_vjp

@@ -26,10 +26,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ._compat import _CHECK_KW, shard_map
 
 
 def _neg_big(dtype):
@@ -77,18 +75,20 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
 
 
 def plain_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None, precision=None):
     """Single-device reference semantics (and the <2-way-SP fallback).
-    q,k,v: [B, H, S, D]."""
+    q,k,v: [B, H, S, D]. `precision` is the einsums' MXU precision
+    (`autograd.Attention` passes the framework's matmul policy; None
+    is jax's default, one bf16 pass for float32 on the TPU)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision=precision) * scale
     if causal:
         Sq, Sk = s.shape[-2], s.shape[-1]
         mask = jnp.arange(Sq)[:, None] >= jnp.arange(Sk)[None, :]
         s = jnp.where(mask, s, _neg_big(s.dtype))
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision=precision)
 
 
 def ring_attention(q, k, v, mesh: Mesh, *, axis_name: str = "seq",
@@ -120,6 +120,6 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis_name: str = "seq",
         partial(_ring_attention_local, axis_name=axis_name,
                 causal=causal, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        **_CHECK_KW,
+        check_vma=False,
     )
     return fn(q, k, v)

@@ -233,7 +233,7 @@ class ShardedJitStep(_JitStep):
         import jax.numpy as jnp
 
         from ..model import _bound_model
-        from ._compat import _CHECK_KW, shard_map
+        from jax import shard_map
 
         if not self._accum_pure_dp(n, batch):
             return super()._accum_step(n, pvals, svals, ovals, key,
@@ -387,7 +387,7 @@ class ShardedJitStep(_JitStep):
             in_specs=(P(), P(), P(), P(), P())
             + tuple(P(ax) for _ in batch),
             out_specs=(outs_specs, P(), P(), P(), P()),
-            **_CHECK_KW)
+            check_vma=False)
         return fn(pvals, svals, ovals, key, step_counter, *batch)
 
     # -- AOT export cache (ISSUE 6) ----------------------------------------

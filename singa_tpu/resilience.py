@@ -372,7 +372,7 @@ stats_mod.register_cache("resilience", _ResilienceStats())
 # Fault injection: deterministic, seed-keyed.
 # ---------------------------------------------------------------------------
 class DeviceLostError(RuntimeError):
-    """Simulated device/tunnel loss (the PJRT dial dying mid-run)."""
+    """Simulated device loss (the PJRT client dying mid-run)."""
 
 
 class FaultInjector:

@@ -1002,6 +1002,7 @@ class ServingEngine:
         self._decode_thread: Optional[threading.Thread] = None
         self._decode_running = False
         self._slab = None               # pooled KV cache, built lazily
+        self._slab_put = None           # places step inputs beside it
         self._slab_free: List[int] = []  # free slab row indices
         self._decode_params = None
         self._decode_quant = quant_mod.mode()  # frozen at slab build
@@ -1417,7 +1418,6 @@ class ServingEngine:
         pad prefill rows carry an out-of-bounds slot, so nothing is
         written. Returns the number of executables warmed."""
         import jax
-        import jax.numpy as jnp
 
         n_new = int(max_new_tokens if max_new_tokens is not None
                     else self.decode_max_new)
@@ -1443,8 +1443,9 @@ class ServingEngine:
         model = self.model
         Sb = int(self._slab_dims()[1])
         warmed = 0
-        tok = jnp.zeros(Sb, jnp.int32)
-        pos = jnp.zeros(Sb, jnp.int32)
+        put = self._slab_put
+        tok = put(np.zeros(Sb, np.int32))
+        pos = put(np.zeros(Sb, np.int32))
         lg, _ = model.decode_step(params, self._slab, tok, pos)
         np.asarray(lg)
         warmed += 1
@@ -1453,7 +1454,7 @@ class ServingEngine:
             if t == 0.0:
                 continue  # greedy is an argmax on host, nothing to warm
             key, sub = jax.random.split(jax.random.PRNGKey(0))
-            np.asarray(model.sample_fn(t, k)(jnp.asarray(lg[0:1]), sub))
+            np.asarray(model.sample_fn(t, k)(lg[0:1], sub))
             warmed += 1
         ks = set()
         k = 2
@@ -1473,9 +1474,10 @@ class ServingEngine:
         bb = 1
         while bb <= bmax:
             for pb in pbs:
-                ids = jnp.zeros((bb, pb), jnp.int32)
-                nv = jnp.ones(bb, jnp.int32)
-                sv = jnp.full(bb, Sb, jnp.int32)  # OOB: writes nothing
+                ids = put(np.zeros((bb, pb), np.int32))
+                nv = put(np.ones(bb, np.int32))
+                sv = put(np.full(bb, Sb, np.int32))  # OOB: writes
+                # nothing
                 lg, _ = model.prefill_slab(params, self._slab, ids,
                                            nv, sv)
                 np.asarray(lg)
@@ -1754,13 +1756,24 @@ class ServingEngine:
               if self.max_sessions <= self.policy.max_batch
               else _pow2_ceil(self.max_sessions))
         Tslab = self._slab_seq_bucket(need_t)
+        # born ON the engine's device, and every step input placed
+        # beside it (`_slab_put`): an uncommitted jnp.zeros/asarray
+        # lands on jax's default device (chip 0), which on a multi-chip
+        # host is not where replica i's params live — and it gives the
+        # live path (committed slab out of the previous step) another
+        # jit signature than the one warm_decode compiled
+        device = self._device()
+        self._slab_put = device.put
+        dev = device.jax_device
         if self._decode_quant == "int8":
-            self._slab = [(jnp.zeros((2, Sb, H, Tslab, D), jnp.int8),
-                           jnp.zeros((2, Sb, Tslab), jnp.float32))
+            self._slab = [(jnp.zeros((2, Sb, H, Tslab, D), jnp.int8,
+                                     device=dev),
+                           jnp.zeros((2, Sb, Tslab), jnp.float32,
+                                     device=dev))
                           for _ in range(L)]
         else:
-            self._slab = [jnp.zeros((2, Sb, H, Tslab, D),
-                                    embed.dtype)
+            self._slab = [jnp.zeros((2, Sb, H, Tslab, D), embed.dtype,
+                                    device=dev)
                           for _ in range(L)]
         self._slab_free = list(range(Sb))
         self._decode_params = params
@@ -2041,7 +2054,6 @@ class ServingEngine:
         fused dispatch itself fails the whole cohort (the batch shares
         one program) but never the sessions already streaming."""
         import jax
-        import jax.numpy as jnp
 
         model = self.model
         params = geom[0]
@@ -2094,10 +2106,10 @@ class ServingEngine:
             nvec[r] = len(row)
             slotv[r] = slot
         t0 = time.perf_counter()
+        put = self._slab_put
         try:
             logits, new_slab = model.prefill_slab(
-                params, self._slab, jnp.asarray(ids),
-                jnp.asarray(nvec), jnp.asarray(slotv))
+                params, self._slab, put(ids), put(nvec), put(slotv))
             lg = np.asarray(logits)
         except BaseException as e:  # noqa: BLE001 — isolate: a failed
             # cohort dispatch fails ITS members, never the sessions
@@ -2145,7 +2157,7 @@ class ServingEngine:
                 sampler = model.sample_fn(sess.temperature,
                                           sess.top_k)
                 tok = int(np.asarray(
-                    sampler(jnp.asarray(lg[r:r + 1]), sub))[0])
+                    sampler(put(lg[r:r + 1]), sub))[0])
             sess.slot = slot
             sess.tok = tok
             sess.pos = P
@@ -2204,12 +2216,12 @@ class ServingEngine:
         dispatch recomputes from the UNCHANGED slab, so a delivered
         stream is never torn or duplicated."""
         import jax
-        import jax.numpy as jnp
 
         from . import resilience
 
         model = self.model
         params = geom[0]
+        put = self._slab_put
         Sb = int(self._slab_dims()[1])
         tokv = np.zeros(Sb, np.int32)
         posv = np.zeros(Sb, np.int32)
@@ -2231,14 +2243,12 @@ class ServingEngine:
                         f"injected decode step failure (step {idx})")
                 if k == 1:
                     logits, new_slab = model.decode_step(
-                        params, self._slab, jnp.asarray(tokv),
-                        jnp.asarray(posv))
+                        params, self._slab, put(tokv), put(posv))
                     lg = np.asarray(logits)  # completes the dispatch
                     toks = None
                 else:
                     toks_j, new_slab = model.decode_scan(
-                        params, self._slab, jnp.asarray(tokv),
-                        jnp.asarray(posv), k)
+                        params, self._slab, put(tokv), put(posv), k)
                     toks = np.asarray(toks_j)  # [k, Sb]
                 break
             except BaseException as e:  # noqa: BLE001 — retry below
@@ -2283,7 +2293,7 @@ class ServingEngine:
                 sampler = model.sample_fn(sess.temperature,
                                           sess.top_k)
                 seq = [int(np.asarray(
-                    sampler(jnp.asarray(lg[slot:slot + 1]), sub))[0])]
+                    sampler(put(lg[slot:slot + 1]), sub))[0])]
             for tok in seq:
                 sess.toks.append(tok)
                 sess.reply._push_token(tok)

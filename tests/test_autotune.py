@@ -306,6 +306,18 @@ def test_corrupt_store_reads_empty_never_crashes(tmp_path, capsys):
     assert store.get(alias="x") is not None
 
 
+def test_unknown_device_kind_has_no_chip_spec():
+    """A device kind with no CHIP_SPECS row is an error, never an
+    assumed v5e; the kinds jax reports for known chips resolve."""
+    assert tuning.normalize_chip("TPU v5 lite") == "v5e"
+    assert tuning.normalize_chip("tpu TPU v5 lite") == "v5e"
+    assert tuning.normalize_chip("cpu cpu") == "cpu"
+    with pytest.raises(ValueError, match="no CHIP_SPECS row"):
+        tuning.normalize_chip("TPU v9 mega")
+    with pytest.raises(ValueError, match="no CHIP_SPECS row"):
+        tuning.normalize_chip("")
+
+
 def test_load_best_resolves_current_chip(tmp_path, monkeypatch):
     path = str(tmp_path / "tuned.json")
     monkeypatch.setenv("SINGA_TPU_TUNED_STORE", path)

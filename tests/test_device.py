@@ -1,6 +1,8 @@
 """Device/Platform tests. Reference model: `test_platform.cc` +
 `python/singa/device.py` surface."""
+import jax
 import numpy as np
+import pytest
 
 from singa_tpu import device, tensor
 
@@ -18,6 +20,50 @@ def test_create_accel_device():
     assert d.lang == "tpu"
     t = tensor.from_numpy(np.ones((2, 2), np.float32), device=d)
     np.testing.assert_array_equal(t.to_numpy(), np.ones((2, 2)))
+
+
+def test_cpu_is_handed_out_only_when_asked_for(monkeypatch):
+    """No fallback that hides the device: `create_tpu_device()` & co.
+    return CPU devices only when the CPU is the platform jax was told
+    to use first (conftest does that); when jax merely FELL BACK to
+    the CPU — the TPU runtime failed to start — they raise, naming
+    how to ask for the CPU on purpose."""
+    assert device._cpu_requested()  # conftest: jax_platforms="cpu"
+    assert len(device._accel_devices()) == 8  # the virtual devices
+    before = jax.config.jax_platforms
+    try:
+        for asked, is_cpu in (("cpu", True), ("cpu,tpu", True),
+                              ("tpu,cpu", False), ("tpu", False),
+                              ("", False)):
+            jax.config.update("jax_platforms", asked)
+            assert device._cpu_requested() is is_cpu, asked
+    finally:
+        jax.config.update("jax_platforms", before)
+    # jax fell back to the CPU unasked: nothing named a platform and
+    # there is no TPU backend in this process
+    monkeypatch.setattr(device, "_cpu_requested", lambda: False)
+    for make in (device.create_tpu_device,
+                 lambda: device.create_replica_device(1),
+                 lambda: device.Platform.CreateTpuDevices(1),
+                 lambda: device.create_tpu_device_on(0)):
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            make()
+
+
+def test_importing_the_package_creates_no_backend():
+    """A process that has created a jax backend holds the chip, so the
+    parents that spawn chip children (bench.py main, stage_pallas,
+    stage_parity) may import the package but nothing more."""
+    import subprocess
+    import sys
+
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import singa_tpu\n"
+         "from singa_tpu import device, fleet_proc, serve, tuning\n"
+         "print(device.backend_initialized())"],
+        capture_output=True, text=True, timeout=120)
+    assert proc.stdout.strip() == "False", proc.stdout + proc.stderr
 
 
 def test_reference_alias_names():

@@ -8,10 +8,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from singa_tpu import autograd, opt, tensor
-from singa_tpu.parallel._compat import shard_map
 from singa_tpu.dist import Communicator, NcclIdHolder
 
 

@@ -194,6 +194,36 @@ def test_knob_change_orphans_artifact(tmp_path):
     assert s2["saves"] - s1["saves"] == 1
 
 
+def test_framework_source_edit_changes_the_key(tmp_path, monkeypatch):
+    """The op lowerings and the optimizer update live in the package,
+    not in the model class: a digest of the package's source files
+    rides the key, so an artifact the OLD framework code exported can
+    never load after an edit to autograd.py / opt.py / ops/."""
+    pkg = tmp_path / "pkg"
+    (pkg / "ops").mkdir(parents=True)
+    (pkg / "autograd.py").write_text("x = 1\n")
+    (pkg / "ops" / "native.py").write_text("y = 1\n")
+    (pkg / "notes.txt").write_text("not source\n")
+    d0 = export_cache.package_digest(str(pkg))
+    (pkg / "notes.txt").write_text("still not source\n")
+    export_cache.package_digest.cache_clear()
+    assert export_cache.package_digest(str(pkg)) == d0
+    (pkg / "ops" / "native.py").write_text("y = 2\n")
+    export_cache.package_digest.cache_clear()
+    d1 = export_cache.package_digest(str(pkg))
+    assert d1 != d0
+    export_cache.package_digest.cache_clear()
+
+    x, y = _data()
+    m, tx, ty = _build(x, y)
+    args = (tx.data, ty.data)
+    k0, parts = export_cache.step_key(m, m._optimizer, "step", args)
+    assert parts["singa_tpu"] == export_cache.package_digest()
+    monkeypatch.setattr(export_cache, "package_digest", lambda: d1)
+    k1, _ = export_cache.step_key(m, m._optimizer, "step", args)
+    assert k1 != k0
+
+
 def test_per_model_grad_accum_override_keys_the_artifact(tmp_path):
     """`Model.compile(grad_accum=n)` bakes a DIFFERENT program than
     the monolithic step even when the process knob says 1 — the two

@@ -468,6 +468,14 @@ def test_proc_decode_drain_migration_and_sigkill_replay(lm, tmp_path):
     s0 = stats.cache_stats()
     d0 = stats.decode_stats().snapshot()
     reps = _proc_replicas(2, _lm_spec())
+    # w0 is PACED (every decode dispatch sleeps 0.5 s, respawns
+    # included): a toy stream otherwise finishes in milliseconds, and
+    # "drained / killed MID-generation" is a race the test loses. (It
+    # used to win it only because the live path recompiled every
+    # warmed executable — PR 21.) w1 runs free, so its own two
+    # sessions are done — its slots free — when w0's arrive.
+    reps[0].spec["injector"] = {"schedule": {"decode_hang": 1.0},
+                                "hang_s": 0.5}
     router = fleet.FleetRouter(reps, max_failover_hops=2).start()
     try:
         assert router.warm_decode([2, 3, 5, 4], NEW + 8) >= 2
@@ -479,7 +487,8 @@ def test_proc_decode_drain_migration_and_sigkill_replay(lm, tmp_path):
                    for i, (p, c) in enumerate(zip(prompts, cfgs))]
         assert sorted(r.replica for r in replies) == \
             ["w0", "w0", "w1", "w1"]
-        _wait_streams(replies, 3)
+        _wait_streams([r for r in replies if r.replica == "w0"], 3)
+        _wait_streams([r for r in replies if r.replica == "w1"], NEW)
         router.drain("w0")
         for i, r in enumerate(replies):
             got = np.asarray(r.result(timeout=180))
@@ -495,8 +504,9 @@ def test_proc_decode_drain_migration_and_sigkill_replay(lm, tmp_path):
         k = [router.submit_decode(prompts[i], NEW, **cfgs[i],
                                   session_id=f"k{i}")
              for i in range(2)]
-        _wait_streams(k, 3)
-        victim = k[0].replica
+        live = next(s for s in k if s.replica == "w0")  # the paced one
+        _wait_streams([live], 3)
+        victim = live.replica
         by_name = {r.name: r for r in reps}
         by_name[victim].sigkill()  # discovered, not told
         for i in range(2):
@@ -504,7 +514,7 @@ def test_proc_decode_drain_migration_and_sigkill_replay(lm, tmp_path):
             np.testing.assert_array_equal(got, want[i])
             assert list(k[i]._stream) == [
                 int(t) for t in want[i][0, prompts[i].shape[1]:]]
-        assert k[0].hops >= 1 and k[0].replica != victim
+        assert live.hops >= 1 and live.replica != victim
 
         # the respawned generation re-ran warm_decode from the spec,
         # deserialize-only from the store gen-0 populated — probed

@@ -260,6 +260,29 @@ def test_proc_fleet_sigkill_failover_respawn_and_health(tmp_path):
                for s in snaps for g in s["generations"].values())
 
 
+def test_proc_replica_refuses_a_tpu_its_parent_holds(monkeypatch):
+    """One process per chip: a parent that has initialised jax on the
+    TPU holds it, so a worker could never get the device. The spawn
+    must fail AT ONCE with the rule and the supported shape — not run
+    into spawn_timeout_s — and start no process."""
+    import jax
+
+    assert device.backend_initialized()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for mode in ("spawn", "listen"):
+        rep = fleet_proc.ProcReplica("w0", _spec(), mode=mode,
+                                     spawn_timeout_s=120.0)
+        t0 = time.perf_counter()
+        with pytest.raises(RuntimeError, match="one process per chip"
+                           ) as ei:
+            rep.start()
+        assert time.perf_counter() - t0 < 5.0
+        assert "transport='engine'" in str(ei.value)
+        assert "EngineReplica" in str(ei.value)
+        assert rep._proc is None
+        rep.kill()
+
+
 def test_missed_heartbeats_eject_fail_closed():
     """A wedged worker stops heartbeating: the snapshot AGES and the
     router's existing stale ejection fires — missed heartbeat =>

@@ -31,11 +31,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.5 top-level spelling; 0.4.x keeps it in experimental
-    _enable_x64 = jax.enable_x64
-except AttributeError:
-    from jax.experimental import enable_x64 as _enable_x64
-
 from singa_tpu import autograd, tensor
 from singa_tpu.ops import native
 from singa_tpu.ops.rnn import RNNHandle
@@ -74,7 +69,7 @@ def _grad_check(make_op, arrays, diff=None, eps=1e-5, rtol=1e-4,
     old_training = autograd.training
     autograd.training = train
     try:
-        with _enable_x64():
+        with jax.enable_x64():
             arrays = [np.asarray(a, np.float64)
                       if np.issubdtype(np.asarray(a).dtype, np.floating)
                       else np.asarray(a) for a in arrays]

@@ -1,7 +1,7 @@
 """Summarize on-chip stage logs into a BASELINE-ready table.
 
-`tools/onchip_runner.sh` mirrors every stage attempt's output into
-`onchip_logs/<stage>.out` (append-only across attempts); this reads
+Reads `onchip_logs/<stage>.out` — one file per `bench.py --stage`,
+its output appended attempt after attempt — takes
 each file's LAST result-JSON line and prints one row per stage, ready
 to fold into BASELINE.md. A result with trailing non-JSON output
 after it (a later attempt that died before printing its result) is
@@ -80,7 +80,7 @@ def _stage_breakdown(r):
 
 def main():
     if not os.path.isdir(LOGS):
-        print("no onchip_logs/ yet — run tools/onchip_runner.sh first")
+        print("no onchip_logs/ — nothing to fold")
         return 1
     entries = []  # (stage, result-dict or None, stale)
     for name in sorted(os.listdir(LOGS)):
@@ -103,26 +103,14 @@ def main():
             else:
                 rows.append((stage, "no result line"))
             continue
-        # Probe-escalation observability (ISSUE 3): the driver counts
-        # probe deadline kills into the result JSON; surface them on
-        # whichever row carries them (notably the final driver table
-        # and the tpu_unreachable failure row).
-        pt = (f", probe_timeouts={r['probe_timeouts']}"
-              if "probe_timeouts" in r else "")
         if not r.get("ok", False) and "value" not in r:
-            rows.append((stage, f"FAILED: {r.get('error', r)}"
-                         + pt + mark))
+            rows.append((stage, f"FAILED: {r.get('error', r)}" + mark))
             continue
         if "metric" in r and "value" in r:
             # driver-level result table (bench.py _final_json)
             rows.append((stage,
                          f"{r['value']} {r.get('unit', '')}".strip()
-                         + f"  ({r['metric']}"
-                         + (f", {r['provenance']}"
-                            if r.get("provenance") else "")
-                         + (f", ERROR: {r['error']}"
-                            if r.get("error") else "")
-                         + f"{pt})" + mark))
+                         + f"  ({r['metric']})" + mark))
         elif "ips" in r:
             # byte-diet matrix columns render only when non-default,
             # so pre-matrix logs fold unchanged
