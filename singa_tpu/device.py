@@ -644,7 +644,6 @@ def set_remat_policy(policy, *names) -> None:
 
 
 def set_tracing(flag: bool = True, ring_capacity: Optional[int] = None,
-                profile_dir: Optional[str] = None,
                 ship_capacity: Optional[int] = None) -> None:
     """Toggle the span-based host tracer (`singa_tpu.trace`).
 
@@ -664,17 +663,17 @@ def set_tracing(flag: bool = True, ring_capacity: Optional[int] = None,
     aligned timeline (see README "Fleet observability").
     NOTE: enabling adds a device sync per graph-mode step (the
     device_sync span needs a fence to mean anything) — leave it off
-    for peak-throughput runs. `ring_capacity` resizes the span ring
-    (default 16384 spans); `profile_dir` is where
-    `trace.profile_steps(n)` writes `jax.profiler` device traces;
-    `ship_capacity` bounds the cross-process span ship-back buffer a
+    for peak-throughput runs. Enabled spans are also
+    `jax.profiler.TraceAnnotation`s ("singa:<name>"): any profiler
+    session (`jax.profiler.start_trace`) shows them on the host's
+    threads under the device's operations. `ring_capacity` resizes the
+    span ring (default 16384 spans); `ship_capacity` bounds the cross-process span ship-back buffer a
     fleet WORKER drains into reply/heartbeat frames (0 = off, the
     default — overflow drops oldest, counted `ship_dropped`).
     Counters: `cache_stats()["trace"]`."""
     from . import trace
 
     trace.configure(enabled=flag, ring_capacity=ring_capacity,
-                    profile_dir=profile_dir,
                     ship_capacity=ship_capacity)
 
 

@@ -86,7 +86,11 @@ LOUD recovery path, proven by seed-keyed fault injection:
 Observability: per-request spans thread the PR 5 tracer (`queue_wait`
 via `trace.record_span` — it crosses threads — plus per-dispatch
 `batch_assemble` / `dispatch` / `reply` and per-retry
-`dispatch_retry` spans), a `MetricsLogger` JSONL stream records one
+`dispatch_retry` spans; the decode tier's dispatcher cycle is ten
+leaf spans that never overlap — `decode.wait_work`, `decode.admit`,
+`decode.{prefill,step}.{assemble,dispatch,readback,scatter}` — around
+the `prefill` / `decode_step` records, plus one `decode_queue_wait`
+per admitted session), a `MetricsLogger` JSONL stream records one
 record per dispatch (batch occupancy, pad fraction, rolling
 p50/p95/p99, cumulative expired/shed/retries/failed), and
 `cache_stats()["serve"]` exposes queue depth, coalesce sizes, the
@@ -1904,44 +1908,55 @@ class ServingEngine:
             if not running:
                 return  # stop() fails the remaining sessions
             if not has_work:
-                self._decode_have_work.wait(0.05)
-                self._decode_have_work.clear()
+                with trace_mod.span("decode.wait_work"):
+                    self._decode_have_work.wait(0.05)
+                    self._decode_have_work.clear()
                 continue
-            self._decode_expire(dst)
-            # -- resume fast path: transplant migrated KV rows first
-            # (a resumed session re-joins WITHOUT a prefill dispatch)
-            if self._decode_admit_imports(dst):
-                geom = self._decode_geom()
-            # -- admit: ONE cohort prefill dispatch, bounded per cycle
-            cohort = []
-            while len(cohort) < self.prefill_batch:
-                with self._decode_lock:
-                    if not self._dqueue:
-                        break
-                    head = self._dqueue[0]
-                    if head.resume_kv is not None:
-                        # a KV import can't ride the prefill program;
-                        # it waits for the next cycle's import pass
-                        break
-                    P_h = self._prefill_len(head)
-                    pol = self.policy
-                    Pb_h = (pol.bucket_seq(P_h)
-                            if pol.max_seq is not None
-                            and P_h <= pol.max_seq
-                            else _pow2_ceil(P_h))
-                    need_t = max(
-                        int(head.prompt.shape[1]) + head.n_new, Pb_h)
-                    if self._slab is None:
-                        geom = self._build_slab(need_t)
-                    elif need_t > int(self._slab_dims()[3]):
-                        geom = self._grow_slab(need_t)
-                    if not self._slab_free:
-                        break
-                    sess = self._dqueue.popleft()
-                    slot = self._slab_free.pop(0)
-                    self._prefill_idx += 1
-                    ordinal = self._prefill_idx
-                cohort.append((sess, slot, ordinal))
+            with trace_mod.span("decode.admit"):
+                self._decode_expire(dst)
+                # -- resume fast path: transplant migrated KV rows
+                # first (a resumed session re-joins WITHOUT a prefill
+                # dispatch)
+                if self._decode_admit_imports(dst):
+                    geom = self._decode_geom()
+                # -- admit: ONE cohort prefill dispatch, bounded per
+                # cycle
+                cohort = []
+                while len(cohort) < self.prefill_batch:
+                    with self._decode_lock:
+                        if not self._dqueue:
+                            break
+                        head = self._dqueue[0]
+                        if head.resume_kv is not None:
+                            # a KV import can't ride the prefill
+                            # program; it waits for the next cycle's
+                            # import pass
+                            break
+                        P_h = self._prefill_len(head)
+                        pol = self.policy
+                        Pb_h = (pol.bucket_seq(P_h)
+                                if pol.max_seq is not None
+                                and P_h <= pol.max_seq
+                                else _pow2_ceil(P_h))
+                        need_t = max(
+                            int(head.prompt.shape[1]) + head.n_new,
+                            Pb_h)
+                        if self._slab is None:
+                            geom = self._build_slab(need_t)
+                        elif need_t > int(self._slab_dims()[3]):
+                            geom = self._grow_slab(need_t)
+                        if not self._slab_free:
+                            break
+                        sess = self._dqueue.popleft()
+                        slot = self._slab_free.pop(0)
+                        self._prefill_idx += 1
+                        ordinal = self._prefill_idx
+                    # waited for a slot and for its turn; its prefill
+                    # is the `prefill` span that follows
+                    trace_mod.record_span(
+                        "decode_queue_wait", sess.t_enqueue,
+                        time.perf_counter(), trace=sess.trace)
+                    cohort.append((sess, slot, ordinal))
             if cohort:
                 if geom is None:
                     geom = self._decode_geom()
@@ -1992,7 +2007,13 @@ class ServingEngine:
                     break
                 sess = self._dqueue.popleft()
                 slot = self._slab_free.pop(0)
+            t_pop = time.perf_counter()
             if self._decode_import(sess, slot, dst):
+                # a failed import goes back to the queue and is
+                # counted when a prefill cohort takes it
+                trace_mod.record_span("decode_queue_wait",
+                                      sess.t_enqueue, t_pop,
+                                      trace=sess.trace)
                 any_in = True
         return any_in
 
@@ -2059,58 +2080,62 @@ class ServingEngine:
         params = geom[0]
         inj = self.fault_injector
         pol = self.policy
-        members = []
-        for sess, slot, ordinal in cohort:
-            if inj is not None and inj.should("prefill_fail", ordinal):
-                self._release_slot(slot)
-                sess.slot = -1
-                self._decode_fail_session(sess, dst,
-                                          ServeDispatchError(
-                    f"decode prefill failed: injected prefill "
-                    f"failure (session {ordinal})"))
-                continue
-            members.append((sess, slot))
-        if not members:
-            return
-        # one bucket for the cohort: the widest member's pow2 rung.
-        # Prefilling a short prompt at a wider rung is exact — pad
-        # rows write K/V the causal mask hides and decode overwrites
-        # slot p before any query attends it (see prefill_slab).
-        Pb = 1
-        for sess, _ in members:
-            P = self._prefill_len(sess)
-            Pb = max(Pb, (pol.bucket_seq(P)
-                          if pol.max_seq is not None and P <= pol.max_seq
-                          else _pow2_ceil(P)))
-        # bucket the cohort's batch dim on the pow2 ladder too — a
-        # cohort of every size 1..prefill_batch would otherwise compile
-        # its own executable (program-cache churn on every admission
-        # mix). Pad rows carry an OUT-OF-BOUNDS slot index: XLA scatter
-        # drops OOB updates, so a pad row touches nothing.
-        Bp = len(members)
-        Bb = (pol.bucket_batch(Bp) if Bp <= pol.max_batch
-              else _pow2_ceil(Bp))
-        n_slots = int(self._slab_dims()[1])
-        ids = np.zeros((Bb, Pb), np.int32)
-        nvec = np.ones(Bb, np.int32)
-        slotv = np.full(Bb, n_slots, np.int32)  # OOB => dropped
-        for r, (sess, slot) in enumerate(members):
-            # a ledger-REPLAY resume prefills prompt + toks[:-1]: the
-            # rebuilt cache is bit-identical to the one the original
-            # replica held when it produced toks[-1]
-            row = sess.prompt[0]
-            if sess.resumed and len(sess.toks) > 1:
-                row = np.concatenate(
-                    [row, np.asarray(sess.toks[:-1], np.int32)])
-            ids[r, :len(row)] = row
-            nvec[r] = len(row)
-            slotv[r] = slot
+        with trace_mod.span("decode.prefill.assemble"):
+            members = []
+            for sess, slot, ordinal in cohort:
+                if inj is not None and inj.should("prefill_fail", ordinal):
+                    self._release_slot(slot)
+                    sess.slot = -1
+                    self._decode_fail_session(sess, dst,
+                                              ServeDispatchError(
+                        f"decode prefill failed: injected prefill "
+                        f"failure (session {ordinal})"))
+                    continue
+                members.append((sess, slot))
+            if not members:
+                return
+            # one bucket for the cohort: the widest member's pow2 rung.
+            # Prefilling a short prompt at a wider rung is exact — pad
+            # rows write K/V the causal mask hides and decode overwrites
+            # slot p before any query attends it (see prefill_slab).
+            Pb = 1
+            for sess, _ in members:
+                P = self._prefill_len(sess)
+                Pb = max(Pb, (pol.bucket_seq(P)
+                              if pol.max_seq is not None and P <= pol.max_seq
+                              else _pow2_ceil(P)))
+            # bucket the cohort's batch dim on the pow2 ladder too — a
+            # cohort of every size 1..prefill_batch would otherwise compile
+            # its own executable (program-cache churn on every admission
+            # mix). Pad rows carry an OUT-OF-BOUNDS slot index: XLA scatter
+            # drops OOB updates, so a pad row touches nothing.
+            Bp = len(members)
+            Bb = (pol.bucket_batch(Bp) if Bp <= pol.max_batch
+                  else _pow2_ceil(Bp))
+            n_slots = int(self._slab_dims()[1])
+            ids = np.zeros((Bb, Pb), np.int32)
+            nvec = np.ones(Bb, np.int32)
+            slotv = np.full(Bb, n_slots, np.int32)  # OOB => dropped
+            for r, (sess, slot) in enumerate(members):
+                # a ledger-REPLAY resume prefills prompt + toks[:-1]: the
+                # rebuilt cache is bit-identical to the one the original
+                # replica held when it produced toks[-1]
+                row = sess.prompt[0]
+                if sess.resumed and len(sess.toks) > 1:
+                    row = np.concatenate(
+                        [row, np.asarray(sess.toks[:-1], np.int32)])
+                ids[r, :len(row)] = row
+                nvec[r] = len(row)
+                slotv[r] = slot
         t0 = time.perf_counter()
         put = self._slab_put
         try:
-            logits, new_slab = model.prefill_slab(
-                params, self._slab, put(ids), put(nvec), put(slotv))
-            lg = np.asarray(logits)
+            with trace_mod.span("decode.prefill.dispatch"):
+                logits, new_slab = model.prefill_slab(
+                    params, self._slab, put(ids), put(nvec),
+                    put(slotv))
+            with trace_mod.span("decode.prefill.readback"):
+                lg = np.asarray(logits)
         except BaseException as e:  # noqa: BLE001 — isolate: a failed
             # cohort dispatch fails ITS members, never the sessions
             # already streaming from the slab
@@ -2124,60 +2149,61 @@ class ServingEngine:
         self._slab = new_slab
         now = time.perf_counter()
         trace_mod.record_span("prefill", t0, now, rows=Bp, bucket=Pb)
-        for r, (sess, slot) in enumerate(members):
-            P = int(sess.prompt.shape[1])
-            if sess.resumed:
-                # replay resume: the prefill rebuilt the KV state; the
-                # ledger already holds every produced token (streamed
-                # at admission) and toks[-1] is the next step's input
-                # — discard this row's logits, restore position state
-                k0 = len(sess.toks)
+        with trace_mod.span("decode.prefill.scatter"):
+            for r, (sess, slot) in enumerate(members):
+                P = int(sess.prompt.shape[1])
+                if sess.resumed:
+                    # replay resume: the prefill rebuilt the KV state; the
+                    # ledger already holds every produced token (streamed
+                    # at admission) and toks[-1] is the next step's input
+                    # — discard this row's logits, restore position state
+                    k0 = len(sess.toks)
+                    sess.slot = slot
+                    sess.pos = P + k0 - 1
+                    sess.left = sess.n_new - k0
+                    sess.tok = sess.toks[-1]
+                    sess.reply.state = "dispatching"
+                    sess.t_last_tok = now
+                    trace_mod.record_span("resume_replay", t0, now,
+                                          trace=sess.trace, prompt=P,
+                                          ledger=k0)
+                    dst.prefills += 1
+                    dst.joins += 1
+                    with self._decode_lock:
+                        self._decode_live[slot] = sess
+                        dst.slots_in_use = len(self._decode_live)
+                    continue
+                if sess.temperature == 0.0:
+                    # host argmax on identical float bits == the traced
+                    # jnp.argmax (both first-max-wins): no extra dispatch
+                    tok = int(np.argmax(lg[r]))
+                else:
+                    sess.key = jax.random.PRNGKey(sess.seed)
+                    sess.key, sub = jax.random.split(sess.key)
+                    sampler = model.sample_fn(sess.temperature,
+                                              sess.top_k)
+                    tok = int(np.asarray(
+                        sampler(put(lg[r:r + 1]), sub))[0])
                 sess.slot = slot
-                sess.pos = P + k0 - 1
-                sess.left = sess.n_new - k0
-                sess.tok = sess.toks[-1]
+                sess.tok = tok
+                sess.pos = P
+                sess.left = sess.n_new - 1
+                sess.toks.append(tok)
                 sess.reply.state = "dispatching"
+                sess.reply._push_token(tok)
                 sess.t_last_tok = now
-                trace_mod.record_span("resume_replay", t0, now,
-                                      trace=sess.trace, prompt=P,
-                                      ledger=k0)
+                trace_mod.record_span("ttft", sess.reply.t_submit, now,
+                                      trace=sess.trace, prompt=P)
+                slo_mod.observe("ttft", now - sess.reply.t_submit)
                 dst.prefills += 1
                 dst.joins += 1
-                with self._decode_lock:
-                    self._decode_live[slot] = sess
-                    dst.slots_in_use = len(self._decode_live)
-                continue
-            if sess.temperature == 0.0:
-                # host argmax on identical float bits == the traced
-                # jnp.argmax (both first-max-wins): no extra dispatch
-                tok = int(np.argmax(lg[r]))
-            else:
-                sess.key = jax.random.PRNGKey(sess.seed)
-                sess.key, sub = jax.random.split(sess.key)
-                sampler = model.sample_fn(sess.temperature,
-                                          sess.top_k)
-                tok = int(np.asarray(
-                    sampler(put(lg[r:r + 1]), sub))[0])
-            sess.slot = slot
-            sess.tok = tok
-            sess.pos = P
-            sess.left = sess.n_new - 1
-            sess.toks.append(tok)
-            sess.reply.state = "dispatching"
-            sess.reply._push_token(tok)
-            sess.t_last_tok = now
-            trace_mod.record_span("ttft", sess.reply.t_submit, now,
-                                  trace=sess.trace, prompt=P)
-            slo_mod.observe("ttft", now - sess.reply.t_submit)
-            dst.prefills += 1
-            dst.joins += 1
-            dst.tokens_streamed += 1
-            if sess.left == 0:
-                self._decode_finish(sess, dst)
-            else:
-                with self._decode_lock:
-                    self._decode_live[slot] = sess
-                    dst.slots_in_use = len(self._decode_live)
+                dst.tokens_streamed += 1
+                if sess.left == 0:
+                    self._decode_finish(sess, dst)
+                else:
+                    with self._decode_lock:
+                        self._decode_live[slot] = sess
+                        dst.slots_in_use = len(self._decode_live)
 
     def _decode_run_ahead(self, live) -> int:
         """How many fused steps may dispatch as ONE scanned block
@@ -2222,13 +2248,14 @@ class ServingEngine:
         model = self.model
         params = geom[0]
         put = self._slab_put
-        Sb = int(self._slab_dims()[1])
-        tokv = np.zeros(Sb, np.int32)
-        posv = np.zeros(Sb, np.int32)
-        for slot, sess in live:
-            tokv[slot] = sess.tok
-            posv[slot] = sess.pos
-        k = self._decode_run_ahead(live)
+        with trace_mod.span("decode.step.assemble"):
+            Sb = int(self._slab_dims()[1])
+            tokv = np.zeros(Sb, np.int32)
+            posv = np.zeros(Sb, np.int32)
+            for slot, sess in live:
+                tokv[slot] = sess.tok
+                posv[slot] = sess.pos
+            k = self._decode_run_ahead(live)
         inj = self.fault_injector
         t0 = time.perf_counter()
         attempt = 0
@@ -2241,15 +2268,19 @@ class ServingEngine:
                 if inj is not None and inj.should("decode_fail", idx):
                     raise RuntimeError(
                         f"injected decode step failure (step {idx})")
-                if k == 1:
-                    logits, new_slab = model.decode_step(
-                        params, self._slab, put(tokv), put(posv))
-                    lg = np.asarray(logits)  # completes the dispatch
-                    toks = None
-                else:
-                    toks_j, new_slab = model.decode_scan(
-                        params, self._slab, put(tokv), put(posv), k)
-                    toks = np.asarray(toks_j)  # [k, Sb]
+                with trace_mod.span("decode.step.dispatch", steps=k):
+                    if k == 1:
+                        out, new_slab = model.decode_step(
+                            params, self._slab, put(tokv), put(posv))
+                    else:
+                        out, new_slab = model.decode_scan(
+                            params, self._slab, put(tokv), put(posv),
+                            k)
+                with trace_mod.span("decode.step.readback", steps=k):
+                    out = np.asarray(out)  # completes the dispatch
+                # logits [Sb, V] of a single step, tokens [k, Sb] of
+                # a run-ahead block
+                lg, toks = (out, None) if k == 1 else (None, out)
                 break
             except BaseException as e:  # noqa: BLE001 — retry below
                 if attempt >= self.max_retries:
@@ -2282,52 +2313,53 @@ class ServingEngine:
         dst.decode_steps += k
         trace_mod.record_span("decode_step", t0, t0 + block_s,
                               rows=len(live), slots=Sb, steps=k)
-        now = time.perf_counter()
-        for slot, sess in live:
-            if toks is not None:
-                seq = [int(t) for t in toks[:, slot]]
-            elif sess.temperature == 0.0:
-                seq = [int(np.argmax(lg[slot]))]
-            else:
-                sess.key, sub = jax.random.split(sess.key)
-                sampler = model.sample_fn(sess.temperature,
-                                          sess.top_k)
-                seq = [int(np.asarray(
-                    sampler(put(lg[slot:slot + 1]), sub))[0])]
-            for tok in seq:
-                sess.toks.append(tok)
-                sess.reply._push_token(tok)
-                trace_mod.record_span("tpot", sess.t_last_tok, now,
-                                      trace=sess.trace)
-                slo_mod.observe("tpot", now - sess.t_last_tok)
-                sess.t_last_tok = now
-                dst.tokens_streamed += 1
-            sess.tok = seq[-1]
-            sess.pos += k
-            sess.left -= k
-            if sess.left == 0:
-                self._decode_finish(sess, dst)
-        with self._decode_lock:
-            nlive = len(self._decode_live)
-            qdepth = len(self._dqueue)
-            dst.slots_in_use = nlive
-        if self.metrics is not None:
-            try:
-                extra = ({"quant": self._decode_quant}
-                         if self._decode_quant != "off" else {})
-                self.metrics.log_step(
-                    self._decode_step_idx,
-                    examples=len(live) * k,
-                    step_s=block_s, tier="decode",
-                    sessions=len(live), slots=Sb, block=k,
-                    slab_seq=int(self._slab_dims()[3]),
-                    occupancy=round(len(live) / Sb, 4),
-                    queue_depth=qdepth,
-                    tokens_streamed=dst.tokens_streamed,
-                    completed=dst.completed, expired=dst.expired,
-                    shed=dst.shed, failed=dst.failed, **extra)
-            except Exception:
-                _STATS.errors += 1  # metrics stream closed mid-serve
+        with trace_mod.span("decode.step.scatter"):
+            now = time.perf_counter()
+            for slot, sess in live:
+                if toks is not None:
+                    seq = [int(t) for t in toks[:, slot]]
+                elif sess.temperature == 0.0:
+                    seq = [int(np.argmax(lg[slot]))]
+                else:
+                    sess.key, sub = jax.random.split(sess.key)
+                    sampler = model.sample_fn(sess.temperature,
+                                              sess.top_k)
+                    seq = [int(np.asarray(
+                        sampler(put(lg[slot:slot + 1]), sub))[0])]
+                for tok in seq:
+                    sess.toks.append(tok)
+                    sess.reply._push_token(tok)
+                    trace_mod.record_span("tpot", sess.t_last_tok, now,
+                                          trace=sess.trace)
+                    slo_mod.observe("tpot", now - sess.t_last_tok)
+                    sess.t_last_tok = now
+                    dst.tokens_streamed += 1
+                sess.tok = seq[-1]
+                sess.pos += k
+                sess.left -= k
+                if sess.left == 0:
+                    self._decode_finish(sess, dst)
+            with self._decode_lock:
+                nlive = len(self._decode_live)
+                qdepth = len(self._dqueue)
+                dst.slots_in_use = nlive
+            if self.metrics is not None:
+                try:
+                    extra = ({"quant": self._decode_quant}
+                             if self._decode_quant != "off" else {})
+                    self.metrics.log_step(
+                        self._decode_step_idx,
+                        examples=len(live) * k,
+                        step_s=block_s, tier="decode",
+                        sessions=len(live), slots=Sb, block=k,
+                        slab_seq=int(self._slab_dims()[3]),
+                        occupancy=round(len(live) / Sb, 4),
+                        queue_depth=qdepth,
+                        tokens_streamed=dst.tokens_streamed,
+                        completed=dst.completed, expired=dst.expired,
+                        shed=dst.shed, failed=dst.failed, **extra)
+                except Exception:
+                    _STATS.errors += 1  # metrics stream closed mid-serve
 
     # -- dispatcher -------------------------------------------------------
     def _fail_request(self, req: _Request, err: BaseException,

@@ -1,5 +1,6 @@
-"""Step-timeline tracing, structured metrics logging, and device-
-profiler hooks (ISSUE 5) — the standard instrumentation surface.
+"""Step-timeline tracing, structured metrics logging, and the
+device profiler's host plane (ISSUE 5) — the standard instrumentation
+surface.
 
 The reference's observability is a per-op wall-time table
 (`Device::PrintTimeProfiling`); the TPU-native step is one opaque XLA
@@ -21,6 +22,12 @@ what justifies decomposition choices). Three pieces:
     checkpoint save/restore). Enable: `device.set_tracing(True)`.
     Export: `export_chrome_trace(path)` (Chrome trace-event /
     Perfetto JSON) or the per-step `format_summary()` table.
+  - **Device-trace sink** — an enabled `span()` is also a
+    `jax.profiler.TraceAnnotation("singa:" + name)`: whenever any
+    profiler session runs (`jax.profiler.start_trace`), every span
+    lands in the trace's `/host:CPU` plane on the device trace's
+    clock, on the thread that did the work, under the device's
+    operations. No session running, it costs under a microsecond.
   - **MetricsLogger** — one schema-stable JSONL record per training
     step (step, loss, examples/sec, data-wait / dispatch /
     device-sync seconds, `cache_stats` counter deltas,
@@ -28,9 +35,6 @@ what justifies decomposition choices). Three pieces:
     record-atomically so a killed run (PR 3's `fit_resumable`)
     leaves a parseable log — `read_metrics` tolerates the one
     partial trailing line a kill mid-write can leave.
-  - **Device profiler hook** — `profile_steps(n)` arms
-    `jax.profiler` tracing for the next n step spans, so bench runs
-    capture REAL device traces for steps k..k+n, not host proxies.
 
 Counters surface in `cache_stats()["trace"]` and reset with
 `reset_cache_stats()` (ring entries survive the reset — resetting
@@ -67,7 +71,6 @@ __all__ = [
     "aggregate_fleet",
     "span_summary",
     "format_summary",
-    "profile_steps",
     "new_trace_id",
     "context",
     "current_trace",
@@ -90,8 +93,8 @@ _ENABLED = False
 _RING: deque = deque(maxlen=16384)
 _NEXT_ID = itertools.count(1)  # .__next__ is atomic in CPython
 _TLS = threading.local()
-_PROFILE: Optional[Dict] = None
-_PROFILE_DIR = "/tmp/singa_tpu_profile"
+# jax.profiler.TraceAnnotation, imported by the first enabled span
+_ANNOTATION = None
 _LAST_STEP: Optional[Dict] = None
 # Cross-process span ship-back (ISSUE 15): spans carrying a trace
 # context are ALSO buffered here when a capacity is armed
@@ -145,9 +148,8 @@ stats_mod.register_cache("trace", _STATS)
 # ---------------------------------------------------------------------------
 def configure(enabled: Optional[bool] = None,
               ring_capacity: Optional[int] = None,
-              profile_dir: Optional[str] = None,
               ship_capacity: Optional[int] = None) -> Dict:
-    global _ENABLED, _RING, _PROFILE_DIR, _SHIP_CAP
+    global _ENABLED, _RING, _SHIP_CAP
     with _LOCK:
         if ring_capacity is not None:
             cap = int(ring_capacity)
@@ -155,8 +157,6 @@ def configure(enabled: Optional[bool] = None,
                 raise ValueError("ring_capacity must be >= 1")
             if cap != _RING.maxlen:
                 _RING = deque(_RING, maxlen=cap)
-        if profile_dir is not None:
-            _PROFILE_DIR = str(profile_dir)
         if ship_capacity is not None:
             cap = int(ship_capacity)
             if cap < 0:
@@ -171,7 +171,7 @@ def configure(enabled: Optional[bool] = None,
 
 def get_config() -> Dict:
     return {"enabled": _ENABLED, "ring_capacity": _RING.maxlen,
-            "profile_dir": _PROFILE_DIR, "ship_capacity": _SHIP_CAP}
+            "ship_capacity": _SHIP_CAP}
 
 
 def enabled() -> bool:
@@ -340,13 +340,21 @@ class _Span:
     # cross-validated against this very span) must feed the IDENTICAL
     # value, not a second clock read that diverges under load.
     __slots__ = ("name", "args", "id", "parent", "depth", "t0",
-                 "dur_s")
+                 "dur_s", "_ann")
 
     def __init__(self, name: str, args: Optional[Dict]):
         self.name = name
         self.args = args
 
     def __enter__(self):
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            from jax.profiler import TraceAnnotation
+
+            _ANNOTATION = TraceAnnotation
+        # the span's args stay out of the name: readers match on it
+        self._ann = _ANNOTATION("singa:" + self.name)
+        self._ann.__enter__()
         st = _stack()
         self.depth = len(st)
         self.parent = st[-1].id if st else None
@@ -357,6 +365,15 @@ class _Span:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        try:
+            self._record(t1)
+        finally:
+            # the annotation opens first and closes last: in a device
+            # trace the span's own bookkeeping is inside the span
+            self._ann.__exit__(*exc)
+        return False
+
+    def _record(self, t1: float) -> None:
         self.dur_s = t1 - self.t0
         st = _stack()
         if st and st[-1] is self:
@@ -389,7 +406,7 @@ class _Span:
                 rec["remote_parent"] = c.parent
         with _LOCK:
             if not _ENABLED:
-                return False  # disabled mid-span: drop silently
+                return  # disabled mid-span: drop silently
             if len(_RING) == _RING.maxlen:
                 _STATS.dropped += 1
             _RING.append(rec)
@@ -399,12 +416,13 @@ class _Span:
             if frame is not None and self.name != "step":
                 acc = frame["acc"]
                 acc[self.name] = acc.get(self.name, 0.0) + (t1 - self.t0)
-        return False
 
 
 def span(name: str, **args):
     """Context manager timing one named host span. Nests (thread-local
-    stack fixes depth/parent), records into the bounded ring on exit.
+    stack fixes depth/parent), records into the bounded ring on exit,
+    and is a `jax.profiler.TraceAnnotation("singa:" + name)` meanwhile,
+    so a running profiler session shows it on the device trace's clock.
     Strict no-op while tracing is disabled: the shared `_NULL` context
     is returned, nothing is recorded or allocated."""
     if not _ENABLED:
@@ -420,12 +438,14 @@ def record_span(name: str, t0: float, t1: float, trace=None,
     serving request's `queue_wait`, measured from the submitter's
     enqueue to the dispatcher's dequeue — can only be recorded after
     the fact. Same ring, same drop accounting, same strict no-op while
-    tracing is disabled. Top-level by construction (no parent): the
-    two endpoint threads have different span stacks, so nesting is
-    undefined. `trace` attaches a trace context explicitly — a str
-    trace id or a (trace_id, parent_span_id) pair — for spans whose
-    owning request lives on another thread; None falls back to the
-    calling thread's active context."""
+    tracing is disabled. Ring only: an annotation cannot be opened in
+    the past, so a profiler session's trace does not show these spans.
+    Top-level by construction (no parent): the two endpoint threads
+    have different span stacks, so nesting is undefined. `trace`
+    attaches a trace context explicitly — a str trace id or a
+    (trace_id, parent_span_id) pair — for spans whose owning request
+    lives on another thread; None falls back to the calling thread's
+    active context."""
     if not _ENABLED:
         return
     rec = {
@@ -463,8 +483,7 @@ def record_span(name: str, t0: float, t1: float, trace=None,
 class _StepCtx:
     """One training step: opens a "step" span, accumulates child span
     durations by name (the per-step data_wait / dispatch / device_sync
-    decomposition `MetricsLogger` reads via `last_step_timings`), and
-    drives the jax.profiler window armed by `profile_steps`."""
+    decomposition `MetricsLogger` reads via `last_step_timings`)."""
 
     __slots__ = ("step", "_span", "_frame", "_prev_frame", "_t0")
 
@@ -472,10 +491,6 @@ class _StepCtx:
         self.step = step
 
     def __enter__(self):
-        _profile_step_started()
-        if not _ENABLED:
-            self._span = None
-            return self
         self._prev_frame = getattr(_TLS, "step_frame", None)
         self._frame = {"step": self.step, "acc": {}}
         _TLS.step_frame = self._frame
@@ -486,23 +501,21 @@ class _StepCtx:
 
     def __exit__(self, *exc):
         global _LAST_STEP
-        if self._span is not None:
-            self._span.__exit__(*exc)
-            wall = time.perf_counter() - self._t0
-            _TLS.step_frame = self._prev_frame
-            acc = self._frame["acc"]
-            summary = {
-                "step": self.step,
-                "step_s": wall,
-                "data_wait_s": acc.get("data_wait", 0.0),
-                "dispatch_s": acc.get("dispatch", 0.0),
-                "device_sync_s": acc.get("device_sync", 0.0),
-            }
-            with _LOCK:
-                if _ENABLED:
-                    _LAST_STEP = summary
-                    _STATS.steps += 1
-        _profile_step_finished()
+        self._span.__exit__(*exc)
+        wall = time.perf_counter() - self._t0
+        _TLS.step_frame = self._prev_frame
+        acc = self._frame["acc"]
+        summary = {
+            "step": self.step,
+            "step_s": wall,
+            "data_wait_s": acc.get("data_wait", 0.0),
+            "dispatch_s": acc.get("dispatch", 0.0),
+            "device_sync_s": acc.get("device_sync", 0.0),
+        }
+        with _LOCK:
+            if _ENABLED:
+                _LAST_STEP = summary
+                _STATS.steps += 1
         return False
 
 
@@ -510,9 +523,8 @@ def step_span(step=None):
     """Context manager for ONE training step. While tracing is enabled
     it opens a "step" span whose children (data_wait / dispatch /
     device_sync, emitted by the wired step path) become the per-step
-    decomposition; it also ticks the `profile_steps` window either
-    way. A strict no-op when tracing is off and no profile is armed."""
-    if not _ENABLED and _PROFILE is None:
+    decomposition. A strict no-op when tracing is off."""
+    if not _ENABLED:
         return _NULL
     return _StepCtx(step)
 
@@ -722,74 +734,6 @@ def format_summary() -> str:
             f"{s['mean_ms']:>9.3f} {s['max_ms']:>9.3f} "
             f"{s['total_ms'] / n_steps:>9.3f}")
     return "\n".join(lines)
-
-
-# ---------------------------------------------------------------------------
-# Device profiler hook: jax.profiler over a step window.
-# ---------------------------------------------------------------------------
-def profile_steps(n: int, logdir: Optional[str] = None) -> str:
-    """Arm `jax.profiler.trace` for the NEXT `n` step spans: the trace
-    starts when the next `step_span` opens and stops after n of them
-    close, so bench runs capture real device traces for steps k..k+n
-    (not host-side proxies) without bracketing warmup/compile noise.
-    Returns the log directory (default: the `profile_dir` configured
-    via `device.set_tracing`). One window at a time; re-arming
-    replaces a not-yet-started window."""
-    global _PROFILE
-    n = int(n)
-    if n < 1:
-        raise ValueError(f"profile_steps: n must be >= 1, got {n}")
-    with _LOCK:
-        if _PROFILE is not None and _PROFILE["active"]:
-            raise RuntimeError(
-                "profile_steps: a profiler window is already running")
-        _PROFILE = {"remaining": n,
-                    "logdir": str(logdir or _PROFILE_DIR),
-                    "active": False}
-        return _PROFILE["logdir"]
-
-
-def _profile_step_started() -> None:
-    global _PROFILE
-    with _LOCK:
-        prof = _PROFILE
-        if prof is None or prof["active"]:
-            return
-        prof["active"] = True
-        logdir = prof["logdir"]
-    try:
-        import jax
-
-        os.makedirs(logdir, exist_ok=True)
-        jax.profiler.start_trace(logdir)
-    except Exception as e:
-        import sys
-
-        print(f"singa_tpu: jax profiler start failed ({e!r}); "
-              "profile window dropped", file=sys.stderr)
-        with _LOCK:
-            _PROFILE = None
-
-
-def _profile_step_finished() -> None:
-    global _PROFILE
-    with _LOCK:
-        prof = _PROFILE
-        if prof is None or not prof["active"]:
-            return
-        prof["remaining"] -= 1
-        if prof["remaining"] > 0:
-            return
-        _PROFILE = None
-    try:
-        import jax
-
-        jax.profiler.stop_trace()
-    except Exception as e:
-        import sys
-
-        print(f"singa_tpu: jax profiler stop failed ({e!r})",
-              file=sys.stderr)
 
 
 # ---------------------------------------------------------------------------

@@ -58,7 +58,7 @@ def test_observability_row_and_readme_section_present():
     assert "set_tracing" in readme
     assert "MetricsLogger" in readme
     assert "export_chrome_trace" in readme
-    assert "profile_steps" in readme
+    assert "TraceAnnotation" in readme
 
 
 def test_export_cache_row_and_readme_section_present():

@@ -343,3 +343,170 @@ def test_ttft_tpot_spans_under_tracing(lm):
     seg = trace_mod._segment_stats(recs)
     assert seg["ttft"]["count"] == len(prompts)
     assert "p99_ms" in seg["tpot"]
+
+
+# ---------------------------------------------------------------------------
+# The dispatcher's cycle as leaf spans (ISSUE 24)
+# ---------------------------------------------------------------------------
+_PREFILL = ["decode.prefill." + p
+            for p in ("assemble", "dispatch", "readback", "scatter")]
+_STEP = ["decode.step." + p
+         for p in ("assemble", "dispatch", "readback", "scatter")]
+
+
+def _cycles(names):
+    """Split the dispatcher's leaves, in time order, into cycles: a
+    wait for work, or an admit with the phases that followed it."""
+    cycles = []
+    for n in names:
+        if n in ("decode.admit", "decode.wait_work"):
+            cycles.append([n])
+        else:
+            cycles[-1].append(n)
+    return cycles
+
+
+def _assert_cycle_grammar(names):
+    assert names[0] in ("decode.admit", "decode.wait_work")
+    allowed = [["decode.wait_work"]] + [
+        ["decode.admit"] + p + s
+        for p in ([], _PREFILL) for s in ([], _STEP)]
+    for cyc in _cycles(names):
+        assert cyc in allowed, cyc
+
+
+def _traced_sessions(lm, monkeypatch, n=5, **engine_kw):
+    """`n` sessions, each under its own trace id, through a toy
+    engine with tracing on: (sessions as the engine made them, the
+    ring's records)."""
+    from singa_tpu import trace as trace_mod
+
+    made = []
+
+    class Spy(serve._DecodeSession):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            made.append(self)
+
+    monkeypatch.setattr(serve, "_DecodeSession", Spy)
+    kw = dict(max_sessions=2, max_new_tokens=NEW, prefill_batch=2,
+              decode_block=2)
+    kw.update(engine_kw)
+    eng = serve.ServingEngine(lm, **kw).start()
+    try:
+        eng.warm_decode(prompt_lens=(2, 3, 5), max_new_tokens=NEW)
+        device.set_tracing(True, ring_capacity=8192)
+        trace_mod.clear()
+        replies = []
+        for i, p in enumerate(_prompts(n)):
+            with trace_mod.context(f"req-{i}"):
+                while True:     # two slots: a shed session comes back
+                    try:
+                        replies.append(eng.submit_decode(p, NEW))
+                        break
+                    except serve.ServeOverloadError:
+                        time.sleep(0.002)
+        for r in replies:
+            r.result(timeout=60)
+        time.sleep(0.12)        # two empty waits close the timeline
+        recs = trace_mod.records()
+    finally:
+        device.set_tracing(False)
+        eng.stop()
+    admitted = [s for s in made if s.reply.done()
+                and s.reply in replies]
+    return admitted, recs
+
+
+def test_dispatcher_cycle_is_covered_by_ordered_disjoint_leaves(
+        lm, monkeypatch):
+    """Between its first and its last span the dispatcher thread is
+    always inside exactly one leaf, the leaves of a cycle come in the
+    loop's order, and the `prefill` / `decode_step` records still lie
+    around their dispatch and readback leaves."""
+    _, recs = _traced_sessions(lm, monkeypatch)
+    leaves = sorted((r for r in recs if r["name"].startswith("decode.")),
+                    key=lambda r: r["ts"])
+    assert len({r["tid"] for r in leaves}) == 1
+    assert all(r["depth"] == 0 for r in leaves)
+    _assert_cycle_grammar([r["name"] for r in leaves])
+    for a, b in zip(leaves, leaves[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"], (a["name"], b["name"])
+    covered = sum(r["dur"] for r in leaves)
+    spanned = leaves[-1]["ts"] + leaves[-1]["dur"] - leaves[0]["ts"]
+    assert covered >= 0.95 * spanned, (covered, spanned)
+    names = [r["name"] for r in recs]
+    assert names.count("decode.step.readback") == names.count(
+        "decode_step") > 0
+    assert names.count("decode.prefill.readback") == names.count(
+        "prefill") > 0
+    # the old records keep their endpoints: from just before the
+    # dispatch leaf to just after the readback leaf
+    for old, first, last in (("decode_step", "decode.step.dispatch",
+                              "decode.step.readback"),
+                             ("prefill", "decode.prefill.dispatch",
+                              "decode.prefill.readback")):
+        olds = [r for r in recs if r["name"] == old]
+        firsts = [r for r in leaves if r["name"] == first]
+        lasts = [r for r in leaves if r["name"] == last]
+        for o, f, la in zip(olds, firsts, lasts):
+            assert o["ts"] <= f["ts"]
+            assert la["ts"] + la["dur"] <= o["ts"] + o["dur"]
+            assert (o["dur"] - (la["ts"] + la["dur"] - f["ts"])) < 2e3
+    steps = [r for r in leaves if r["name"] == "decode.step.dispatch"]
+    assert {r["args"]["steps"] for r in steps} <= {1, 2}
+
+
+def test_one_decode_queue_wait_per_admitted_session(lm, monkeypatch):
+    """From the session's enqueue to its pop into a cohort, under the
+    request's trace id, ending before its first token."""
+    admitted, recs = _traced_sessions(lm, monkeypatch)
+    assert len(admitted) == 5
+    waits = [r for r in recs if r["name"] == "decode_queue_wait"]
+    ttft = {r["trace"]: r for r in recs if r["name"] == "ttft"}
+    assert sorted(r["trace"] for r in waits) == sorted(
+        s.trace[0] for s in admitted)
+    by_trace = {s.trace[0]: s for s in admitted}
+    for w in waits:
+        sess = by_trace[w["trace"]]
+        assert w["ts"] == pytest.approx(sess.t_enqueue * 1e6, abs=1e-3)
+        first = ttft[w["trace"]]
+        assert w["ts"] + w["dur"] <= first["ts"] + first["dur"]
+    # five sessions over two slots: some waited for a slot
+    assert max(w["dur"] for w in waits) > min(w["dur"] for w in waits)
+
+
+def test_dispatcher_leaves_reach_the_profilers_host_plane(
+        lm, monkeypatch, tmp_path):
+    """Live, on the CPU backend: a profiler session around a few toy
+    requests holds the dispatcher's leaves under "singa:" in
+    /host:CPU, on one line, in the loop's order, and the benchmark's
+    reader finds them."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from perfbench.harness import xplane
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, recs = _traced_sessions(lm, monkeypatch, n=3)
+    finally:
+        jax.profiler.stop_trace()
+    path = xplane.newest_xplane(str(tmp_path))
+    tr = xplane.load(path, host_prefix="singa:")
+    leaves = [e for e in tr.host if e[0].startswith("singa:decode.")]
+    _assert_cycle_grammar([e[0][len("singa:"):] for e in leaves])
+    for a, b in zip(leaves, leaves[1:]):
+        assert a[2] <= b[1], (a, b)
+    in_ring = [r["name"] for r in sorted(recs, key=lambda r: r["ts"])
+               if r["name"].startswith("decode.")]
+    # the same leaves as the ring holds, but for the one that was open
+    # when tracing went off (the ring drops it, its annotation closes)
+    in_trace = [e[0][len("singa:"):] for e in leaves]
+    assert in_trace[:len(in_ring)] == in_ring
+    assert len(in_ring) > 10 and len(in_trace) - len(in_ring) <= 1
+    lines = [line for plane in ProfileData.from_file(path).planes
+             if plane.name == xplane.HOST_PLANE for line in plane.lines
+             if any(e.name.startswith("singa:decode.")
+                    for e in line.events)]
+    assert len(lines) == 1

@@ -1,5 +1,5 @@
 """Observability layer (ISSUE 5): span tracer, Chrome trace export,
-metrics JSONL, device-profiler hook.
+metrics JSONL, device-trace sink.
 
 Contracts under test:
   - span nesting/ordering (thread-local stack; children close first),
@@ -250,28 +250,147 @@ def test_batchiter_emits_data_wait_spans():
 
 
 # ---------------------------------------------------------------------------
-# Device-profiler hook
+# Device-trace sink: an enabled span is a jax.profiler.TraceAnnotation
 # ---------------------------------------------------------------------------
-def test_profile_steps_wraps_jax_profiler(monkeypatch, tmp_path):
+@pytest.fixture
+def annotations(monkeypatch):
+    """Stand in for `jax.profiler.TraceAnnotation`; the log holds
+    (what, name) in the order things happened."""
+    log = []
+
+    class Fake:
+        def __init__(self, name):
+            self.name = name
+            log.append(("new", name))
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+            return False
+
+    monkeypatch.setattr(trace, "_ANNOTATION", Fake)
+    return log
+
+
+@pytest.mark.parametrize("how", ["clean", "exception", "mismatched"])
+def test_enabled_span_is_exactly_one_annotation(annotations, how):
+    device.set_tracing(True)
+    if how == "clean":
+        with trace.span("work", rows=3):
+            assert annotations == [("new", "singa:work"),
+                                   ("enter", "singa:work")]
+    elif how == "exception":
+        with pytest.raises(KeyError):
+            with trace.span("work", rows=3):
+                raise KeyError("boom")
+    else:  # a generator's teardown: the outer span leaves first
+        outer, inner = trace.span("work", rows=3), trace.span("inner")
+        outer.__enter__()
+        inner.__enter__()
+        outer.__exit__(None, None, None)
+        inner.__exit__(None, None, None)
+    # the span's args never reach the annotation's name
+    for name in {n for _, n in annotations}:
+        assert [w for w, n in annotations if n == name] == [
+            "new", "enter", "exit"]
+    assert {n for _, n in annotations} == (
+        {"singa:work"} if how != "mismatched"
+        else {"singa:work", "singa:inner"})
+    assert {r["name"] for r in trace.records()} == {
+        n[len("singa:"):] for _, n in annotations}
+
+
+def test_step_span_and_record_span_sinks(annotations):
+    """`step_span` is a span like any other; `record_span` has its
+    endpoints in the past and stays in the ring only."""
+    device.set_tracing(True)
+    with trace.step_span(7):
+        trace.record_span("queue_wait", 1.0, 2.0)
+    assert annotations == [("new", "singa:step"), ("enter", "singa:step"),
+                           ("exit", "singa:step")]
+    assert {r["name"] for r in trace.records()} == {"step", "queue_wait"}
+
+
+def test_disabled_span_makes_no_annotation_and_allocates_nothing(
+        annotations):
+    """The PR 5 pin with the sink in place: disabled, `span()` is
+    the shared null context: no annotation object, and no allocation
+    that grows with the number of calls (the smallest per-call leak,
+    a 24-byte object, would be 48 KB over 2000 calls)."""
+    import tracemalloc
+
+    assert not trace.enabled()
+    assert trace.span("decode.step.dispatch", steps=8) is trace._NULL
+    N = 2000
+    only_trace = tracemalloc.Filter(True, "*trace.py")
+    rounds = []
+    tracemalloc.start()
+    try:
+        for _ in range(3):
+            for _ in range(50):  # warm frames/freelists
+                with trace.span("decode.step.readback", steps=1):
+                    pass
+            before = tracemalloc.take_snapshot().filter_traces(
+                [only_trace])
+            for _ in range(N):
+                with trace.span("decode.step.readback", steps=1):
+                    pass
+            after = tracemalloc.take_snapshot().filter_traces(
+                [only_trace])
+            rounds.append(sum(
+                d.size_diff for d in after.compare_to(before, "lineno")
+                if d.size_diff > 0))
+    finally:
+        tracemalloc.stop()
+    assert annotations == []
+    assert trace.records() == []
+    assert min(rounds) < N // 2, (
+        f"disabled span allocates per call: {rounds} bytes per "
+        f"{N}-call round")
+
+
+def test_spans_of_a_worker_thread_reach_the_profilers_host_plane(
+        tmp_path):
+    """Live, on the CPU backend: with a profiler session running, the
+    spans a worker thread opens come back from the trace's /host:CPU
+    plane under "singa:<name>", in order, nested as they were."""
+    import glob
+
     import jax
+    from jax.profiler import ProfileData
 
-    calls = []
-    monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d, **kw: calls.append(("start", d)))
-    monkeypatch.setattr(jax.profiler, "stop_trace",
-                        lambda: calls.append(("stop",)))
-    device.set_tracing(True, profile_dir=str(tmp_path))
-    logdir = trace.profile_steps(2)
-    assert logdir == str(tmp_path)
-    for k in range(4):  # window covers steps 0..1 only
-        with trace.step_span(k):
-            pass
-    assert calls == [("start", str(tmp_path)), ("stop",)]
+    device.set_tracing(True)
 
+    def work():
+        for _ in range(3):
+            with trace.span("outer", rows=1):
+                with trace.span("inner"):
+                    np.dot(_X.T, _X)
 
-def test_profile_steps_validates_n():
-    with pytest.raises(ValueError):
-        trace.profile_steps(0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t = threading.Thread(target=work)
+        t.start()
+        t.join(30)
+        assert not t.is_alive()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events if e.name.startswith("singa:")]
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU" for line in plane.lines]
+    (mine,) = [evs for evs in lines if evs]   # one thread, one line
+    mine.sort(key=lambda e: (e[1], -e[2]))
+    assert [e[0] for e in mine] == ["singa:outer", "singa:inner"] * 3
+    for outer, inner in zip(mine[::2], mine[1::2]):
+        assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    # the ring holds the same six, on perf_counter
+    assert [r["name"] for r in trace.records()] == ["inner", "outer"] * 3
 
 
 # ---------------------------------------------------------------------------
