@@ -123,12 +123,15 @@ class TransformerLM(model.Model):
             return (l.W.data, l.b.data if l.bias else None)
 
         def ln(l):
-            # (g, eps) = RMSNorm, (g, b, eps) = LayerNorm — tuple
-            # LENGTH is the dispatch (strings can't be jit pytree
-            # leaves; eps floats can)
+            # (g,) = RMSNorm, (g, b) = LayerNorm — tuple LENGTH is the
+            # dispatch. Nothing but arrays in a jitted call's tree: jax
+            # transfers a Python scalar leaf to the device on EVERY
+            # call (25 eps floats cost 5 ms of each 6.6 ms enqueue on
+            # the v5e), so eps is a constant of the traced program,
+            # read from the layer (`_norm_eps`)
             if isinstance(l, layer.RMSNorm):
-                return (l.gamma.data, l.eps)
-            return (l.gamma.data, l.beta.data, l.eps)
+                return (l.gamma.data,)
+            return (l.gamma.data, l.beta.data)
 
         blocks = []
         for blk in self.blocks._seq:
@@ -157,6 +160,22 @@ class TransformerLM(model.Model):
             "ln_f": ln(self.ln_f),
             "head": head,
         }
+
+    def _norm_eps(self):
+        """Every norm layer's own `eps`, block by block (ln1, ln2) and
+        `ln_f` last: the static half of the norm specs. The decode
+        programs bake these in at trace time, so they ride every
+        program-cache key — a changed `eps` traces a new program, it
+        never runs a stale one. (The AOT store is keyed by
+        `topology_fingerprint()`, which hashes each layer's `eps`.)"""
+        return tuple((float(blk.ln1.eps), float(blk.ln2.eps))
+                     for blk in self.blocks._seq) + (
+                         float(self.ln_f.eps),)
+
+    def _trace_key(self):
+        """What a decode program closes over besides its arguments'
+        shapes: the precision policy and the norms' `eps`."""
+        return (autograd._policy_key(), self._norm_eps())
 
     def _decode_params_quant(self):
         """Int8 view of `_decode_params()` (ISSUE 19): linear entries
@@ -212,14 +231,16 @@ class TransformerLM(model.Model):
         return jnp.matmul(last, head, precision=prec)
 
     @staticmethod
-    def _ln(x, spec):
+    def _ln(x, spec, eps):
+        """`spec` is the traced half (arrays), `eps` the static half
+        (a Python float from `_norm_eps`, a constant of the program)."""
         import jax.numpy as jnp
 
-        if len(spec) == 2:  # RMSNorm: (gamma, eps)
-            g, eps = spec
+        if len(spec) == 1:  # RMSNorm: (gamma,)
+            g, = spec
             return x / jnp.sqrt(
                 jnp.mean(jnp.square(x), -1, keepdims=True) + eps) * g
-        g, b, eps = spec
+        g, b = spec
         mu = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.var(x, axis=-1, keepdims=True)
         return (x - mu) / jnp.sqrt(var + eps) * g + b
@@ -272,8 +293,10 @@ class TransformerLM(model.Model):
                 y = jnp.matmul(x, w, precision=prec)
             return y if b is None else y + b
 
+        *blk_eps, eps_f = self._norm_eps()
         for li, blk in enumerate(params["blocks"]):
-            x = self._ln(h, blk["ln1"])
+            eps1, eps2 = blk_eps[li]
+            x = self._ln(h, blk["ln1"], eps1)
 
             def split(t):  # [B,S,E] -> [B,H,S,D]
                 return t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
@@ -311,10 +334,10 @@ class TransformerLM(model.Model):
             o = jnp.einsum("bhqk,bhkd->bhqd", p, v_all, precision=prec)
             o = o.transpose(0, 2, 1, 3).reshape(B, S, E)
             h = h + lin(o, blk["o"])
-            x = self._ln(h, blk["ln2"])
+            x = self._ln(h, blk["ln2"], eps2)
             h = h + lin(jax.nn.gelu(lin(x, blk["fc1"]),
                                     approximate=False), blk["fc2"])
-        h = self._ln(h, params["ln_f"])
+        h = self._ln(h, params["ln_f"], eps_f)
         if last_index is None:
             last = h[:, -1]
         elif getattr(last_index, "ndim", 0) == 1:
@@ -378,7 +401,7 @@ class TransformerLM(model.Model):
         from jax import lax
 
         key_ = (B, P, max_new, float(temperature), int(top_k),
-                autograd._policy_key())  # policy baked in at trace time
+                self._trace_key())  # baked in at trace time
         cache_dict = self._program_cache()
         hit = cache_dict.get(key_)
         if hit is not None:
@@ -478,8 +501,10 @@ class TransformerLM(model.Model):
                 y = jnp.matmul(x, w, precision=prec)
             return y if b is None else y + b
 
+        *blk_eps, eps_f = self._norm_eps()
         for li, blk in enumerate(params["blocks"]):
-            x = self._ln(h, blk["ln1"])
+            eps1, eps2 = blk_eps[li]
+            x = self._ln(h, blk["ln1"], eps1)
 
             def split(t):  # [B,1,E] -> [B,H,1,D]
                 return t.reshape(B, 1, H, D).transpose(0, 2, 1, 3)
@@ -526,10 +551,10 @@ class TransformerLM(model.Model):
             o = jnp.einsum("bhqk,bhkd->bhqd", p, v_all, precision=prec)
             o = o.transpose(0, 2, 1, 3).reshape(B, 1, E)
             h = h + lin(o, blk["o"])
-            x = self._ln(h, blk["ln2"])
+            x = self._ln(h, blk["ln2"], eps2)
             h = h + lin(jax.nn.gelu(lin(x, blk["fc1"]),
                                     approximate=False), blk["fc2"])
-        h = self._ln(h, params["ln_f"])
+        h = self._ln(h, params["ln_f"], eps_f)
         return (self._head_matmul(h[:, -1], params["head"], prec),
                 new_cache)
 
@@ -564,7 +589,7 @@ class TransformerLM(model.Model):
         through export_cache when the store is armed."""
         cache_dict = self._program_cache()
         key_ = ("slot_step", quant_mod.cache_sig(cache),
-                autograd._policy_key())
+                self._trace_key())
         fn = cache_dict.get(key_)
         if fn is None:
             import jax
@@ -625,7 +650,7 @@ class TransformerLM(model.Model):
 
         cache_dict = self._program_cache()
         key_ = ("slot_scan", int(k), quant_mod.cache_sig(cache),
-                autograd._policy_key())
+                self._trace_key())
         fn = cache_dict.get(key_)
         if fn is None:
             import jax
@@ -664,7 +689,7 @@ class TransformerLM(model.Model):
 
         cache_dict = self._program_cache()
         key_ = ("prefill", ids.shape, cache.shape,
-                jnp.asarray(cache).dtype.name, autograd._policy_key())
+                jnp.asarray(cache).dtype.name, self._trace_key())
         fn = cache_dict.get(key_)
         if fn is None:
             import jax
@@ -700,7 +725,7 @@ class TransformerLM(model.Model):
         cache_dict = self._program_cache()
         key_ = ("prefill_slab", ids.shape,
                 quant_mod.cache_sig(slab),
-                autograd._policy_key())
+                self._trace_key())
         fn = cache_dict.get(key_)
         if fn is None:
             import jax
@@ -879,9 +904,8 @@ class TransformerLM(model.Model):
 
         col, row, rep = P(None, "model"), P("model", None), P()
 
-        def norm_put(t):  # replicate array leaves, pass tags/eps through
-            return tuple(put(v, rep) if hasattr(v, "shape") else v
-                         for v in t)
+        def norm_put(t):  # nothing but arrays in a jitted call's tree
+            return tuple(put(v, rep) for v in t)
 
         def lin(wb, spec):
             w, b = wb

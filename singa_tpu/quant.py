@@ -157,7 +157,9 @@ def quantize_decode_params(params):
     LENGTH is the dispatch, same idiom `_ln` uses for norm specs.
     Embedding-style tables ("embed", "pos", "head") become (payload,
     scale) pairs with per-row / per-column scales shaped for direct
-    broadcast. Norm specs and eps floats pass through untouched."""
+    broadcast. Norm specs (arrays only, like every leaf of a jitted
+    call's tree: `eps` is a constant of the traced program) pass
+    through untouched."""
     def lin3(wb):
         w, b = wb
         q, s = quantize_weight(w, axis=0)    # per-output-channel

@@ -1737,6 +1737,7 @@ class ServingEngine:
         sequence dim starts at the smallest ladder rung covering
         `need_t` and grows via `_grow_slab`. Returns
         (params, L, H, D, Sb, Tslab)."""
+        import jax
         import jax.numpy as jnp
 
         model = self.model
@@ -1753,6 +1754,11 @@ class ServingEngine:
         else:
             params = model._decode_params()
             embed = params["embed"]
+        # every decode-tier call receives this tree: a leaf that is
+        # not a device array is transferred again on each one
+        stats_mod.decode_stats().host_leaves_per_call = sum(
+            not isinstance(leaf, jax.Array)
+            for leaf in jax.tree_util.tree_leaves(params))
         L = len(params["blocks"])
         H = model.blocks._seq[0].attn.num_heads
         D = int(embed.shape[-1]) // H

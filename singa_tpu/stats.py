@@ -669,6 +669,10 @@ class _DecodeStats:
         self.reset()
         self.slots = 0          # gauge: pool size (0 = no pool built)
         self.slots_in_use = 0   # gauge: occupied right now
+        # gauge: leaves of the live decode-params tree that are not
+        # `jax.Array` (counted once at slab build). Each one is a
+        # host-to-device transfer on EVERY decode-tier call; must be 0
+        self.host_leaves_per_call = 0
 
     def reset(self) -> None:
         self.cache.reset()
@@ -711,6 +715,7 @@ class _DecodeStats:
             "resumed": self.resumed,
             "slots": self.slots,
             "slots_in_use": self.slots_in_use,
+            "host_leaves_per_call": self.host_leaves_per_call,
         })
         return out
 
