@@ -15,7 +15,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from .. import autograd, layer, model, quant as quant_mod, tensor
+from .. import autograd, layer, quant as quant_mod, tensor
+from .decode_lm import DecodeLM
 
 
 _NORM_CLS = {"layer": layer.LayerNorm, "rms": layer.RMSNorm}
@@ -58,7 +59,7 @@ class TransformerBlock(layer.Layer):
         return autograd.add(x, h)
 
 
-class TransformerLM(model.Model):
+class TransformerLM(DecodeLM):
     """Causal LM over int token ids [B, S] → logits [B, S, vocab]."""
 
     def __init__(self, vocab_size: int, d_model: int = 256,
@@ -171,11 +172,6 @@ class TransformerLM(model.Model):
         return tuple((float(blk.ln1.eps), float(blk.ln2.eps))
                      for blk in self.blocks._seq) + (
                          float(self.ln_f.eps),)
-
-    def _trace_key(self):
-        """What a decode program closes over besides its arguments'
-        shapes: the precision policy and the norms' `eps`."""
-        return (autograd._policy_key(), self._norm_eps())
 
     def _decode_params_quant(self):
         """Int8 view of `_decode_params()` (ISSUE 19): linear entries
@@ -352,46 +348,6 @@ class TransformerLM(model.Model):
         return (self._head_matmul(last, params["head"], prec),
                 (new_pay, new_sc) if qcache else new_cache)
 
-    def _program_cache(self):
-        """`_gen_cache`: the model's compiled decode-program cache —
-        a bounded `stats.TieredLRUCache` sharing the process-wide
-        `cache_stats()["decode"]` counters (was an unbounded dict;
-        a long-lived server cycling sampling configs and shapes must
-        evict, not grow)."""
-        from .. import stats as stats_mod
-
-        cache = getattr(self, "_gen_cache", None)
-        if cache is None:
-            cache = self._gen_cache = stats_mod.TieredLRUCache(
-                "decode", stats=stats_mod.decode_stats().cache)
-        return cache
-
-    @staticmethod
-    def _count_first_trace(fn):
-        """Time `fn`'s first invocation (trace + compile + run) into
-        the decode CacheStats — the retrace-storm signal for the
-        decode tier."""
-        import time
-
-        import jax
-
-        from .. import stats as stats_mod
-
-        state = [True]
-
-        def wrapped(*a):
-            if state[0]:
-                state[0] = False
-                t0 = time.perf_counter()
-                out = fn(*a)
-                jax.block_until_ready(out)
-                stats_mod.decode_stats().cache.record_trace(
-                    time.perf_counter() - t0)
-                return out
-            return fn(*a)
-
-        return wrapped
-
     def _compiled_decode(self, B, P, max_new, temperature, top_k):
         """Build (or fetch) the jitted prefill+scan decode program for
         this (shapes, sampling config) combination. Cached on the
@@ -558,52 +514,6 @@ class TransformerLM(model.Model):
         return (self._head_matmul(h[:, -1], params["head"], prec),
                 new_cache)
 
-    def _aot_step(self, kind, jitted, args, extras):
-        """Route a decode-tier step through the AOT store when armed:
-        load the serialized executable (no trace) or trace once +
-        publish, falling back to the plain jit on store miss/failure.
-        `args` must be the CONCRETE first-call arguments."""
-        import jax
-
-        from .. import export_cache
-
-        if not export_cache.active():
-            return self._count_first_trace(jitted)
-        key, parts = export_cache.step_key(self, None, kind, args,
-                                           extras=extras)
-        exp = export_cache.load(key)
-        if exp is None:
-            exp = export_cache.export_and_save(key, parts, jitted,
-                                               args)
-            if exp is None:
-                return self._count_first_trace(jitted)
-        return jax.jit(exp.call)
-
-    def decode_step(self, params, cache, tok, pos):
-        """ONE fused decode step for the serving tier: advance every
-        slab row by one token (`tok` [B] int32 at per-row positions
-        `pos` [B] int32), returning (next-token logits [B, V], new
-        cache). `cache` is the per-layer list `_slot_step` documents.
-        Compiled once per slab shape — the one warm executable
-        continuous batching dispatches every step — and AOT-exported
-        through export_cache when the store is armed."""
-        cache_dict = self._program_cache()
-        key_ = ("slot_step", quant_mod.cache_sig(cache),
-                self._trace_key())
-        fn = cache_dict.get(key_)
-        if fn is None:
-            import jax
-
-            jitted = jax.jit(
-                lambda p, c, t, po: self._slot_step(p, c, t, po))
-            args = (params, list(cache), tok, pos)
-            fn = self._aot_step(
-                "decode_step", jitted, args,
-                extras={"slab": self._slab_extra(cache),
-                        "policy": autograd._policy_key()})
-            cache_dict[key_] = fn
-        return fn(params, list(cache), tok, pos)
-
     def decode_step_hlo(self, params, cache, tok, pos,
                         optimized: bool = True) -> str:
         """HLO text of the fused decode step at this exact slab
@@ -631,50 +541,6 @@ class TransformerLM(model.Model):
                     "payload": [list(p.shape) for p, _ in cache],
                     "scale": [list(s.shape) for _, s in cache]}
         return [list(c.shape) for c in cache]
-
-    def decode_scan(self, params, cache, tok, pos, k):
-        """`k` GREEDY fused decode steps in ONE program (`lax.scan`
-        over `_slot_step` + in-graph argmax). XLA updates the scan's
-        cache carry in place — the per-dispatch whole-slab copy that
-        JAX's CPU backend cannot elide (no buffer donation) is paid
-        once per BLOCK instead of once per token, which is where the
-        serving tier's throughput win over sequential `generate()`
-        comes from. In-graph `jnp.argmax` is the exact greedy program
-        `generate()` scans with (and equals host `np.argmax` on
-        identical logits bits — both first-max-wins), so a block
-        decodes bit-identically to k single steps. Returns
-        (toks [k, B] — one sampled token per step per row, new
-        cache). The caller only dispatches a block when no session
-        joins, leaves, expires, or samples within it."""
-        import jax.numpy as jnp
-
-        cache_dict = self._program_cache()
-        key_ = ("slot_scan", int(k), quant_mod.cache_sig(cache),
-                self._trace_key())
-        fn = cache_dict.get(key_)
-        if fn is None:
-            import jax
-
-            def scan_k(p, c, t, po):
-                def body(carry, _):
-                    c, t, po = carry
-                    logits, c = self._slot_step(p, c, t, po)
-                    t2 = jnp.argmax(logits, -1).astype(jnp.int32)
-                    return (c, t2, po + 1), t2
-
-                (c, _t, _po), toks = jax.lax.scan(
-                    body, (c, t, po), None, length=int(k))
-                return toks, c
-
-            jitted = jax.jit(scan_k)
-            args = (params, list(cache), tok, pos)
-            fn = self._aot_step(
-                "decode_scan", jitted, args,
-                extras={"slab": self._slab_extra(cache),
-                        "block": int(k),
-                        "policy": autograd._policy_key()})
-            cache_dict[key_] = fn
-        return fn(params, list(cache), tok, pos)
 
     def prefill_step(self, params, cache, ids, n_real):
         """Prefill one session's bucket-padded prompt: run `ids`
@@ -706,73 +572,78 @@ class TransformerLM(model.Model):
             cache_dict[key_] = fn
         return fn(params, cache, ids, n_real)
 
-    def prefill_slab(self, params, slab, ids, n_real, slots):
-        """Prefill a COHORT of bucket-padded prompts and scatter their
-        K/V into slab rows `slots` in a single program: `_stack_step`
-        runs `ids` [Bp, Pb] against a fresh Pb-wide cache materialised
-        in-graph, each row reads its own last real token's logits
-        (`n_real` [Bp] int32), and every layer's rows land in the slab
-        via one scatter. Param streaming — the dominant prefill cost
-        on memory-bound hosts — is paid once per cohort instead of
-        once per session, the same amortization the fused decode step
-        applies. The slab keeps its stale tail beyond Pb; decode
-        overwrites position p before any query attends it (see
-        `prefill_step`'s pad argument). `slots` [Bp] int32 is traced —
-        one executable per (Bp, Pb) serves every row assignment.
-        Returns (logits [Bp, V], new slab)."""
+    def _prefill_rows(self, params, slab, ids, n_real, slots):
+        """`prefill_slab`'s program: `_stack_step` runs `ids` [Bp, Pb]
+        against a fresh Pb-wide cache materialised in-graph, and every
+        layer's rows land in the slab via one scatter. The slab keeps
+        its stale tail beyond Pb; decode overwrites position p before
+        any query attends it (see `prefill_step`'s pad argument)."""
         import jax.numpy as jnp
 
-        cache_dict = self._program_cache()
-        key_ = ("prefill_slab", ids.shape,
-                quant_mod.cache_sig(slab),
-                self._trace_key())
-        fn = cache_dict.get(key_)
-        if fn is None:
-            import jax
+        L = len(slab)
+        qslab = quant_mod.is_quant_cache(slab)
+        c0 = slab[0][0] if qslab else slab[0]
+        H, D = int(c0.shape[2]), int(c0.shape[4])
+        Bp, Pb = ids.shape
+        if qslab:
+            # fresh Pb-wide QUANTIZED cache in-graph: the chunked
+            # _stack_step writes the same payload + scale planes the
+            # per-step chain would (see quantize_kv), then both planes
+            # scatter into the slab rows in one program
+            c1 = (jnp.zeros((L, 2, Bp, H, Pb, D), jnp.int8),
+                  jnp.zeros((L, 2, Bp, Pb), jnp.float32))
+            logits, (pay, sc) = self._stack_step(
+                params, ids, c1, 0, last_index=n_real - 1)
+            return logits, [
+                (slab[li][0].at[:, slots, :, :Pb, :].set(pay[li]),
+                 slab[li][1].at[:, slots, :Pb].set(sc[li]))
+                for li in range(L)]
+        c1 = jnp.zeros((L, 2, Bp, H, Pb, D), slab[0].dtype)
+        logits, c1 = self._stack_step(params, ids, c1, 0,
+                                      last_index=n_real - 1)
+        return logits, [slab[li].at[:, slots, :, :Pb, :].set(c1[li])
+                        for li in range(L)]
 
-            L = len(slab)
-            qslab = quant_mod.is_quant_cache(slab)
-            c0 = slab[0][0] if qslab else slab[0]
-            H = int(c0.shape[2])
-            D = int(c0.shape[4])
+    # -- the slab, as serve.py asks for it -------------------------------
+    _slab_sig = staticmethod(quant_mod.cache_sig)
 
-            if qslab:
-                def pf(p, sl, i, n, s):
-                    # fresh Pb-wide QUANTIZED cache in-graph: the
-                    # chunked _stack_step writes the same payload +
-                    # scale planes the per-step chain would (see
-                    # quantize_kv), then both planes scatter into
-                    # the slab rows in one program
-                    Bp, Pb = i.shape
-                    c1 = (jnp.zeros((L, 2, Bp, H, Pb, D), jnp.int8),
-                          jnp.zeros((L, 2, Bp, Pb), jnp.float32))
-                    logits, c1 = self._stack_step(p, i, c1, 0,
-                                                  last_index=n - 1)
-                    pay, sc = c1
-                    new = [(sl[li][0].at[:, s, :, :Pb, :]
-                            .set(pay[li]),
-                            sl[li][1].at[:, s, :Pb].set(sc[li]))
-                           for li in range(L)]
-                    return logits, new
-            else:
-                def pf(p, sl, i, n, s):
-                    Bp, Pb = i.shape
-                    c1 = jnp.zeros((L, 2, Bp, H, Pb, D), sl[0].dtype)
-                    logits, c1 = self._stack_step(p, i, c1, 0,
-                                                  last_index=n - 1)
-                    new = [sl[li].at[:, s, :, :Pb, :].set(c1[li])
-                           for li in range(L)]
-                    return logits, new
+    def new_slab(self, params, slots, seq, device):
+        """A per-layer list of [2, slots, H, seq, D] buffers (one
+        stacked [L, ...] array would cost a full extra slab pass per
+        layer inside the fused step — see `_slot_step`), born on
+        `device`; for int8 params the (payload, scale) form."""
+        import jax.numpy as jnp
 
-            jitted = jax.jit(pf)
-            args = (params, list(slab), ids, n_real, slots)
-            fn = self._aot_step(
-                "prefill_slab", jitted, args,
-                extras={"prompt_bucket": list(ids.shape),
-                        "slab": self._slab_extra(slab),
-                        "policy": autograd._policy_key()})
-            cache_dict[key_] = fn
-        return fn(params, list(slab), ids, n_real, slots)
+        quant = isinstance(params["embed"], tuple)
+        embed = params["embed"][0] if quant else params["embed"]
+        L = len(params["blocks"])
+        H = self.blocks._seq[0].attn.num_heads
+        D = int(embed.shape[-1]) // H
+        if quant:
+            return [(jnp.zeros((2, slots, H, seq, D), jnp.int8,
+                               device=device),
+                     jnp.zeros((2, slots, seq), jnp.float32,
+                               device=device))
+                    for _ in range(L)]
+        return [jnp.zeros((2, slots, H, seq, D), embed.dtype,
+                          device=device)
+                for _ in range(L)]
+
+    grow_slab = staticmethod(quant_mod.pad_slab_seq)
+
+    @staticmethod
+    def slab_dims(slab):
+        """(slots, sequence rung) of either slab form."""
+        s0 = quant_mod.slab_shape(slab)
+        return int(s0[1]), int(s0[3])
+
+    @staticmethod
+    def slab_bytes(slab):
+        """Every layer holds the context: nothing here is a ring."""
+        import jax
+
+        return {"ring": 0, "context": sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(slab))}
 
     def export_slab_rows(self, slab, slot, pos):
         """Snapshot one session's live K/V out of the decode slab as a
@@ -851,38 +722,6 @@ class TransformerLM(model.Model):
         padded = np.zeros((L, 2, H, Ts, D), dt)
         padded[:, :, :, :t, :] = rows
         return fn(list(slab), padded, np.int32(slot))
-
-    def sample_fn(self, temperature, top_k):
-        """The EXACT sampling program generate() compiles (argmax when
-        temperature == 0, else temperature-scaled top-k categorical)
-        as a standalone jitted fn `(logits [B, V], key) -> tok [B]`.
-        The serving tier samples each session host-side with the same
-        `jax.random.split` sequence generate() traces, keeping
-        streamed tokens bit-identical to the sequential path."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        key_ = ("sample", float(temperature), int(top_k),
-                autograd._policy_key())
-        cache_dict = self._program_cache()
-        fn = cache_dict.get(key_)
-        if fn is not None:
-            return fn
-
-        def sample(logits, key):
-            if temperature == 0.0:
-                return jnp.argmax(logits, -1).astype(jnp.int32)
-            z = logits / temperature
-            if top_k > 0:
-                k = min(int(top_k), int(logits.shape[-1]))
-                kth = lax.top_k(z, k)[0][..., -1:]
-                z = jnp.where(z < kth, -jnp.inf, z)
-            return jax.random.categorical(key, z).astype(jnp.int32)
-
-        fn = jax.jit(sample)
-        cache_dict[key_] = fn
-        return fn
 
     def _shard_decode_params(self, params, mesh):
         """Lay the decode params out for tensor-parallel inference on

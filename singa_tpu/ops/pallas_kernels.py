@@ -577,3 +577,53 @@ def topk_sparsify(x, spars: float):
     k = max(1, int(flat.shape[0] * spars))
     thr = topk_threshold(flat, k)
     return threshold_mask(x, thr)
+
+
+# -- one position of a decode cache a row, in place ---------------------------
+def _cache_write_kernel(at_ref, cache_ref, new_ref, out_ref, *, axis, tb):
+    """The block of the row's cache that holds position at[b], with
+    that position replaced by `new`: a select over the whole block, so
+    no store has a dynamic lane or sublane."""
+    here = at_ref[pl.program_id(0)] % tb
+    blk = cache_ref[...]
+    where = jax.lax.broadcasted_iota(jnp.int32, blk.shape, axis) == here
+    out_ref[...] = jnp.where(where, new_ref[...], blk)
+
+
+def cache_write(cache, new, at, axis):
+    """`cache` [B, H, ...] with position `at[b]` of its axis `axis` (2
+    or 3: [B,H,T,D] holds a position as a row, [B,H,D,T] as a column)
+    set to `new` [B, H, D], row by row, IN PLACE: the result aliases
+    `cache` (donate it), and of each row only the 128-position block
+    around `at[b]` crosses VMEM. XLA's scatter would do the same write
+    after copying the whole cache into a layout of its own and back,
+    twice the cache's bytes a step (PERF.md, PR 27)."""
+    B, H = cache.shape[:2]
+    T = cache.shape[axis]
+    tb = min(128, T)
+    if T % tb:
+        raise ValueError(f"cache_write: {T} positions do not divide "
+                         f"into blocks of {tb}")
+    block = list(cache.shape)
+    block[0], block[axis] = 1, tb
+    new = jnp.expand_dims(new, axis).astype(cache.dtype)
+    one = list(new.shape)
+    one[0] = 1
+
+    def cache_map(b, at_ref):
+        idx = [b, 0, 0, 0]
+        idx[axis] = at_ref[b] // tb
+        return tuple(idx)
+
+    return pl.pallas_call(
+        functools.partial(_cache_write_kernel, axis=axis, tb=tb),
+        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B,),
+            in_specs=[pl.BlockSpec(tuple(block), cache_map),
+                      pl.BlockSpec(tuple(one),
+                                   lambda b, at_ref: (b, 0, 0, 0))],
+            out_specs=pl.BlockSpec(tuple(block), cache_map)),
+        input_output_aliases={1: 0},
+        interpret=_interpret(),
+    )(at.astype(jnp.int32), cache, new)

@@ -1108,7 +1108,7 @@ class ServingEngine:
             self._decode_live.clear()
             if self._slab is not None:
                 self._slab_free = list(range(
-                    int(self._slab_dims()[1])))
+                    self._slab_dims()[0]))
             self._decode_reserved = 0
         dst = stats_mod.decode_stats()
         for s in waiting + live:
@@ -1439,19 +1439,30 @@ class ServingEngine:
         with self._decode_lock:
             if self._slab is None:
                 geom = self._build_slab(need_t)
-            elif need_t > int(self._slab_dims()[3]):
+            elif need_t > self._slab_dims()[1]:
                 geom = self._grow_slab(need_t)
             else:
                 geom = self._decode_geom()
         params = geom[0]
         model = self.model
-        Sb = int(self._slab_dims()[1])
+        Sb = self._slab_dims()[0]
         warmed = 0
         put = self._slab_put
         tok = put(np.zeros(Sb, np.int32))
         pos = put(np.zeros(Sb, np.int32))
-        lg, _ = model.decode_step(params, self._slab, tok, pos)
-        np.asarray(lg)
+        # a model whose programs donate the slab leaves only the one
+        # they return; what the warm steps wrote there is stale state
+        # no query attends (prefill_slab's argument)
+        keep = model.donates_slab
+
+        def ran(out, slab):
+            np.asarray(out)
+            model.take_step_counters()
+            if keep:
+                self._slab = slab
+            return out
+
+        lg = ran(*model.decode_step(params, self._slab, tok, pos))
         warmed += 1
         for t_k in samplers:
             t, k = float(t_k[0]), int(t_k[1])
@@ -1468,9 +1479,7 @@ class ServingEngine:
         if self.decode_block > 1:
             ks.add(self.decode_block)  # its own rung when not pow2
         for k in sorted(ks):
-            toks, _ = model.decode_scan(params, self._slab, tok, pos,
-                                        k)
-            np.asarray(toks)
+            ran(*model.decode_scan(params, self._slab, tok, pos, k))
             warmed += 1
         bmax = min(self.prefill_batch, Sb)
         bmax = (pol.bucket_batch(bmax) if bmax <= pol.max_batch
@@ -1482,9 +1491,8 @@ class ServingEngine:
                 nv = put(np.ones(bb, np.int32))
                 sv = put(np.full(bb, Sb, np.int32))  # OOB: writes
                 # nothing
-                lg, _ = model.prefill_slab(params, self._slab, ids,
-                                           nv, sv)
-                np.asarray(lg)
+                ran(*model.prefill_slab(params, self._slab, ids, nv,
+                                        sv))
                 warmed += 1
             bb <<= 1
         return warmed
@@ -1533,7 +1541,7 @@ class ServingEngine:
             self._decode_live.clear()
             slab = self._slab
             if slab is not None:
-                self._slab_free = list(range(int(self._slab_dims()[1])))
+                self._slab_free = list(range(self._slab_dims()[0]))
             self._decode_reserved = 0
             dst.slots_in_use = 0
         out: List[Dict] = []
@@ -1716,29 +1724,24 @@ class ServingEngine:
         return min(_pow2_ceil(max(1, int(need_t))), cap)
 
     def _slab_dims(self):
-        """[2, Sb, H, Tslab, D] geometry of the live slab — works for
-        both the plain fp32 form and the int8 (payload, scale) form
-        (ISSUE 19), so every shape accessor below is quant-agnostic."""
-        return quant_mod.slab_shape(self._slab)
+        """(slots, sequence rung) of the live slab, as the model
+        states them: the slab's layout is the model's."""
+        return self.model.slab_dims(self._slab)
 
     def _decode_geom(self):
-        """(params, L, H, D, Sb, Tslab) read off the live slab."""
-        s0 = self._slab_dims()
-        return (self._decode_params, len(self._slab),
-                int(s0[2]), int(s0[4]), int(s0[1]), int(s0[3]))
+        """(params, slots, sequence rung) of the live slab."""
+        return (self._decode_params, *self._slab_dims())
 
     def _build_slab(self, need_t: int):
-        """Allocate the pooled KV cache + the decode-tier executables'
-        static geometry. The cache is a PER-LAYER list of
-        [2, Sb, H, Tslab, D] buffers (one stacked [L, ...] array would
-        cost a full extra slab pass per layer inside the fused step —
-        see `TransformerLM._slot_step`). Batch slots ride the PR 6
+        """Allocate the pooled cache: the model states its layout
+        (`new_slab`: which layers hold the context and climb the
+        sequence ladder, which a ring that does not), the engine says
+        how many slots and which rung. Batch slots ride the PR 6
         bucket ladder (`policy.bucket_batch(max_sessions)`); the
         sequence dim starts at the smallest ladder rung covering
         `need_t` and grows via `_grow_slab`. Returns
-        (params, L, H, D, Sb, Tslab)."""
+        (params, slots, sequence rung)."""
         import jax
-        import jax.numpy as jnp
 
         model = self.model
         # int8 decode tier (ISSUE 19): the quant mode is FROZEN at
@@ -1748,20 +1751,15 @@ class ServingEngine:
         self._decode_quant = (
             "int8" if quant_mod.enabled()
             and hasattr(model, "_decode_params_quant") else "off")
-        if self._decode_quant == "int8":
-            params = model._decode_params_quant()
-            embed = params["embed"][0]
-        else:
-            params = model._decode_params()
-            embed = params["embed"]
+        params = (model._decode_params_quant()
+                  if self._decode_quant == "int8"
+                  else model._decode_params())
         # every decode-tier call receives this tree: a leaf that is
         # not a device array is transferred again on each one
-        stats_mod.decode_stats().host_leaves_per_call = sum(
+        dst = stats_mod.decode_stats()
+        dst.host_leaves_per_call = sum(
             not isinstance(leaf, jax.Array)
             for leaf in jax.tree_util.tree_leaves(params))
-        L = len(params["blocks"])
-        H = model.blocks._seq[0].attn.num_heads
-        D = int(embed.shape[-1]) // H
         Sb = (self.policy.bucket_batch(self.max_sessions)
               if self.max_sessions <= self.policy.max_batch
               else _pow2_ceil(self.max_sessions))
@@ -1774,33 +1772,49 @@ class ServingEngine:
         # jit signature than the one warm_decode compiled
         device = self._device()
         self._slab_put = device.put
-        dev = device.jax_device
-        if self._decode_quant == "int8":
-            self._slab = [(jnp.zeros((2, Sb, H, Tslab, D), jnp.int8,
-                                     device=dev),
-                           jnp.zeros((2, Sb, Tslab), jnp.float32,
-                                     device=dev))
-                          for _ in range(L)]
-        else:
-            self._slab = [jnp.zeros((2, Sb, H, Tslab, D), embed.dtype,
-                                    device=dev)
-                          for _ in range(L)]
+        self._slab = model.new_slab(params, Sb, Tslab, device.jax_device)
+        self._note_slab_bytes(dst)
         self._slab_free = list(range(Sb))
         self._decode_params = params
-        return params, L, H, D, Sb, Tslab
+        return params, Sb, Tslab
+
+    def _note_slab_bytes(self, dst) -> None:
+        by_kind = self.model.slab_bytes(self._slab)
+        dst.cache_bytes_ring = int(by_kind["ring"])
+        dst.cache_bytes_context = int(by_kind["context"])
 
     def _grow_slab(self, need_t: int):
-        """Climb the sequence ladder mid-stream: zero-pad every layer
-        buffer out to the next rung covering `need_t`. Live rows carry
-        their K/V across the copy unchanged, and because every rung is
-        pow2 their remaining tokens still decode bit-identically to
-        `generate()` — growth is invisible to in-flight streams.
-        Returns the refreshed geometry."""
-        old_t = int(self._slab_dims()[3])
+        """Climb the sequence ladder mid-stream: the model pads what
+        holds the context out to the next rung covering `need_t` (a
+        ring stays as it is). Live rows carry their state across the
+        copy unchanged, and because every rung is pow2 their remaining
+        tokens still decode bit-identically to `generate()` — growth
+        is invisible to in-flight streams. Returns the refreshed
+        geometry."""
+        old_t = self._slab_dims()[1]
         new_t = self._slab_seq_bucket(need_t)
         if new_t > old_t:
-            self._slab = quant_mod.pad_slab_seq(self._slab, new_t)
+            self._slab = self.model.grow_slab(self._slab, new_t)
+            self._note_slab_bytes(stats_mod.decode_stats())
         return self._decode_geom()
+
+    def _slab_lost(self) -> bool:
+        """A program that donates the slab and then fails leaves its
+        input deleted: there is nothing to retry from."""
+        import jax
+
+        return self.model.donates_slab and any(
+            leaf.is_deleted()
+            for leaf in jax.tree_util.tree_leaves(self._slab))
+
+    def _rebuild_lost_slab(self) -> None:
+        """Fresh buffers at the lost slab's geometry (its sessions
+        have been failed by the caller), so queued work can go on."""
+        if self._slab_lost():
+            slots, seq = self._slab_dims()
+            self._slab = self.model.new_slab(
+                self._decode_params, slots, seq,
+                self._device().jax_device)
 
     def _decode_free_slot(self, sess: "_DecodeSession") -> None:
         """Return a session's slab row to the pool (lowest-index-first
@@ -1949,7 +1963,7 @@ class ServingEngine:
                             Pb_h)
                         if self._slab is None:
                             geom = self._build_slab(need_t)
-                        elif need_t > int(self._slab_dims()[3]):
+                        elif need_t > self._slab_dims()[1]:
                             geom = self._grow_slab(need_t)
                         if not self._slab_free:
                             break
@@ -2007,7 +2021,7 @@ class ServingEngine:
                     int(head.prompt.shape[1]) + head.n_new, kv_pos)
                 if self._slab is None:
                     self._build_slab(need_t)
-                elif need_t > int(self._slab_dims()[3]):
+                elif need_t > self._slab_dims()[1]:
                     self._grow_slab(need_t)
                 if not self._slab_free:
                     break
@@ -2118,7 +2132,7 @@ class ServingEngine:
             Bp = len(members)
             Bb = (pol.bucket_batch(Bp) if Bp <= pol.max_batch
                   else _pow2_ceil(Bp))
-            n_slots = int(self._slab_dims()[1])
+            n_slots = self._slab_dims()[0]
             ids = np.zeros((Bb, Pb), np.int32)
             nvec = np.ones(Bb, np.int32)
             slotv = np.full(Bb, n_slots, np.int32)  # OOB => dropped
@@ -2151,6 +2165,15 @@ class ServingEngine:
                 self._decode_fail_session(sess, dst,
                                           ServeDispatchError(
                     f"decode prefill failed: {e!r}"))
+            if self._slab_lost():
+                with self._decode_lock:
+                    live = list(self._decode_live.values())
+                for sess in live:
+                    self._decode_fail_session(sess, dst,
+                                              ServeDispatchError(
+                        f"the donated slab was lost to a failed "
+                        f"prefill: {e!r}"))
+                self._rebuild_lost_slab()
             return
         self._slab = new_slab
         now = time.perf_counter()
@@ -2255,7 +2278,7 @@ class ServingEngine:
         params = geom[0]
         put = self._slab_put
         with trace_mod.span("decode.step.assemble"):
-            Sb = int(self._slab_dims()[1])
+            Sb = self._slab_dims()[0]
             tokv = np.zeros(Sb, np.int32)
             posv = np.zeros(Sb, np.int32)
             for slot, sess in live:
@@ -2284,12 +2307,13 @@ class ServingEngine:
                             k)
                 with trace_mod.span("decode.step.readback", steps=k):
                     out = np.asarray(out)  # completes the dispatch
+                    counted = model.take_step_counters()
                 # logits [Sb, V] of a single step, tokens [k, Sb] of
                 # a run-ahead block
                 lg, toks = (out, None) if k == 1 else (None, out)
                 break
             except BaseException as e:  # noqa: BLE001 — retry below
-                if attempt >= self.max_retries:
+                if attempt >= self.max_retries or self._slab_lost():
                     # retries exhausted: the fused step is the only
                     # way forward for these sessions — fail them
                     # loudly, free every slot for queued work
@@ -2300,6 +2324,7 @@ class ServingEngine:
                             f"{attempt} retries: {e!r}"))
                     with self._decode_lock:
                         dst.slots_in_use = len(self._decode_live)
+                    self._rebuild_lost_slab()
                     return
                 attempt += 1
                 time.sleep(resilience.backoff_delay_s(
@@ -2317,6 +2342,8 @@ class ServingEngine:
             rate if not self._decode_tokens_ema
             else 0.8 * self._decode_tokens_ema + 0.2 * rate)
         dst.decode_steps += k
+        for name, n in counted.items():
+            setattr(dst, name, getattr(dst, name) + n)
         trace_mod.record_span("decode_step", t0, t0 + block_s,
                               rows=len(live), slots=Sb, steps=k)
         with trace_mod.span("decode.step.scatter"):
@@ -2358,7 +2385,7 @@ class ServingEngine:
                         examples=len(live) * k,
                         step_s=block_s, tier="decode",
                         sessions=len(live), slots=Sb, block=k,
-                        slab_seq=int(self._slab_dims()[3]),
+                        slab_seq=self._slab_dims()[1],
                         occupancy=round(len(live) / Sb, 4),
                         queue_depth=qdepth,
                         tokens_streamed=dst.tokens_streamed,

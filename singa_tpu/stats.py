@@ -673,6 +673,11 @@ class _DecodeStats:
         # `jax.Array` (counted once at slab build). Each one is a
         # host-to-device transfer on EVERY decode-tier call; must be 0
         self.host_leaves_per_call = 0
+        # gauges: the live slab's bytes by kind, as the model states
+        # them at `_build_slab` / `_grow_slab`: layers that hold a ring
+        # of window positions, layers that hold the whole context
+        self.cache_bytes_ring = 0
+        self.cache_bytes_context = 0
 
     def reset(self) -> None:
         self.cache.reset()
@@ -687,6 +692,13 @@ class _DecodeStats:
         self.tokens_streamed = 0
         self.decode_steps = 0   # fused decode_step dispatches
         self.prefills = 0       # prefill dispatches
+        # what a model's fused step counts itself (`DecodeLM.
+        # step_counter_names`), summed over decode steps and expert
+        # layers: assignments routed to an expert held here, held
+        # experts with at least one, and the fullest held expert's
+        self.moe_assignments_local = 0
+        self.moe_experts_touched = 0
+        self.moe_expert_load_max = 0
         # KV migration (ISSUE 17). `migrated` counts sessions exported
         # off this engine's books (each decrements `sessions` too, so
         # the 4-equation reconciliation stays exact per engine: the
@@ -711,11 +723,16 @@ class _DecodeStats:
             "tokens_streamed": self.tokens_streamed,
             "decode_steps": self.decode_steps,
             "prefills": self.prefills,
+            "moe_assignments_local": self.moe_assignments_local,
+            "moe_experts_touched": self.moe_experts_touched,
+            "moe_expert_load_max": self.moe_expert_load_max,
             "migrated": self.migrated,
             "resumed": self.resumed,
             "slots": self.slots,
             "slots_in_use": self.slots_in_use,
             "host_leaves_per_call": self.host_leaves_per_call,
+            "cache_bytes_ring": self.cache_bytes_ring,
+            "cache_bytes_context": self.cache_bytes_context,
         })
         return out
 

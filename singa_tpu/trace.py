@@ -812,7 +812,7 @@ class MetricsLogger:
     _GAUGE_KEYS = frozenset({
         # decode slot pool / LRU cache occupancy
         "slots", "slots_in_use", "size", "negative_size", "capacity",
-        "host_leaves_per_call",
+        "host_leaves_per_call", "cache_bytes_ring", "cache_bytes_context",
         # serve queue live state, watermarks, derived ratios
         "queue_depth", "max_queue_depth", "effective_wait_ms",
         "coalesce_mean", "occupancy", "max_coalesce",

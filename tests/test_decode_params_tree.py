@@ -160,11 +160,33 @@ def test_changed_eps_traces_a_new_program_never_a_stale_one():
     assert np.abs(after - before).max() > 1e-3
 
 
+def _build_hybrid(seed=3):
+    """The hybrid window/full mixture-of-experts LM at a toy size: its
+    tree holds the sinks, the router's bias and every expert's
+    matrices beside the norms' gains."""
+    from singa_tpu.models.hybrid_moe import HybridWindowMoELM
+
+    dev = device.get_default_device()
+    dev.SetRandSeed(seed)
+    m = HybridWindowMoELM(
+        V, d_model=D, num_heads=4, head_dim=12, v_head_dim=8,
+        kv_heads_full=1, kv_heads_window=2, window=4, rotary_dim=4,
+        layer_pattern=(0, 1, 0), moe_layers=(0, 1, 1), d_ff=64,
+        d_ff_expert=16, n_experts=8, experts_per_token=2, held=(2, 2),
+        max_len=MAXLEN, init_std=0.3)
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32),
+                                 device=dev)],
+              is_train=False, use_graph=False)
+    m.eval()
+    return m
+
+
 @pytest.mark.parametrize("norm,quant", [("layer", "off"),
                                         ("layer", "int8"),
-                                        ("rms", "off")])
+                                        ("rms", "off"),
+                                        ("hybrid", "off")])
 def test_warmed_engine_counts_no_host_leaf(norm, quant):
-    m = _build(norm)
+    m = _build_hybrid() if norm == "hybrid" else _build(norm)
     prompt = np.array([[3, 1, 4]], np.int32)
     device.set_inference_quant(quant)
     dst = stats.decode_stats()
@@ -178,5 +200,11 @@ def test_warmed_engine_counts_no_host_leaf(norm, quant):
         eng.stop()
         device.set_inference_quant("off")
     assert stats.cache_stats()["decode"]["host_leaves_per_call"] == 0
-    if quant == "off":  # a slab row is generate(), bit for bit
+    if norm == "hybrid":
+        import jax
+
+        assert all(isinstance(leaf, jax.Array) for leaf in
+                   jax.tree_util.tree_leaves(m._decode_params()))
+        assert np.asarray(got).shape == (1, 7)
+    elif quant == "off":  # a slab row is generate(), bit for bit
         assert np.array_equal(np.asarray(got), m.generate(prompt, 4))
