@@ -1653,18 +1653,13 @@ def stage_serve_decode(sessions, deadline_s, rate=0.0, chaos=False,
 
         from singa_tpu import hlo_profile
 
-        Dh, Tq = D // H, 16
+        Tq = 16
         tokq = jnp.zeros((MAXS,), jnp.int32)
         posq = jnp.zeros((MAXS,), jnp.int32)
-        cache_fp = [jnp.zeros((2, MAXS, H, Tq, Dh), jnp.float32)
-                    for _ in range(L)]
-        cache_q = [(jnp.zeros((2, MAXS, H, Tq, Dh), jnp.int8),
-                    jnp.zeros((2, MAXS, Tq), jnp.float32))
-                   for _ in range(L)]
-        b_fp = hlo_profile.bytes_accessed(m.decode_step_hlo(
-            m._decode_params(), cache_fp, tokq, posq))["total"]
-        b_q = hlo_profile.bytes_accessed(m.decode_step_hlo(
-            m._decode_params_quant(), cache_q, tokq, posq))["total"]
+        b_fp, b_q = (
+            hlo_profile.bytes_accessed(m.decode_step_hlo(
+                p, m.new_slab(p, MAXS, Tq, None), tokq, posq))["total"]
+            for p in (m._decode_params(), m._decode_params_quant()))
         qbytes = {"fp32": int(b_fp), "int8": int(b_q),
                   "ratio": round(b_q / b_fp, 4) if b_fp else None,
                   "strictly_lower": bool(b_q < b_fp)}

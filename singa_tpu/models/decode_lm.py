@@ -14,9 +14,11 @@ A subclass provides its mathematics and states its slab:
   _slab_extra`                          the cache's geometry
 
 `serve.py` asks the model for the geometry and never reads a layer's
-head count itself. `donates_slab` says whether the programs that take
-the slab donate it (the caller then keeps only the slab they return);
-`scan_unroll` whether a run-ahead block is a loop or its steps in a row.
+head count or axis itself. Every program that takes the slab DONATES it
+and updates it where it lies: the caller keeps only the slab a program
+returns, and a program that fails after it was dispatched leaves none
+(`ServingEngine._slab_lost`). `scan_unroll` says whether a run-ahead
+block is a loop or its steps in a row.
 """
 from __future__ import annotations
 
@@ -26,7 +28,6 @@ from .. import autograd, model
 class DecodeLM(model.Model):
     """Base of `TransformerLM` and `HybridWindowMoELM`."""
 
-    donates_slab = False
     # int32 vector a step may return beside (logits, slab): summed on
     # the host into `stats.cache_stats()["decode"]` under these names
     step_counter_names = ()
@@ -97,21 +98,18 @@ class DecodeLM(model.Model):
                                                args)
             if exp is None:
                 return self._count_first_trace(jitted)
-        return jax.jit(exp.call, donate_argnums=self._donate())
-
-    def _donate(self):
-        return (1,) if self.donates_slab else ()
+        return jax.jit(exp.call, donate_argnums=(1,))
 
     def _slab_program(self, kind, key_, fn, args, extras):
         """The cached executable of one slab-taking program (argument
-        1 is the slab, donated where the model says so)."""
+        1 is the slab, donated)."""
         cache_dict = self._program_cache()
         key_ = key_ + (self._slab_sig(args[1]), self._trace_key())
         hit = cache_dict.get(key_)
         if hit is None:
             import jax
 
-            jitted = jax.jit(fn, donate_argnums=self._donate())
+            jitted = jax.jit(fn, donate_argnums=(1,))
             hit = self._aot_step(
                 kind, jitted, args,
                 extras={**extras, "slab": self._slab_extra(args[1]),
@@ -154,12 +152,12 @@ class DecodeLM(model.Model):
 
     def decode_scan(self, params, cache, tok, pos, k):
         """`k` GREEDY fused decode steps in ONE program (`lax.scan`
-        over `_slot_step` + in-graph argmax). XLA updates the scan's
-        cache carry in place — the per-dispatch whole-slab copy that
-        JAX's CPU backend cannot elide (no buffer donation) is paid
-        once per BLOCK instead of once per token, which is where the
-        serving tier's throughput win over sequential `generate()`
-        comes from. In-graph `jnp.argmax` is the exact greedy program
+        over `_slot_step` + in-graph argmax). The scan's cache carry
+        is the donated slab, updated in place step after step, so a
+        block costs its steps and one dispatch, one readback of
+        [k, B] tokens instead of k of [B, V] logits: that, and not a
+        saved copy, is what a block is for. In-graph `jnp.argmax` is
+        the exact greedy program
         `generate()` scans with (and equals host `np.argmax` on
         identical logits bits — both first-max-wins), so a block
         decodes bit-identically to k single steps. Returns

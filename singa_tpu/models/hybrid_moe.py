@@ -62,7 +62,6 @@ def _drawer(shape, dtype, std):
 class HybridWindowMoELM(DecodeLM):
     """Causal LM over int token ids [B, S] -> logits [B, S, vocab]."""
 
-    donates_slab = True
     # a run-ahead block is its steps in a row, not a loop: around a
     # loop XLA re-lays every held expert's gate and up matrices out
     # (12 copies of 0.83 ms a block on the chip, which the single step

@@ -413,27 +413,31 @@ class TransformerLM(DecodeLM):
         """One fused decode step over every batch slot at PER-ROW
         positions: row b writes its K/V at cache slot (b, pos[b]) and
         attends slots 0..pos[b]. `cache` is a PER-LAYER list of
-        [2, B, H, T, D] arrays — one buffer per layer, not one stacked
-        [L, ...] slab — so XLA:CPU never materialises a whole-slab
-        copy per layer (`at[li].set` on a stacked slab costs a full
-        slab pass per layer; the per-layer list halves steady-state
-        step time). The op sequence mirrors `_stack_step` S=1 exactly
-        (same matmul/einsum forms, same mask constant) so a slab row
-        decodes bitwise identically to the same request running alone
-        through `generate()`. Returns (logits [B, V], new per-layer
-        cache list)."""
+        [2, B, H, D, T] arrays (`new_slab`): one buffer per layer, so
+        a layer's write touches that layer alone, and positions LAST,
+        which is how the chip lays a layer out whatever its shape
+        says. Both rows of a step (key and value) go in through one
+        `cache_write`, in place in the donated slab, and the two
+        attention products contract against the layer as stored: no
+        operation of the program moves a whole layer but that write
+        (`tests/test_tpu_compile_widths.py`; in [2, B, H, T, D] the
+        write was re-laid out and back, 24 whole-layer copies a
+        program). The mathematics is `_stack_step`'s at S=1 (same
+        products over the same float32 values, same mask constant),
+        so a slab row decodes the same request's `generate()` stream.
+        Returns (logits [B, V], new per-layer cache list)."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
+
+        from ..ops.pallas_kernels import cache_write
 
         H = self.blocks._seq[0].attn.num_heads
         B = tok.shape[0]
         # quantized slab (ISSUE 19): per-layer (payload int8
-        # [2,B,H,T,D], scale f32 [2,B,T]) tuples instead of plain
-        # fp32 arrays — the update copy that dominates the step's
-        # byte traffic shrinks 4x
+        # [2,B,H,D,T], scale f32 [2,B,T]) tuples instead of plain
+        # fp32 arrays, written and read through the same code
         qcache = isinstance(cache[0], tuple)
-        maxT = (cache[0][0] if qcache else cache[0]).shape[-2]
+        maxT = quant_mod.slab_payload(cache[0]).shape[-1]
         h = self._table(params["embed"], tok[:, None]) \
             + self._table(params["pos"], pos)[:, None]
         E = h.shape[-1]
@@ -442,6 +446,7 @@ class TransformerLM(DecodeLM):
         # row b (absolute position pos[b]) may attend slot j <= pos[b]
         mask = pos[:, None] >= jnp.arange(maxT)[None, :]      # [B, maxT]
         neg = jnp.asarray(jnp.finfo(h.dtype).min / 2, h.dtype)
+        at = jnp.concatenate([pos, pos])
         new_cache = []
 
         prec = tensor.get_matmul_precision()
@@ -457,56 +462,41 @@ class TransformerLM(DecodeLM):
                 y = jnp.matmul(x, w, precision=prec)
             return y if b is None else y + b
 
+        def write(layer, kv):
+            # layer [2,B,H,D,T], kv [2,B,H,D]: keys are rows 0..B-1 of
+            # the kernel's [2B,H,D,T] view, values rows B..2B-1
+            return cache_write(layer.reshape((2 * B,) + layer.shape[2:]),
+                               kv.reshape(2 * B, H, D), at,
+                               axis=3).reshape(layer.shape)
+
         *blk_eps, eps_f = self._norm_eps()
         for li, blk in enumerate(params["blocks"]):
             eps1, eps2 = blk_eps[li]
             x = self._ln(h, blk["ln1"], eps1)
-
-            def split(t):  # [B,1,E] -> [B,H,1,D]
-                return t.reshape(B, 1, H, D).transpose(0, 2, 1, 3)
-
-            q = split(lin(x, blk["q"]))
-            kk = split(lin(x, blk["k"]))
-            vv = split(lin(x, blk["v"]))
-            kv = jnp.stack([kk, vv])                  # [2,B,H,1,D]
-
-            def upd(c_row, kv_row, p):
-                # c_row [2,H,T,D], kv_row [2,H,1,D]: write at slot p
-                return lax.dynamic_update_slice(c_row, kv_row,
-                                                (0, 0, p, 0))
-
+            q = lin(x, blk["q"]).reshape(B, H, D)
+            kv = jnp.stack([lin(x, blk["k"]).reshape(B, H, D),
+                            lin(x, blk["v"]).reshape(B, H, D)])
             if qcache:
                 # same per-position quantization as the chunked
                 # prefill form (reduce over H, D) — the replay
                 # bit-exactness lever
-                qkv, sc = quant_mod.quantize_kv(kv)   # sc [2,B,1]
+                qkv, sc = quant_mod.quantize_kv(
+                    kv[:, :, :, None, :])             # sc [2,B,1]
                 payload, scp = cache[li]
-                new_pay = jax.vmap(upd, in_axes=(1, 1, 0),
-                                   out_axes=1)(payload, qkv, pos)
-
-                def upds(s_row, sc_row, p):
-                    # s_row [2,T], sc_row [2,1]: write at slot p
-                    return lax.dynamic_update_slice(s_row, sc_row,
-                                                    (0, p))
-
-                new_sc = jax.vmap(upds, in_axes=(1, 1, 0),
-                                  out_axes=1)(scp, sc, pos)
+                new_pay = write(payload, qkv[:, :, :, 0, :])
+                new_sc = jnp.where(pos[:, None] == jnp.arange(maxT), sc,
+                                   scp)
                 new_cache.append((new_pay, new_sc))
-                kv_all = quant_mod.dequantize_kv(new_pay, new_sc)
-                k_all, v_all = kv_all[0], kv_all[1]
+                kv_all = quant_mod.dequantize_slab(new_pay, new_sc)
             else:
-                new_li = jax.vmap(upd, in_axes=(1, 1, 0), out_axes=1)(
-                    cache[li], kv, pos)
-                new_cache.append(new_li)
-                k_all = new_li[0]
-                v_all = new_li[1]
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k_all,
+                kv_all = write(cache[li], kv)
+                new_cache.append(kv_all)
+            s = jnp.einsum("bhd,bhdk->bhk", q, kv_all[0],
                            precision=prec) * scale
-            s = jnp.where(mask[:, None, None], s, neg)
+            s = jnp.where(mask[:, None], s, neg)
             p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhqk,bhkd->bhqd", p, v_all, precision=prec)
-            o = o.transpose(0, 2, 1, 3).reshape(B, 1, E)
-            h = h + lin(o, blk["o"])
+            o = jnp.einsum("bhk,bhdk->bhd", p, kv_all[1], precision=prec)
+            h = h + lin(o.reshape(B, 1, E), blk["o"])
             x = self._ln(h, blk["ln2"], eps2)
             h = h + lin(jax.nn.gelu(lin(x, blk["fc1"]),
                                     approximate=False), blk["fc2"])
@@ -575,59 +565,63 @@ class TransformerLM(DecodeLM):
     def _prefill_rows(self, params, slab, ids, n_real, slots):
         """`prefill_slab`'s program: `_stack_step` runs `ids` [Bp, Pb]
         against a fresh Pb-wide cache materialised in-graph, and every
-        layer's rows land in the slab via one scatter. The slab keeps
-        its stale tail beyond Pb; decode overwrites position p before
-        any query attends it (see `prefill_step`'s pad argument)."""
+        layer's rows land in the donated slab, in place, via one
+        scatter (a row whose slot is out of bounds is dropped by it).
+        Only the cohort's rows are turned positions-last on the way.
+        The slab keeps its stale tail beyond Pb; decode overwrites
+        position p before any query attends it (see `prefill_step`'s
+        pad argument)."""
+        import jax
         import jax.numpy as jnp
 
         L = len(slab)
         qslab = quant_mod.is_quant_cache(slab)
-        c0 = slab[0][0] if qslab else slab[0]
-        H, D = int(c0.shape[2]), int(c0.shape[4])
+        c0 = quant_mod.slab_payload(slab[0])
+        H, D = int(c0.shape[2]), int(c0.shape[3])
         Bp, Pb = ids.shape
+        # fresh Pb-wide cache in-graph, in the slab's form: the
+        # chunked _stack_step writes the same payload + scale planes
+        # the per-step chain would (see quantize_kv)
+        c1 = jnp.zeros((L, 2, Bp, H, Pb, D), c0.dtype)
         if qslab:
-            # fresh Pb-wide QUANTIZED cache in-graph: the chunked
-            # _stack_step writes the same payload + scale planes the
-            # per-step chain would (see quantize_kv), then both planes
-            # scatter into the slab rows in one program
-            c1 = (jnp.zeros((L, 2, Bp, H, Pb, D), jnp.int8),
-                  jnp.zeros((L, 2, Bp, Pb), jnp.float32))
-            logits, (pay, sc) = self._stack_step(
-                params, ids, c1, 0, last_index=n_real - 1)
-            return logits, [
-                (slab[li][0].at[:, slots, :, :Pb, :].set(pay[li]),
-                 slab[li][1].at[:, slots, :Pb].set(sc[li]))
-                for li in range(L)]
-        c1 = jnp.zeros((L, 2, Bp, H, Pb, D), slab[0].dtype)
+            c1 = (c1, jnp.zeros((L, 2, Bp, Pb), jnp.float32))
         logits, c1 = self._stack_step(params, ids, c1, 0,
                                       last_index=n_real - 1)
-        return logits, [slab[li].at[:, slots, :, :Pb, :].set(c1[li])
-                        for li in range(L)]
+        rows = jnp.swapaxes(c1[0] if qslab else c1, -1, -2)
+        if qslab:
+            rows = (rows, c1[1])
+        # leaf by leaf (a layer, or its payload and its scales): the
+        # cohort's first Pb positions of rows `slots`
+        return logits, [
+            jax.tree_util.tree_map(
+                lambda a, r: a.at[:, slots, ..., :Pb].set(r[li]),
+                slab[li], rows)
+            for li in range(L)]
 
     # -- the slab, as serve.py asks for it -------------------------------
     _slab_sig = staticmethod(quant_mod.cache_sig)
 
     def new_slab(self, params, slots, seq, device):
-        """A per-layer list of [2, slots, H, seq, D] buffers (one
-        stacked [L, ...] array would cost a full extra slab pass per
-        layer inside the fused step — see `_slot_step`), born on
-        `device`; for int8 params the (payload, scale) form."""
+        """A per-layer list of [2, slots, H, D, seq] buffers, keys at
+        [0] and values at [1], positions last (`_slot_step` says why;
+        one buffer per layer, so that a write touches one layer),
+        born on `device`; for int8 params the (payload, scale
+        [2, slots, seq]) form in the same geometry."""
         import jax.numpy as jnp
 
         quant = isinstance(params["embed"], tuple)
         embed = params["embed"][0] if quant else params["embed"]
-        L = len(params["blocks"])
         H = self.blocks._seq[0].attn.num_heads
-        D = int(embed.shape[-1]) // H
-        if quant:
-            return [(jnp.zeros((2, slots, H, seq, D), jnp.int8,
-                               device=device),
-                     jnp.zeros((2, slots, seq), jnp.float32,
-                               device=device))
-                    for _ in range(L)]
-        return [jnp.zeros((2, slots, H, seq, D), embed.dtype,
-                          device=device)
-                for _ in range(L)]
+        shape = (2, slots, H, int(embed.shape[-1]) // H, seq)
+
+        def layer():
+            if quant:
+                return (jnp.zeros(shape, jnp.int8, device=device),
+                        jnp.zeros((2, slots, seq), jnp.float32,
+                                  device=device))
+            return jnp.zeros(shape, embed.dtype, device=device)
+
+        return [layer() for _ in params["blocks"]]
 
     grow_slab = staticmethod(quant_mod.pad_slab_seq)
 
@@ -635,7 +629,7 @@ class TransformerLM(DecodeLM):
     def slab_dims(slab):
         """(slots, sequence rung) of either slab form."""
         s0 = quant_mod.slab_shape(slab)
-        return int(s0[1]), int(s0[3])
+        return int(s0[1]), int(s0[4])
 
     @staticmethod
     def slab_bytes(slab):
@@ -643,39 +637,42 @@ class TransformerLM(DecodeLM):
         import jax
 
         return {"ring": 0, "context": sum(
-            leaf.nbytes for leaf in jax.tree_util.tree_leaves(slab))}
+            leaf.size * leaf.dtype.itemsize
+            for leaf in jax.tree_util.tree_leaves(slab))}
 
     def export_slab_rows(self, slab, slot, pos):
         """Snapshot one session's live K/V out of the decode slab as a
         single host array [L, 2, H, pos, D] — the portable half of KV
-        migration. Pure host-side gather (no compile): the slab leaves
-        are device arrays, `np.asarray` forces the transfer, and only
-        the first `pos` sequence rows are real (the tail past `pos` is
-        stale garbage decode would overwrite anyway, so it never
-        crosses the wire). A QUANTIZED slab exports the PACKED form —
-        (payload int8 [L, 2, H, pos, D], scale f32 [L, 2, pos]) — so
-        live migration ships ~4x fewer bytes (ISSUE 19)."""
+        migration, and the wire form whatever the slab's own layout
+        (transposed on the host, here). Pure host-side gather (no
+        compile): the slab leaves are device arrays, `np.asarray`
+        forces the transfer, and only the first `pos` positions are
+        real (the tail past `pos` is stale garbage decode would
+        overwrite anyway, so it never crosses the wire). A QUANTIZED
+        slab exports the PACKED form — (payload int8
+        [L, 2, H, pos, D], scale f32 [L, 2, pos]) — so live migration
+        ships ~4x fewer bytes (ISSUE 19)."""
         if quant_mod.is_quant_cache(slab):
             quant_mod.stats_counters()["packed_kv_exports"] += 1
             return quant_mod.pack_slab_rows(slab, slot, pos)
-        return np.stack(
-            [np.asarray(c[:, slot, :, :pos, :]) for c in slab])
+        return quant_mod.rows_to_wire(slab, slot, pos)
 
     def import_slab_rows(self, slab, slot, rows):
         """Transplant `export_slab_rows` output into row `slot` of a
-        (possibly different-geometry) slab, returning the new slab.
-        The seq dim is zero-padded host-side to the target's rung so
-        ONE executable per slab geometry serves every (slot, pos)
-        pair — `slot` is traced, and the stale-tail argument from
+        (possibly different-geometry) slab, in place in the donated
+        slab, returning the new slab. The rows are turned positions-
+        last and zero-padded host-side to the target's rung so ONE
+        executable per slab geometry serves every (slot, pos) pair —
+        `slot` is traced, and the stale-tail argument from
         `prefill_slab` makes the zero padding exact: decode overwrites
         position p before any query attends it. Requires the target
         rung to cover `pos` (serve sizes the rung from the session's
         own prompt+budget, which migration preserves). A QUANTIZED
         slab takes the PACKED pair `export_slab_rows` produced —
         (payload, scale) — and transplants both planes; mixing forms
-        (packed rows into an fp32 slab or vice versa) raises."""
+        (packed rows into an fp32 slab or vice versa) raises, before
+        anything is dispatched."""
         import jax
-        import jax.numpy as jnp
 
         L = len(slab)
         qslab = quant_mod.is_quant_cache(slab)
@@ -687,9 +684,8 @@ class TransformerLM(DecodeLM):
                 f"{'int8-packed' if qrows else 'fp32'} — the quant "
                 "mode must match across a migration (it rides the "
                 "fleet spec and knob_fingerprint)")
-        c0 = slab[0][0] if qslab else slab[0]
-        H, Ts, D = (int(c0.shape[2]), int(c0.shape[3]),
-                    int(c0.shape[4]))
+        c0 = quant_mod.slab_payload(slab[0])
+        H, D, Ts = (int(n) for n in c0.shape[2:])
         pay = rows[0] if qslab else rows
         t = int(pay.shape[3])
         if pay.shape[0] != L or pay.shape[2] != H \
@@ -701,26 +697,20 @@ class TransformerLM(DecodeLM):
         key_ = ("import_slab", quant_mod.cache_sig(slab))
         fn = cache_dict.get(key_)
         if fn is None:
-            if qslab:
-                fn = jax.jit(lambda sl, r, s: [
-                    (sl[li][0].at[:, s, :, :, :].set(r[0][li]),
-                     sl[li][1].at[:, s, :].set(r[1][li]))
-                    for li in range(L)])
-            else:
-                fn = jax.jit(lambda sl, r, s: [
-                    sl[li].at[:, s, :, :, :].set(r[li])
-                    for li in range(L)])
+            def put_rows(sl, r, s):
+                # leaf by leaf: a layer, or its payload and its scales
+                return [jax.tree_util.tree_map(
+                    lambda a, x: a.at[:, s].set(x[li]), layer, r)
+                    for li, layer in enumerate(sl)]
+
+            fn = jax.jit(put_rows, donate_argnums=(0,))
             cache_dict[key_] = fn
+        padded = quant_mod.rows_from_wire(
+            np.asarray(pay, c0.dtype), Ts)
         if qslab:
-            sc = rows[1]
-            ppay = np.zeros((L, 2, H, Ts, D), np.int8)
-            ppay[:, :, :, :t, :] = pay
             psc = np.zeros((L, 2, Ts), np.float32)
-            psc[:, :, :t] = sc
-            return fn(list(slab), (ppay, psc), np.int32(slot))
-        dt = np.asarray(slab[0]).dtype
-        padded = np.zeros((L, 2, H, Ts, D), dt)
-        padded[:, :, :, :t, :] = rows
+            psc[:, :, :t] = rows[1]
+            padded = (padded, psc)
         return fn(list(slab), padded, np.int32(slot))
 
     def _shard_decode_params(self, params, mesh):

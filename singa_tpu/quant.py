@@ -64,11 +64,19 @@ def dequantize_weight(q, scale):
 
 # -- KV-slab helpers --------------------------------------------------
 #
-# A quantized slab is a per-layer list of (payload, scale) tuples:
-#   payload int8  [2, B, H, T, D]
+# A layer of the decode slab holds POSITIONS LAST: [2, B, H, D, T]
+# (keys at [0], values at [1]). The chip keeps the positions in the
+# lanes whatever the program says (a 64-wide axis is never minor
+# there), so this is the layout the attention products read and the
+# one `cache_write` updates in place; any other is re-laid around
+# every write (PERF.md, PR 28). A quantized slab is a per-layer list
+# of (payload, scale) tuples in the same geometry:
+#   payload int8  [2, B, H, D, T]
 #   scale   f32   [2, B, T]      (reduced over H and D per position)
 # Plain tuples, not a custom pytree class: jax.export serializes the
 # builtin containers, so the AOT decode ladder works unchanged.
+# `generate()`'s own stacked cache stays [L, 2, B, H, T, D], and so
+# does the wire form of a migrated row ([L, 2, H, pos, D], below).
 
 def is_quant_cache(cache) -> bool:
     """True when `cache` is a quantized per-layer slab (list of
@@ -85,45 +93,32 @@ def cache_sig(cache):
         return (tuple(tuple(p.shape) for p, _ in cache)
                 + tuple(tuple(s.shape) for _, s in cache),
                 "int8+scale")
-    import jax.numpy as jnp
-
     return (tuple(tuple(c.shape) for c in cache),
-            jnp.asarray(cache[0]).dtype.name)
+            np.dtype(cache[0].dtype).name)
+
+
+def slab_payload(layer):
+    """The [2, B, H, D, T] array of one layer, for either slab form."""
+    return layer[0] if isinstance(layer, tuple) else layer
 
 
 def slab_shape(slab):
-    """[2, B, H, T, D] geometry of layer 0, for either slab form."""
-    c = slab[0]
-    return tuple((c[0] if isinstance(c, tuple) else c).shape)
-
-
-def alloc_slab(L, B, H, T, D, dtype):
-    """Allocate a fresh decode slab in the ACTIVE quant mode: plain
-    f32 arrays when off, (int8 payload, f32 scale) tuples when int8."""
-    import jax.numpy as jnp
-
-    if enabled():
-        return [(jnp.zeros((2, B, H, T, D), jnp.int8),
-                 jnp.zeros((2, B, T), jnp.float32))
-                for _ in range(L)]
-    return [jnp.zeros((2, B, H, T, D), dtype) for _ in range(L)]
+    """[2, B, H, D, T] geometry of layer 0, for either slab form."""
+    return tuple(slab_payload(slab[0]).shape)
 
 
 def pad_slab_seq(slab, new_t):
-    """Zero-pad the seq dim of either slab form to `new_t` (the
-    `_grow_slab` path). Stale-tail argument makes zeros exact."""
+    """Zero-pad the positions (the last axis of every array of either
+    slab form) to `new_t` (the `_grow_slab` path). Stale-tail argument
+    makes zeros exact."""
+    import jax
     import jax.numpy as jnp
 
-    if is_quant_cache(slab):
-        out = []
-        for p, s in slab:
-            dt = new_t - int(p.shape[3])
-            out.append((jnp.pad(p, ((0, 0),) * 3 + ((0, dt), (0, 0))),
-                        jnp.pad(s, ((0, 0), (0, 0), (0, dt)))))
-        return out
-    pad = ((0, 0), (0, 0), (0, 0), (0, new_t - int(slab[0].shape[3])),
-           (0, 0))
-    return [jnp.pad(c, pad) for c in slab]
+    def grown(a):
+        return jnp.pad(a, ((0, 0),) * (a.ndim - 1)
+                       + ((0, new_t - int(a.shape[-1])),))
+
+    return jax.tree_util.tree_map(grown, list(slab))
 
 
 def quantize_kv(kv, axes=(2, 4)):
@@ -143,10 +138,20 @@ def quantize_kv(kv, axes=(2, 4)):
 
 
 def dequantize_kv(payload, scale):
-    """[2,B,H,T,D] int8 + [2,B,T] f32 → f32 [2,B,H,T,D]."""
+    """[2,B,H,T,D] int8 + [2,B,T] f32 → f32 [2,B,H,T,D]: the layout of
+    `generate()`'s own cache and of a chunk before it is stored (a
+    slab layer, positions last, takes `dequantize_slab`)."""
     import jax.numpy as jnp
 
     return payload.astype(jnp.float32) * scale[:, :, None, :, None]
+
+
+def dequantize_slab(payload, scale):
+    """A slab layer: [2,B,H,D,T] int8 + [2,B,T] f32 → f32 [2,B,H,D,T];
+    the same product per element as `dequantize_kv`."""
+    import jax.numpy as jnp
+
+    return payload.astype(jnp.float32) * scale[:, :, None, None, :]
 
 
 # -- model-level quantized decode params ------------------------------
@@ -259,14 +264,31 @@ def calibrate(model, batch, *, seed: int = 0):
 # fleet_proc.encode_tree ships numpy leaves natively, so the packed
 # pair rides MIGRATE/RESUME frames without codec changes.
 
+def rows_to_wire(slab, slot, pos):
+    """One session's first `pos` positions out of every layer's
+    [2, B, H, D, T] array (plain layer or payload) as one host array
+    in the wire form [L, 2, H, pos, D]: transposed here, at the edge,
+    so a frame reads the same whatever layout the slab has."""
+    return np.ascontiguousarray(np.stack([
+        np.asarray(slab_payload(c)[:, slot, :, :, :pos])
+        for c in slab]).transpose(0, 1, 2, 4, 3))
+
+
+def rows_from_wire(rows, seq):
+    """The wire form [L, 2, H, pos, D] as the slab stores a row:
+    [L, 2, H, D, seq], zeros past `pos`."""
+    L, two, H, t, D = rows.shape
+    out = np.zeros((L, two, H, D, seq), rows.dtype)
+    out[..., :t] = rows.transpose(0, 1, 2, 4, 3)
+    return out
+
+
 def pack_slab_rows(slab, slot, pos):
     """Quantized counterpart of `export_slab_rows`: host-side gather
     of one session's live rows in PACKED form. Returns
     (payload int8 [L, 2, H, pos, D], scale f32 [L, 2, pos])."""
-    pay = np.stack([np.asarray(p[:, slot, :, :pos, :])
-                    for p, _ in slab])
     sc = np.stack([np.asarray(s[:, slot, :pos]) for _, s in slab])
-    return pay, sc
+    return rows_to_wire(slab, slot, pos), sc
 
 
 def stats_counters():

@@ -1450,16 +1450,13 @@ class ServingEngine:
         put = self._slab_put
         tok = put(np.zeros(Sb, np.int32))
         pos = put(np.zeros(Sb, np.int32))
-        # a model whose programs donate the slab leaves only the one
-        # they return; what the warm steps wrote there is stale state
-        # no query attends (prefill_slab's argument)
-        keep = model.donates_slab
-
+        # every program donates the slab and leaves only the one it
+        # returns; what the warm steps wrote there is stale state no
+        # query attends (prefill_slab's argument)
         def ran(out, slab):
             np.asarray(out)
             model.take_step_counters()
-            if keep:
-                self._slab = slab
+            self._slab = slab
             return out
 
         lg = ran(*model.decode_step(params, self._slab, tok, pos))
@@ -1518,9 +1515,10 @@ class ServingEngine:
 
         If the decode dispatcher is HUNG mid-step past the drain
         timeout, live sessions export WITHOUT their KV (ledger replay
-        on the target) — the slab may be mid-write and a torn KV row
-        is exactly the corruption migration must never ship;
-        correctness first, the KV transplant is only the fast path.
+        on the target) — the slab may be mid-write (or, donated to the
+        program that hangs, deleted) and a torn KV row is exactly the
+        corruption migration must never ship; correctness first, the
+        KV transplant is only the fast path.
         Checkpoint leaves are numpy arrays / scalars / None only, so
         the dict crosses `fleet_proc.encode_tree` unchanged."""
         with self._decode_lock:
@@ -1542,6 +1540,12 @@ class ServingEngine:
             slab = self._slab
             if slab is not None:
                 self._slab_free = list(range(self._slab_dims()[0]))
+                if self._slab_lost():
+                    # a dispatcher hung (or died) inside a program
+                    # holds the donated slab: nothing to export, every
+                    # session goes by replay, the next one finds a slab
+                    self._rebuild_lost_slab()
+                    slab = None
             self._decode_reserved = 0
             dst.slots_in_use = 0
         out: List[Dict] = []
@@ -1799,13 +1803,13 @@ class ServingEngine:
         return self._decode_geom()
 
     def _slab_lost(self) -> bool:
-        """A program that donates the slab and then fails leaves its
-        input deleted: there is nothing to retry from."""
+        """A program donates the slab, so one that fails after its
+        dispatch leaves its input deleted: there is nothing to retry
+        from. (A failure before dispatch leaves the slab as it was.)"""
         import jax
 
-        return self.model.donates_slab and any(
-            leaf.is_deleted()
-            for leaf in jax.tree_util.tree_leaves(self._slab))
+        return any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(self._slab))
 
     def _rebuild_lost_slab(self) -> None:
         """Fresh buffers at the lost slab's geometry (its sessions
@@ -1815,6 +1819,19 @@ class ServingEngine:
             self._slab = self.model.new_slab(
                 self._decode_params, slots, seq,
                 self._device().jax_device)
+
+    def _fail_live_if_slab_lost(self, dst, what: str, e) -> None:
+        """After a failed `what` (a prefill, an import) that is not
+        the live sessions' own program: if it took the donated slab
+        with it, their state is gone too, so fail them and rebuild."""
+        if not self._slab_lost():
+            return
+        self._rebuild_lost_slab()
+        with self._decode_lock:
+            live = list(self._decode_live.values())
+        for sess in live:
+            self._decode_fail_session(sess, dst, ServeDispatchError(
+                f"the donated slab was lost to a failed {what}: {e!r}"))
 
     def _decode_free_slot(self, sess: "_DecodeSession") -> None:
         """Return a session's slab row to the pool (lowest-index-first
@@ -2049,9 +2066,10 @@ class ServingEngine:
         try:
             self._slab = self.model.import_slab_rows(
                 self._slab, slot, kv)
-        except BaseException:  # noqa: BLE001 — demote to replay
+        except BaseException as e:  # noqa: BLE001 — demote to replay
             sess.resume_kv = None
             self._release_slot(slot)
+            self._fail_live_if_slab_lost(dst, "import", e)
             with self._decode_lock:
                 self._dqueue.appendleft(sess)
             return False
@@ -2158,22 +2176,15 @@ class ServingEngine:
                 lg = np.asarray(logits)
         except BaseException as e:  # noqa: BLE001 — isolate: a failed
             # cohort dispatch fails ITS members, never the sessions
-            # already streaming from the slab
+            # already streaming from the slab (unless it took the
+            # slab they stream from with it)
+            self._fail_live_if_slab_lost(dst, "prefill", e)
             for sess, slot in members:
                 self._release_slot(slot)
                 sess.slot = -1
                 self._decode_fail_session(sess, dst,
                                           ServeDispatchError(
                     f"decode prefill failed: {e!r}"))
-            if self._slab_lost():
-                with self._decode_lock:
-                    live = list(self._decode_live.values())
-                for sess in live:
-                    self._decode_fail_session(sess, dst,
-                                              ServeDispatchError(
-                        f"the donated slab was lost to a failed "
-                        f"prefill: {e!r}"))
-                self._rebuild_lost_slab()
             return
         self._slab = new_slab
         now = time.perf_counter()
@@ -2314,9 +2325,11 @@ class ServingEngine:
                 break
             except BaseException as e:  # noqa: BLE001 — retry below
                 if attempt >= self.max_retries or self._slab_lost():
-                    # retries exhausted: the fused step is the only
-                    # way forward for these sessions — fail them
-                    # loudly, free every slot for queued work
+                    # retries exhausted (or nothing left to retry
+                    # from): the fused step is the only way forward
+                    # for these sessions — fail them loudly, free
+                    # every slot for queued work
+                    self._rebuild_lost_slab()
                     for _, sess in live:
                         self._decode_fail_session(sess, dst,
                                                   ServeDispatchError(
@@ -2324,7 +2337,6 @@ class ServingEngine:
                             f"{attempt} retries: {e!r}"))
                     with self._decode_lock:
                         dst.slots_in_use = len(self._decode_live)
-                    self._rebuild_lost_slab()
                     return
                 attempt += 1
                 time.sleep(resilience.backoff_delay_s(

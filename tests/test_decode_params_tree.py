@@ -48,16 +48,11 @@ def _set_eps(m, eps):
 def _tree(m, form):
     """(params, slab) in one of the three forms the decode tier runs."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import Mesh
 
-    if form == "quant":
-        slab = [(jnp.zeros((2, B, H, T, D // H), jnp.int8),
-                 jnp.zeros((2, B, T), jnp.float32)) for _ in range(L)]
-        return m._decode_params_quant(), slab
-    slab = [jnp.zeros((2, B, H, T, D // H), jnp.float32)
-            for _ in range(L)]
-    params = m._decode_params()
+    params = (m._decode_params_quant() if form == "quant"
+              else m._decode_params())
+    slab = m.new_slab(params, B, T, None)
     if form == "shard":
         mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
         params = m._shard_decode_params(params, mesh)
@@ -105,14 +100,14 @@ def test_tree_holds_arrays_only_and_calls_transfer_nothing(norm, form):
     n_real = jax.device_put(np.array([3], np.int32))
     slots = jax.device_put(np.array([1], np.int32))
 
-    def calls():
-        m.decode_step(params, slab, tok, pos)
-        m.decode_scan(params, slab, tok, pos, 2)
-        m.prefill_slab(params, slab, ids, n_real, slots)
+    def calls(slab):      # each program donates the slab it is given
+        _, slab = m.decode_step(params, slab, tok, pos)
+        _, slab = m.decode_scan(params, slab, tok, pos, 2)
+        return m.prefill_slab(params, slab, ids, n_real, slots)[1]
 
-    calls()  # compile outside the guard
+    slab = calls(slab)  # compile outside the guard
     with jax.transfer_guard_host_to_device("disallow"):
-        calls()
+        calls(slab)
 
 
 def test_eps_is_the_layers_own_and_keys_the_program():

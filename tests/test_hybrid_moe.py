@@ -412,27 +412,21 @@ def test_bfloat16_parameters_are_drawn_in_place():
     assert np.median(worst_by_position) < 0.05 * np.abs(want).max()
 
 
-# -- (h) GPT-2's slab and streams are what they were -----------------------------
+# -- (h) GPT-2's slab as the model states it, and its streams -------------------
 @pytest.mark.parametrize("quant", ["off", "int8"])
-def test_h_gpt2_slab_and_streams_as_before_the_model_stated_them(quant):
-    """`serve.py` asks the model for the slab now. `TransformerLM`
-    answers with what `_build_slab` built before: L buffers
-    [2, slots, H, rung, D] in the embedding's dtype (int8 payload +
-    float32 scale planes under the int8 tier), zeros after warm-up
-    (warm-up's results are dropped: nothing is donated), and a served
-    stream is `generate()` bit for bit through growth to the next
-    rung."""
+def test_h_gpt2_slab_and_streams_as_before_the_model_stated_them(gpt2,
+                                                                  quant):
+    """`serve.py` asks the model for the slab. `TransformerLM` answers
+    with L buffers [2, slots, H, D, rung], positions last (PR 28), in
+    the embedding's dtype (int8 payload + float32 [2, slots, rung]
+    scale planes under the int8 tier). Its programs donate the slab
+    like the hybrid model's, so after warm-up the engine holds the
+    slab the last warm program returned, and a served stream is
+    `generate()` bit for bit through growth to the next rung."""
     import jax.numpy as jnp
 
-    from singa_tpu.models.transformer import TransformerLM
-
-    dev = device.get_default_device()
-    dev.SetRandSeed(5)
-    m = TransformerLM(V, d_model=32, num_heads=2, num_layers=3, max_len=64)
-    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32), device=dev)],
-              is_train=False, use_graph=False)
-    m.eval()
-    assert not m.donates_slab and m.step_counter_names == ()
+    m = gpt2
+    assert m.step_counter_names == () and m.scan_unroll == 1
     device.set_inference_quant(quant)
     eng = serve.ServingEngine(m, max_sessions=3, max_new_tokens=40,
                               prefill_batch=2, decode_block=4).start()
@@ -442,16 +436,19 @@ def test_h_gpt2_slab_and_streams_as_before_the_model_stated_them(quant):
         assert len(slab) == 3 and eng._slab_dims() == (4, 16)
         assert eng._decode_geom()[1:] == (4, 16)
         for layer_ in slab:
+            pay = layer_[0] if quant == "int8" else layer_
+            assert pay.shape == (2, 4, 2, 16, 16)     # [2, B, H, D, T]
+            assert not pay.is_deleted()
+            # warm-up's longest block wrote positions 0..3 of every
+            # row (stale state no query attends), and a pad row's
+            # prefill (slot out of bounds) wrote nothing
+            assert not np.asarray(pay)[..., 4:].any()
             if quant == "int8":
-                pay, sc = layer_
-                assert pay.shape == (2, 4, 2, 16, 16)
                 assert pay.dtype == jnp.int8
-                assert sc.shape == (2, 4, 16) and sc.dtype == jnp.float32
-                assert not np.asarray(pay).any()
+                assert layer_[1].shape == (2, 4, 16)
+                assert layer_[1].dtype == jnp.float32
             else:
-                assert layer_.shape == (2, 4, 2, 16, 16)
-                assert layer_.dtype == jnp.float32
-                assert not np.asarray(layer_).any()
+                assert pay.dtype == jnp.float32
         d = stats.cache_stats()["decode"]
         assert d["cache_bytes_ring"] == 0
         assert d["cache_bytes_context"] == 3 * 2 * 4 * 2 * 16 * 16 * (
@@ -468,3 +465,174 @@ def test_h_gpt2_slab_and_streams_as_before_the_model_stated_them(quant):
         assert np.array_equal(got[0], m.generate(ids_of((5,), 21)[None], 8))
         assert np.array_equal(got[1], m.generate(ids_of((9,), 22)[None], 40))
     assert m.take_step_counters() == {}
+
+
+# -- (i) GPT-2's slab, positions last and donated (ISSUE 28) ----------------------
+@pytest.fixture(scope="module")
+def gpt2():
+    from singa_tpu.models.transformer import TransformerLM
+
+    dev = device.get_default_device()
+    dev.SetRandSeed(5)
+    m = TransformerLM(V, d_model=32, num_heads=2, num_layers=3, max_len=64)
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32), device=dev)],
+              is_train=False, use_graph=False)
+    m.eval()
+    return m
+
+
+def _gpt2_stream(m, params, prompts, n_new, rung, grow_to=None, block=1):
+    """Greedy streams of `prompts` through the model's own programs:
+    a cohort prefill into a slab on `rung`, three single steps, the
+    slab grown (where asked), the rest by single steps or blocks."""
+    import jax
+
+    B = len(prompts)
+    slab = m.new_slab(params, B, rung, jax.devices()[0])
+    ids = np.zeros((B, 8), np.int32)
+    for r, p in enumerate(prompts):
+        ids[r, :len(p)] = p
+    n = np.asarray([len(p) for p in prompts], np.int32)
+    lg, slab = m.prefill_slab(params, slab, put(ids), put(n),
+                              put(np.arange(B, dtype=np.int32)))
+    tok = np.asarray(lg).argmax(-1).astype(np.int32)
+    out, pos, done = [tok], n.copy(), 1
+    while done < n_new:
+        if done == 4 and grow_to:
+            slab = m.grow_slab(slab, grow_to)
+            assert m.slab_dims(slab) == (B, grow_to)
+        k = block if done >= 4 and n_new - done >= block else 1
+        if k == 1:
+            lg, slab = m.decode_step(params, slab, put(tok), put(pos))
+            new = np.asarray(lg).argmax(-1).astype(np.int32)[None]
+        else:
+            new, slab = m.decode_scan(params, slab, put(tok), put(pos), k)
+            new = np.asarray(new)
+        out.extend(new)
+        tok, pos, done = new[-1], pos + k, done + k
+    return np.stack(out, 1)                                  # [B, n_new]
+
+
+@pytest.mark.parametrize("block", [1, 4], ids=["step", "block"])
+@pytest.mark.parametrize("quant", ["plain", "int8"])
+def test_i_gpt2_slab_rows_are_generates_streams_through_growth(gpt2, quant,
+                                                               block):
+    """Rows of the positions-last slab, written a position at a time
+    by `cache_write` and a cohort at a time by the prefill's scatter,
+    grown from the 16 rung to 32 mid-stream: float32 rows decode
+    `generate()`'s stream; int8 rows (whose `generate()` is float32)
+    the stream of the same rows on a slab that never grew, by single
+    steps."""
+    m = gpt2
+    prompts = [ids_of((5,), 31), ids_of((8,), 32), ids_of((3,), 33)]
+    n_new = 16
+    if quant == "int8":
+        params = m._decode_params_quant()
+        want = _gpt2_stream(m, params, prompts, n_new, 32)
+    else:
+        params = m._decode_params()
+        want = np.stack([m.generate(p[None], n_new)[0, len(p):]
+                         for p in prompts])
+    got = _gpt2_stream(m, params, prompts, n_new, 16, grow_to=32,
+                       block=block)
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("quant", ["plain", "int8"])
+def test_i_gpt2_a_pad_row_writes_nothing(gpt2, quant):
+    import jax
+
+    m = gpt2
+    params = (m._decode_params_quant() if quant == "int8"
+              else m._decode_params())
+    slab = m.new_slab(params, 2, 16, jax.devices()[0])
+    rows = [ids_of((5,), 41), ids_of((4,), 42)]
+    ids = np.zeros((2, 8), np.int32)
+    ids[0, :5], ids[1, :4] = rows
+    # row 0 goes to slot 1; row 1's slot is out of bounds
+    _, slab = m.prefill_slab(params, slab, put(ids),
+                             put(np.asarray([5, 4], np.int32)),
+                             put(np.asarray([1, 2], np.int32)))
+    for leaf in jax.tree_util.tree_leaves(slab):
+        leaf = np.asarray(leaf)
+        assert not leaf[:, 0].any() and leaf[:, 1].any()
+        assert not leaf[:, 1, ..., 8:].any()     # the bucket, no further
+
+
+@pytest.fixture(params=["gpt2", "hybrid"])
+def either(request):
+    return request.getfixturevalue(
+        "model" if request.param == "hybrid" else request.param)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "decode_scan",
+                                     "prefill_slab"])
+def test_i_a_program_that_fails_after_it_took_the_slab(either, program,
+                                                       monkeypatch):
+    """Every program donates the slab. One that fails after its
+    dispatch leaves nothing to retry from: the live sessions fail
+    loudly (a failed prefill's cohort with them), the slab is rebuilt
+    at its geometry, and queued work goes on as if nothing had been."""
+    import jax
+
+    m = either
+    real = getattr(m, program)
+    fail = [True]
+
+    def consumed_then_failed(params, slab, *rest):
+        out = real(params, slab, *rest)
+        if fail[0] and stats.cache_stats()["decode"]["tokens_streamed"]:
+            fail[0] = False
+            assert all(leaf.is_deleted()
+                       for leaf in jax.tree_util.tree_leaves(slab))
+            raise RuntimeError("the device failed under the program")
+        return out
+
+    first, late = (ids_of((5,), 51), 12), (ids_of((6,), 52), 12)
+    after = (ids_of((4,), 53), 9)
+    eng = serve.ServingEngine(m, max_sessions=4, max_new_tokens=24,
+                              prefill_batch=2, decode_block=4,
+                              max_retries=2, backoff_ms=0.1).start()
+    try:
+        eng.warm_decode(prompt_lens=(4, 8), max_new_tokens=24)
+        stats.reset_cache_stats()
+        geom = eng._slab_dims()
+        monkeypatch.setattr(m, program, consumed_then_failed)
+        r1 = eng.submit_decode(*first)
+        next(r1.tokens(timeout=60))              # live and streaming
+        r2 = eng.submit_decode(*late)            # its prefill may be it
+        for r in (r1, r2):
+            try:
+                r.result(timeout=60)
+            except serve.ServeDispatchError:
+                pass
+        assert not fail[0], "the failing program never ran"
+        assert eng._slab_dims() == geom and not eng._slab_lost()
+        got = np.asarray(eng.submit_decode(*after).result(timeout=60))[0]
+        d = stats.cache_stats()["decode"]
+    finally:
+        eng.stop()
+    assert d["failed"] >= 1
+    assert d["sessions"] == d["completed"] + d["failed"]
+    monkeypatch.undo()
+    assert np.array_equal(got, _serve(m, [after])[0])
+
+
+def test_i_a_failure_before_dispatch_retries_on_the_untouched_slab(either):
+    """The injected `decode_fail` raises before the program is called:
+    the slab is as it was, the block is retried, nobody fails and the
+    streams are the undisturbed ones."""
+    from singa_tpu import resilience
+
+    m = either
+    requests = [(ids_of((5,), 61), 12), (ids_of((7,), 62), 10)]
+    want = _serve(m, requests)
+    stats.reset_cache_stats()
+    inj = resilience.FaultInjector(seed=0,
+                                   schedule={"decode_fail": {2, 5}})
+    got = _serve(m, requests, max_retries=2, backoff_ms=0.1,
+                 fault_injector=inj)
+    d = stats.cache_stats()["decode"]
+    assert d["failed"] == 0 and d["completed"] == 2
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)

@@ -221,8 +221,11 @@ def test_decode_scan_matches_steps_and_packed_rows_roundtrip(lm):
     B, T, Dh = 2, 16, D // H
     import jax.numpy as jnp
 
-    slab = [(jnp.zeros((2, B, H, T, Dh), jnp.int8),
-             jnp.zeros((2, B, T), jnp.float32)) for _ in range(L)]
+    import jax
+
+    slab = lm.new_slab(params, B, T, None)
+    assert slab[0][0].shape == (2, B, H, Dh, T)    # positions last
+    assert slab[0][1].shape == (2, B, T)
     prompts = _prompts(B, lens=(3, 4))
     ids = np.zeros((B, 4), np.int32)
     n_real = np.array([3, 4], np.int32)
@@ -233,8 +236,9 @@ def test_decode_scan_matches_steps_and_packed_rows_roundtrip(lm):
                            jnp.arange(B, dtype=jnp.int32))[1]
     tok = jnp.asarray(ids[np.arange(B), n_real - 1].astype(np.int32))
     pos = jnp.asarray((n_real - 1).astype(np.int32))
-    # k single steps vs one scan-of-k from the same state
-    c_step, t_step = slab, tok
+    # k single steps vs one scan-of-k from the same state (a copy of
+    # it: each program donates the slab it is given)
+    c_step, t_step = jax.tree_util.tree_map(jnp.copy, slab), tok
     toks_step = []
     p_step = pos
     for _ in range(4):
@@ -252,20 +256,21 @@ def test_decode_scan_matches_steps_and_packed_rows_roundtrip(lm):
     rows = lm.export_slab_rows(c_step, 1, int(n_real[1]) + 4)
     assert isinstance(rows, tuple) and len(rows) == 2
     pay, sc = rows
+    # the wire form, whatever the slab's own layout
+    assert pay.shape == (L, 2, H, int(n_real[1]) + 4, Dh)
+    assert sc.shape == (L, 2, int(n_real[1]) + 4)
     assert np.asarray(pay).dtype == np.int8
     assert np.asarray(sc).dtype == np.float32
     fp32_bytes = np.asarray(pay).size * 4
     packed = np.asarray(pay).nbytes + np.asarray(sc).nbytes
     assert packed < 0.3 * fp32_bytes
     # import into a fresh slab: both planes land bit-exactly
-    fresh = [(jnp.zeros((2, B, H, T, Dh), jnp.int8),
-              jnp.zeros((2, B, T), jnp.float32)) for _ in range(L)]
-    fresh = lm.import_slab_rows(fresh, 1, rows)
+    fresh = lm.import_slab_rows(lm.new_slab(params, B, T, None), 1, rows)
     P = int(n_real[1]) + 4
     for li in range(L):
         np.testing.assert_array_equal(
-            np.asarray(fresh[li][0])[:, 1, :, :P],
-            np.asarray(c_step[li][0])[:, 1, :, :P])
+            np.asarray(fresh[li][0])[:, 1, :, :, :P],
+            np.asarray(c_step[li][0])[:, 1, :, :, :P])
         np.testing.assert_array_equal(
             np.asarray(fresh[li][1])[:, 1, :P],
             np.asarray(c_step[li][1])[:, 1, :P])
@@ -274,20 +279,57 @@ def test_decode_scan_matches_steps_and_packed_rows_roundtrip(lm):
 def test_import_slab_rows_refuses_form_mismatch(lm):
     """fp32 rows into an int8 slab (or vice versa) is a config error
     across a migration — refused LOUDLY, never coerced."""
-    import jax.numpy as jnp
-
     B, T, Dh = 2, 16, D // H
-    qslab = [(jnp.zeros((2, B, H, T, Dh), jnp.int8),
-              jnp.zeros((2, B, T), jnp.float32)) for _ in range(L)]
+    qslab = lm.new_slab(lm._decode_params_quant(), B, T, None)
     fp_rows = np.zeros((L, 2, H, 4, Dh), np.float32)
     with pytest.raises(ValueError, match="form mismatch"):
         lm.import_slab_rows(qslab, 0, fp_rows)
-    fslab = [jnp.zeros((2, B, H, T, Dh), jnp.float32)
-             for _ in range(L)]
+    fslab = lm.new_slab(lm._decode_params(), B, T, None)
     q_rows = (np.zeros((L, 2, H, 4, Dh), np.int8),
               np.zeros((L, 2, 4), np.float32))
     with pytest.raises(ValueError, match="form mismatch"):
         lm.import_slab_rows(fslab, 0, q_rows)
+    # refused before anything was dispatched: the donated slabs live
+    assert not qslab[0][0].is_deleted() and not fslab[0].is_deleted()
+
+
+@pytest.mark.parametrize("quant", ["off", "int8"])
+def test_a_frame_in_the_wire_form_written_by_hand_imports_bit_exactly(
+        lm, quant):
+    """A MIGRATE frame carries [L, 2, H, pos, D] (and, packed, the
+    [L, 2, pos] scales), as replicas older than the positions-last
+    slab wrote it: element (l, kv, h, t, d) of the frame lands at
+    [kv, slot, h, d, t] of layer l and nowhere else, the other rows
+    and the tail past `pos` stay as they were, and the export of that
+    row is the frame again."""
+    B, T, Dh, pos, slot = 3, 16, D // H, 5, 2
+    rng = np.random.RandomState(11)
+    if quant == "int8":
+        params = lm._decode_params_quant()
+        frame = (rng.randint(-127, 128, (L, 2, H, pos, Dh)).astype(np.int8),
+                 rng.rand(L, 2, pos).astype(np.float32))
+    else:
+        params = lm._decode_params()
+        frame = rng.randn(L, 2, H, pos, Dh).astype(np.float32)
+    slab = lm.import_slab_rows(lm.new_slab(params, B, T, None), slot, frame)
+    pay = frame[0] if quant == "int8" else frame
+    for li in range(L):
+        layer = np.asarray(slab[li][0] if quant == "int8" else slab[li])
+        assert layer.shape == (2, B, H, Dh, T)
+        want = np.zeros_like(layer)
+        want[:, slot, :, :, :pos] = pay[li].transpose(0, 1, 3, 2)
+        np.testing.assert_array_equal(layer, want)
+        if quant == "int8":
+            scales = np.zeros((2, B, T), np.float32)
+            scales[:, slot, :pos] = frame[1][li]
+            np.testing.assert_array_equal(np.asarray(slab[li][1]), scales)
+    back = lm.export_slab_rows(slab, slot, pos)
+    if quant == "int8":
+        np.testing.assert_array_equal(back[0], frame[0])
+        np.testing.assert_array_equal(back[1], frame[1])
+    else:
+        assert back.shape == (L, 2, H, pos, Dh)
+        np.testing.assert_array_equal(back, frame)
 
 
 # -- serving: self-consistency, migration bit-identity, chaos ---------
@@ -476,10 +518,9 @@ def test_decode_step_bytes_strictly_lower_at_kv_bound_geometry():
     B, T, Dh = 8, 128, 16
     tok = jnp.zeros((B,), jnp.int32)
     pos = jnp.zeros((B,), jnp.int32)
-    cache_fp = [jnp.zeros((2, B, 4, T, Dh), jnp.float32)
-                for _ in range(2)]
-    cache_q = [(jnp.zeros((2, B, 4, T, Dh), jnp.int8),
-                jnp.zeros((2, B, T), jnp.float32)) for _ in range(2)]
+    cache_fp = m.new_slab(m._decode_params(), B, T, None)
+    cache_q = m.new_slab(m._decode_params_quant(), B, T, None)
+    assert cache_q[0][0].shape == (2, B, 4, Dh, T)
     b_fp = hlo_profile.bytes_accessed(m.decode_step_hlo(
         m._decode_params(), cache_fp, tok, pos))["total"]
     b_q = hlo_profile.bytes_accessed(m.decode_step_hlo(

@@ -5,13 +5,16 @@ rung, the cohort prefill of one and of two prompts in the largest
 bucket, and the expert product both ways. What the chip's compiler
 would refuse (a program that does not fit 16 GB, a grouped product it
 cannot lower) it refuses here, at no chip time. Nothing runs: no
-result and no time comes out of these.
+result and no time comes out of these. Since ISSUE 28 also GPT-2's
+served programs at both serving cells' geometry: what they hold that
+moves a whole layer of the slab.
 
 The topology is described inside a fixture, after collection, and only
 in this file: one process may hold the TPU's library (see the guide).
 """
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -119,8 +122,6 @@ def test_run_ahead_block_reads_the_experts_as_stored(served, monkeypatch):
     """A block of 2 steps: no held expert's matrix is copied into
     another layout and no second slab sits among the temporaries
     (around a loop XLA does both: `HybridWindowMoELM.scan_unroll`)."""
-    import re
-
     model, params, slab, sds = served
     vec = sds((SLOTS,), np.int32)
     lowered = []
@@ -169,6 +170,115 @@ def test_expert_product_compiles_at_real_widths(served, rows):
                    model.d_ff_expert)
         # the buffer's rows * K assignments, once: not times 16 experts
         assert flops < 1.5 * (2 * rows * K * 3 * d * f)
+
+
+# -- GPT-2's served programs update the slab where it lies (ISSUE 28) ----------
+GPT2 = os.path.join(os.path.dirname(CONFIG), "gpt2.json")
+
+
+@pytest.fixture(scope="module")
+def gpt2(one_chip):
+    """`perfbench/configs/gpt2.json`'s model and its decode-params
+    tree as shapes on the described chip, at the serving policy."""
+    import jax
+
+    from perfbench.harness import cell
+    from singa_tpu import device, tensor
+    from singa_tpu.ops import pallas_kernels
+
+    with open(GPT2) as f:
+        config = json.load(f)
+    before = tensor.get_matmul_precision()
+    tensor.set_matmul_precision(config["serve"]["matmul_precision"])
+    model = cell.build(config["builder"])
+    model.compile([tensor.from_numpy(
+        np.zeros((1, 4), np.int32), device=device.get_default_device())],
+        is_train=False, use_graph=False)
+    model.eval()
+    monkey = pytest.MonkeyPatch()
+    monkey.setattr(pallas_kernels, "_interpret", lambda: False)
+    # the programs as `ServingEngine` gets them: `decode_step`,
+    # `decode_scan` and `prefill_slab` build and donate, this keeps
+    # what they compiled
+    compiled = []
+    monkey.setattr(model, "_aot_step",
+                   lambda kind, jitted, args, extras: compiled.append(
+                       jitted.lower(*args).compile()) or (lambda *a: a))
+    monkey.setattr(model, "_program_cache", dict)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                    model._decode_params())
+    yield model, params, sds, compiled
+    monkey.undo()
+    tensor.set_matmul_precision(before)
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \(?\w+\[([\d,]*)\][^=]*? ([\w-]+)\(([^)]*)\)",
+    re.M)   # name, dims (a tuple's first), opcode, operands
+
+
+def _whole_layer_moves(text, layer):
+    """Opcodes of the instructions of a compiled program that move a
+    slab layer's elements or more: every `copy` (`copy-start`: one the
+    compiler made asynchronous) and `transpose`, and every
+    `dynamic-update-slice` but one whose update is smaller than a
+    layer (rows written in place: that is the write itself)."""
+    size, moves = {}, []
+    for name, dims, opcode, operands in _INSTRUCTION.findall(text):
+        size[name] = n = int(np.prod([int(d) for d in dims.split(",") if d]))
+        if n < layer:
+            continue
+        if opcode in ("copy", "copy-start", "transpose"):
+            moves.append(opcode)
+        elif opcode == "dynamic-update-slice":
+            update = operands.split(",")[1].strip().lstrip("%")
+            if size.get(update, layer) >= layer:
+                moves.append(opcode)
+    return moves
+
+
+@pytest.mark.parametrize("program", ["step", "block8", "block2", "prefill"])
+@pytest.mark.parametrize("slots,rung", [(32, 1024), (64, 256)],
+                         ids=["decode_cell_32x1024", "short_cell_64x256"])
+def test_gpt2_programs_move_no_whole_layer_but_the_write(gpt2, slots, rung,
+                                                         program):
+    """The fused step, a run-ahead block (k = 8 and 2) and the 2 x 128
+    cohort prefill over the slab of `gpt2-serve-decode` (32 x 1024)
+    and `gpt2-serve-short` (64 x 256): the slab is aliased whole,
+    nothing of a layer's size sits among the temporaries (a block's
+    0.3 GB are its bfloat16 weights, converted once before the loop),
+    and no operation moves a whole layer. With a layer [2, B, H, T, D]
+    every program held 24 whole-layer copies, in and out around each
+    write (PERF.md, PR 28). One exception, kept small here: on the
+    short cell's rung a layer (101 MB) fits the chip's 128 MiB of
+    VMEM, and inside a block's loop XLA passes 3 of the 12 layers
+    through it, each written back whole by an asynchronous copy."""
+    import jax
+
+    model, params, sds, compiled = gpt2
+    slab = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.new_slab(params, slots, rung, None)))
+    vec = sds((slots,), np.int32)
+    del compiled[:]
+    if program == "step":
+        model.decode_step(params, slab, vec, vec)
+    elif program == "prefill":
+        two = sds((2,), np.int32)
+        model.prefill_slab(params, slab, sds((2, 128), np.int32), two, two)
+    else:
+        model.decode_scan(params, slab, vec, vec, int(program[5:]))
+    (exe,) = compiled
+    m = _fits(exe, f"GPT-2 {program} at {slots} x {rung}")
+    assert m.alias_size_in_bytes >= model.slab_bytes(slab)["context"]
+    assert m.temp_size_in_bytes < 0.5e9
+    moves = _whole_layer_moves(exe.as_text(), int(np.prod(slab[0].shape)))
+    through_vmem = 3 if program.startswith("block") and rung == 256 else 0
+    assert moves == ["copy-start"] * through_vmem
 
 
 def test_the_one_chip_resnet_step_holds_what_the_memory_meter_misses(
