@@ -39,8 +39,7 @@ CASE_SRC = r"""
 import json, os, sys, time
 sys.path.insert(0, {root!r})
 if os.environ.get("PALLAS_TUNE_PLATFORM"):
-    # pin the backend before anything creates one (bench.py's
-    # BENCH_PLATFORM idiom)
+    # pin the backend before anything creates one
     import jax
     jax.config.update("jax_platforms",
                       os.environ["PALLAS_TUNE_PLATFORM"])

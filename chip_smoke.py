@@ -442,8 +442,8 @@ def phase_kernels(xent_shapes=((128, 1000), (8192, 32000)),
                   lm_steps=3, interpret=False, platform="tpu"):
     """The Pallas tier, compiled: each kernel fwd+bwd against the jnp
     path, then `lm_steps` train steps of a `TransformerLM` (vocab,
-    d_model, heads, layers = `lm`; bench.py `stage_lm`'s
-    configuration) with the tier on, whose lowered step must hold the
+    d_model, heads, layers = `lm`) with the tier on, whose lowered
+    step must hold the
     Mosaic custom calls — so the kernels ran, not a reference."""
     from singa_tpu import device, opt, tensor
     from singa_tpu.models.transformer import TransformerLM

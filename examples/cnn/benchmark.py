@@ -58,7 +58,7 @@ def run(depth=50, batch_size=32, steps=20, warmup=5, image_size=224,
     m.compile([tx], is_train=True, use_graph=use_graph)
     # warmup (incl. XLA compile), then pipelined timing blocks: enqueue
     # several steps and block once — per-step waits would measure the
-    # host<->device round trip, not the device (cf. bench.py).
+    # host<->device round trip, not the device.
     for _ in range(max(2, warmup)):
         out, loss = m(tx, ty)
     loss.data.block_until_ready()

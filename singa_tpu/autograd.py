@@ -186,8 +186,8 @@ class Operator:
     def forward(self, *xs):
         if self.requires_grad:
             # Eager op-executable cache: per-call jax.vjp retraces fn
-            # (measured ~3 ms/op on CPU, 30x a graph step —
-            # benchmarks/eager_overhead.py); for config-keyed ops reuse
+            # (~3 ms an op on XLA:CPU, 30x a graph step; no chip
+            # number); for config-keyed ops reuse
             # jitted fwd/bwd executables instead. Tracer inputs (graph
             # mode) keep the plain vjp path: the whole step is traced
             # once anyway, and the cached bwd's forward recompute would

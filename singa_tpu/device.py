@@ -441,7 +441,7 @@ def set_dag_cache_policy(policy: str) -> None:
     """"lru" (default: hits promote, hot executables survive cycling
     workloads) or "fifo" (insertion order only — the pre-observability
     behavior, kept for A/B measurement; see
-    benchmarks/eager_overhead.py)."""
+    tests/test_cache_stats.py)."""
     from . import stats
 
     stats.configure(dag_cache_policy=policy)
@@ -968,9 +968,8 @@ def set_dag_auto_flops_per_op(v: float) -> None:
 # jaxlib's own parser does not know them, and one of them in XLA_FLAGS
 # aborts the process at backend start ("Unknown flags in XLA_FLAGS";
 # chip run, PR 21). So profiles go into LIBTPU_INIT_ARGS, and must be
-# applied before the first jax.devices() / computation of the process
-# — bench.py's staged subprocesses apply them first thing, which is
-# the supported path.
+# applied before the first jax.devices() / computation of the process:
+# first thing in a process is the supported path.
 # ---------------------------------------------------------------------------
 _XLA_PROFILES = {
     # no-op baseline: whatever the environment already set
@@ -1008,8 +1007,8 @@ def set_xla_profile(name: str = "latency"):
     profile here owns, so flags never duplicate or linger. Flags are
     consumed at backend init — if a jax backend already exists in this
     process, a warning is printed and the profile only affects
-    backends created afterwards (bench.py stage subprocesses apply it
-    before touching jax, which is the supported path)."""
+    backends created afterwards (apply it before touching jax, which
+    is the supported path)."""
     global _xla_profile_applied
     if name not in _XLA_PROFILES:
         raise ValueError(
@@ -1039,8 +1038,8 @@ def get_xla_profile() -> Optional[str]:
 
 # ---------------------------------------------------------------------------
 # Persistent compile cache: ONE rule for where it lives, called by every
-# entry point that compiles on the chip (chip_smoke.py, bench.py,
-# examples/cnn/benchmark.py). The directory is part of what makes a
+# entry point that compiles on the chip (chip_smoke.py,
+# perfbench/run.py, examples/cnn/benchmark.py). The directory is part of what makes a
 # later run hit, so it is never a temporary name, a pid or a time.
 # ---------------------------------------------------------------------------
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

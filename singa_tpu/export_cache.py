@@ -214,8 +214,8 @@ class _ExportStats:
     `traces` counts step/forward executables actually TRACED in this
     process (the cost warm starts avoid — a fully warm process shows
     traces=0); `load_s`/`trace_s` are the cumulative wall seconds the
-    two paths cost, which is how bench.py splits its `compile` stage
-    second into trace/compile/load. `step_retraces` counts post-warmup
+    two paths cost, which is how a time to the first step splits
+    into trace/compile/load. `step_retraces` counts post-warmup
     abstract-shape changes on the step path (the retrace-storm
     warning's counter). `buckets_seen` is the number of distinct
     bucketed dispatch shapes — under the policy it is bounded by

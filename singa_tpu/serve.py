@@ -101,12 +101,8 @@ max_queue=...)` and `device.set_serving_resilience(deadline_ms=...,
 max_retries=..., backoff_ms=..., shed_watermark=...,
 adaptive_wait=..., max_restarts=..., drain_timeout_s=...,
 health_file=...)` set the process defaults; `ServingEngine(...)`
-overrides per-engine. Bench: `bench.py --stage serve` drives the
-engine with a seeded Poisson open-loop load generator and reports
-`serve_requests_per_sec` + p50/p99 — CPU-runnable, so CI measures the
-continuous-batching speedup and the chip only confirms it;
-`--chaos` adds an injected-fault arm reporting availability % and
-p99-under-faults.
+overrides per-engine. Speed: the serving cells of `BENCHMARK.json`
+(`python3 -m perfbench.run`, on the chip; `PERF.md`).
 """
 from __future__ import annotations
 

@@ -270,8 +270,8 @@ def rank_quantile(sorted_samples, q: float):
     """`QuantileSketch.quantile`'s rank convention applied to raw
     sorted samples: ``rank = q * (n - 1)``, value = first sample
     whose cumulative count exceeds ``rank`` (= ``sorted[floor(rank)]``).
-    The cross-validation in `bench.py` compares the sketch against
-    THIS, not against `np.percentile`'s interpolation — at small n
+    A cross-validation of the sketch against raw samples compares it
+    with THIS, not with `np.percentile`'s interpolation — at small n
     the interpolation disagrees by more than the sketch's documented
     relative-error bound and would fail the gate spuriously."""
     n = len(sorted_samples)

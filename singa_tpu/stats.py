@@ -15,8 +15,8 @@ this module makes that visible instead of guessable:
     that failed once; cheap to rediscover) are evicted before positive
     compiled executables (expensive to re-pay);
   - `cache_stats()` — one snapshot dict over every registered cache,
-    printed by `benchmarks/eager_overhead.py` and plumbed through
-    `Model.cache_stats()`;
+    plumbed through `Model.cache_stats()` and written into every
+    `trace.MetricsLogger` record;
   - the eager config knobs (`dag_cache_capacity`, `dag_cache_policy`,
     `buffer_donation`), owned here so `device`, `autograd`, and `opt`
     can share them without an import cycle. User-facing setters live
@@ -67,7 +67,7 @@ _CONFIG: Dict = {
     # 256 FIFO before this subsystem existed).
     "dag_cache_capacity": 256,
     # "lru": promote on hit (default). "fifo": insertion order only —
-    # kept for A/B measurement (benchmarks/eager_overhead.py shows the
+    # kept for A/B measurement (tests/test_cache_stats.py shows the
     # retrace storm it causes on cycling workloads).
     "dag_cache_policy": "lru",
     # Donate param/momentum/grad buffers into the jitted optimizer
@@ -780,8 +780,8 @@ def reset_cache_stats() -> None:
 
 
 def format_stats(snapshot: Optional[Dict] = None) -> str:
-    """One `cache_stats <name> k=v ...` line per cache — the stable
-    grep-able form emitted by benchmarks/eager_overhead.py."""
+    """One `cache_stats <name> k=v ...` line per cache: a stable,
+    grep-able form for logs."""
     snap = cache_stats() if snapshot is None else snapshot
     lines = []
     for name, s in snap.items():

@@ -741,8 +741,8 @@ def format_summary() -> str:
 # ---------------------------------------------------------------------------
 def default_metrics_path(tag: str) -> str:
     """`$SINGA_TPU_METRICS_DIR/<tag>.jsonl` (default dir: ./metrics),
-    created on demand — the directory `tools/tpu_watch.sh metrics`
-    tails."""
+    created on demand — the directory `tools/fleet_top.py` and
+    `tools/metrics_lint.py --dir` read by default."""
     d = os.environ.get("SINGA_TPU_METRICS_DIR") or os.path.join(
         os.getcwd(), "metrics")
     os.makedirs(d, exist_ok=True)
@@ -959,8 +959,7 @@ def read_metrics(path: str) -> List[Dict]:
 # ---------------------------------------------------------------------------
 # Fleet telemetry aggregator (ISSUE 15): N per-replica/worker metrics
 # JSONL streams + the merged span timeline -> ONE schema-stable fleet
-# record. Consumed by `bench.py --stage fleet` (`latency_breakdown` /
-# `trace` result blocks) and rendered by `tools/fleet_top.py`.
+# record. Rendered by `tools/fleet_top.py`.
 # ---------------------------------------------------------------------------
 FLEET_AGGREGATE_SCHEMA = 1
 

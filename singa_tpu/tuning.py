@@ -42,8 +42,8 @@ JSON) — the same seed always reproduces the same winner. No
 wall-clock enters proposals.
 
 The best-known config persists per (model topology fingerprint, chip
-kind) in a JSON store (`TunedStore`) that `bench.py --tuned` and the
-serving tier (`serve.ServingEngine`) load by default; the store also
+kind) in a JSON store (`TunedStore`) that the serving tier
+(`serve.ServingEngine`) loads by default; the store also
 carries name aliases ("resnet") so callers can resolve a config
 before the model's params exist.
 
@@ -379,8 +379,8 @@ def ingest_metrics_jsonl(path: str,
                          batch: Optional[int] = None
                          ) -> MeasuredScores:
     """Read measured examples/sec from a metrics JSONL whose records
-    carry a `config` dict (`bench.py` resnet runs append such records
-    to metrics/measured_configs.jsonl). Records without a config are
+    carry a `config` dict (a run that logs its effective config
+    beside its rate). Records without a config are
     skipped — there is nothing exact to match them to. `chip`/`batch`
     filters (pass the chip being tuned and the effective batch being
     scored) drop records measured elsewhere: a CPU toy-geometry run's
@@ -765,8 +765,7 @@ def autotune(scorer: CostModelScorer, budget: int = 16, seed: int = 0,
              jsonl_path: Optional[str] = None,
              log: Optional[Callable] = None) -> Dict:
     """Run the search: propose -> score -> pick. Appends one JSON line
-    per candidate to `jsonl_path` (the stream
-    `tools/tpu_watch.sh tune` pretty-tails) and returns
+    per candidate to `jsonl_path` and returns
     {"best", "best_score", "default_score", "rows", ...}. Winner
     selection is a pure function of the scored rows: max score, then
     FEWEST non-default knobs (never flip a knob the model can't
@@ -854,8 +853,7 @@ STORE_SCHEMA = 1
 
 def default_store_path() -> str:
     """`SINGA_TPU_TUNED_STORE` env override, else
-    `.tuned/tuned_configs.json` under the working directory (bench.py
-    pins it next to the repo via the env var)."""
+    `.tuned/tuned_configs.json` under the working directory."""
     return os.environ.get("SINGA_TPU_TUNED_STORE") or os.path.join(
         ".tuned", "tuned_configs.json")
 
@@ -863,7 +861,7 @@ def default_store_path() -> str:
 class TunedStore:
     """JSON store of best-known configs keyed by
     `(topology fingerprint, chip kind)`, plus a name->fingerprint
-    alias map so `bench.py --tuned` can resolve "resnet" before the
+    alias map so a caller can resolve "resnet" before the
     model's params exist. Writes are atomic (tmp + os.replace); a
     corrupt store reads as empty with a loud stderr note — a bad
     cache entry must cost a re-tune, never a crash."""

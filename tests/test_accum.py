@@ -204,8 +204,15 @@ class TestBitIdentity:
 
     def test_eager_and_graph_accum_identical_over_steps(self):
         """The two accumulation drivers share the fp32 sum order and
-        the mean division, so they stay bit-identical across steps at
-        ANY magnitude (no dyadic construction needed)."""
+        the mean division, so the PARAMETERS stay bit-identical after
+        every step at ANY magnitude (no dyadic construction needed).
+        The reported loss is held to the ulps its own reduction may
+        cost: each microbatch's mean-square is summed inside the
+        scan-fused program in the order XLA's fusion picks and by a
+        standalone reduce in the eager loop, so on non-dyadic data a
+        microbatch loss may round one ulp apart (it does, at step 2
+        under jax 0.9), and the mean of n = 4 of them by no more than
+        n ulps. Nothing of that reaches the gradients."""
         rs = np.random.RandomState(3)
         x = rs.randn(32, 8).astype(np.float32)
         y = rs.randn(32, 4).astype(np.float32)
@@ -215,9 +222,14 @@ class TestBitIdentity:
         for _ in range(3):
             _, lg = mg(txg, tyg)
             _, le = me(txe, tye)
-            np.testing.assert_array_equal(np.asarray(lg.data),
-                                          np.asarray(le.data))
-        _assert_trees_equal(_params_np(mg), _params_np(me))
+            lg, le = np.asarray(lg.data), np.asarray(le.data)
+            np.testing.assert_array_max_ulp(lg, le, maxulp=4)
+            _assert_trees_equal(_params_np(mg), _params_np(me))
+        # what the bound still refuses: a loss off by 1e-4 (a hundred
+        # ulps at this magnitude)
+        with pytest.raises(AssertionError):
+            np.testing.assert_array_max_ulp(lg, le + np.float32(1e-4),
+                                            maxulp=4)
 
     def test_accum_close_to_monolithic_on_softmax_model(self):
         """Realistic config (softmax CE, randn data): accumulation
@@ -459,6 +471,39 @@ _MX = _dyadic(np.random.RandomState(7), (64, 8), 0.5)
 _MY = _dyadic(np.random.RandomState(8), (64, 4), 0.5)
 
 
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+                "collective-permute", "all-to-all")
+
+
+def _collectives(lines):
+    """(opcode, line) of every collective instruction among HLO text
+    `lines`, read by OPCODE (`= <shape> all-reduce(`, or its
+    asynchronous `-start(` spelling), never by the instruction's
+    name: jax names a psum's all-reduce `%psum.7`."""
+    found = []
+    for ln in lines:
+        m = re.search(r" = .*?\b(%s)(-start)?\(" % "|".join(_COLLECTIVES),
+                      ln)
+        if m:
+            found.append((m.group(1), ln.strip()))
+    return found
+
+
+def _assert_one_allreduce_after_the_scan(hlo):
+    """Exactly ONE all-reduce in the program, in the ENTRY computation
+    (after the scan), and no collective of any kind in another
+    computation (the while body above all)."""
+    ars = [ln for op, ln in _collectives(hlo.splitlines())
+           if op == "all-reduce"]
+    assert len(ars) == 1, (
+        f"expected 1 all-reduce, got {len(ars)}:\n" + "\n".join(ars))
+    for name, lines in _hlo_computations(hlo).items():
+        if name.startswith("ENTRY"):
+            continue
+        inside = _collectives(lines[1:])
+        assert not inside, f"collective inside {name}: {inside}"
+
+
 class TestMesh:
     def test_single_allreduce_outside_the_scan(self):
         """THE amortization claim: the pure-DP accum-4 program carries
@@ -468,24 +513,25 @@ class TestMesh:
         mesh = create_mesh({"data": 8})
         m, tx, ty = _build_mse(4, mesh=mesh, x=_MX, y=_MY)
         hlo = m.step_hlo_text(tx, ty)
-        ars = [ln for ln in hlo.splitlines()
-               if re.match(r"%?[\w.-]*all-reduce[\w.]* = ",
-                           ln.strip())]
-        assert len(ars) == 1, f"expected 1 all-reduce, got:\n{ars}"
-        for name, lines in _hlo_computations(hlo).items():
-            body = "\n".join(lines)
-            if "all-reduce(" in body:
-                assert name.startswith("ENTRY"), (
-                    f"all-reduce not in ENTRY but in {name}")
-        # the while body is collective-free
-        for name, lines in _hlo_computations(hlo).items():
-            if name.startswith("ENTRY"):
-                continue
-            body = "\n".join(lines)
-            for coll in ("all-reduce(", "all-gather(",
-                         "reduce-scatter(", "collective-permute("):
-                assert coll not in body, (
-                    f"collective {coll} inside {name}")
+        assert "while(" in hlo, "the accumulation is no loop any more"
+        _assert_one_allreduce_after_the_scan(hlo)
+        # the guard sees what it guards against: the same program with
+        # its all-reduce repeated in, or moved into, the scan's body
+        # is refused, and so is one whose reduction was lost
+        lines = hlo.splitlines()
+        ar = [ln for ln in lines if _collectives([ln])][0]
+        body = re.search(r"f32\[[^\n]* while\([^\n]*body=(%[\w.]+)",
+                         hlo).group(1)
+        head = [ln for ln in lines if ln.startswith(body + " ")
+                and ln.endswith("{")][0]
+        second = hlo.replace(head, head + "\n" + ar, 1)
+        with pytest.raises(AssertionError, match="all-reduce, got 2"):
+            _assert_one_allreduce_after_the_scan(second)
+        moved = hlo.replace(ar, "", 1).replace(head, head + "\n" + ar, 1)
+        with pytest.raises(AssertionError, match="collective inside"):
+            _assert_one_allreduce_after_the_scan(moved)
+        with pytest.raises(AssertionError, match="all-reduce, got 0"):
+            _assert_one_allreduce_after_the_scan(hlo.replace(ar, "", 1))
 
     def test_mesh_accum_matches_single_device_monolithic(self):
         """Dyadic data again: the mesh accum-4 step (8 devices, local

@@ -289,7 +289,7 @@ def test_store_round_trip(tmp_path):
     assert store.get(alias="tiny")["config"]["grad_accum"] == 4
     json.load(open(path))
     # alias lists: every name resolves to the same fingerprint (the
-    # resnet-18/resnet granularity pair bench.py --tuned relies on)
+    # resnet-18/resnet granularity pair a caller resolves by)
     store.put("fp-r", "v5e", {}, 1.0, alias=["resnet-18", "resnet"])
     assert store.get(alias="resnet")["fingerprint"] == "fp-r"
     assert store.get(alias="resnet-18")["fingerprint"] == "fp-r"
@@ -560,7 +560,7 @@ def test_infeasible_mesh_geometry_excluded():
 
 def test_multi_axis_winner_persists_and_loads(tmp_path):
     """Winner with a mesh flip persists to the store and resolves by
-    alias — the `bench.py --tuned` consumption path."""
+    alias — how a later run takes the tuned config up."""
     scorer = _scorer()
     result = tuning.autotune(scorer, budget=3, seed=0,
                              space=MESH_SPACE)

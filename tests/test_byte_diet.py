@@ -76,7 +76,9 @@ def _conv_data(rs, bs=8, hw=8):
 # ---------------------------------------------------------------------------
 # Low-precision optimizer state
 # ---------------------------------------------------------------------------
-def _train_mlp(opt_fn, slot_dtype, steps=20, graph=False):
+def _train_mlp(opt_fn, slot_dtype, steps=20, graph=False, watch=None):
+    """`watch(x, params)` sees the batch and the parameters each step
+    is about to differentiate at."""
     dev = device.get_default_device()
     dev.SetRandSeed(7)
     rs = np.random.RandomState(1)
@@ -88,6 +90,8 @@ def _train_mlp(opt_fn, slot_dtype, steps=20, graph=False):
     m.set_optimizer(o)
     m.compile([x], is_train=True, use_graph=graph)
     for _ in range(steps):
+        if watch is not None:
+            watch(x.to_numpy(), [p.to_numpy() for p in m.param_tensors()])
         m(x, y)
     params = [np.array(p.to_numpy()) for p in m.param_tensors()]
     return params, o
@@ -102,20 +106,56 @@ def test_slot_dtype_bf16_bounded_drift(opt_fn):
     stays within a small relative bound (the drift is the per-step
     slot quantization only — master math is fp32), the slots really
     are stored bf16, and the policy really engaged (params are not
-    bit-identical to the fp32 run)."""
-    ref, _ = _train_mlp(opt_fn, None)
-    low, o = _train_mlp(opt_fn, "bfloat16")
+    bit-identical to the fp32 run).
+
+    Judged by the reference's own margin where it has none: the loss
+    is not smooth where a hidden unit's pre-activation crosses zero.
+    A sample may fall on the other side of a ReLU than in the
+    reference run only at a step where the REFERENCE holds that
+    pre-activation within the stated error (`atol`) of zero; from
+    that step on the unit's incoming weights get another gradient,
+    and Adam, which normalises a small gradient to a full step, walks
+    them apart (under jax 0.9: unit 11, sample 7, 1e-4 from zero at
+    step 10; W1[6, 11] ends 0.014 apart; three units flip in all, at
+    3e-5, 1e-4 and 1.6e-3, none under SGD). Such a unit's incoming
+    weights and bias are held to four times the bound, every other
+    element to the bound; a flip at a wider margin fails, and so do
+    flips in more than an eighth of the units."""
+    rtol, atol = 5e-2, 5e-3
+
+    def pre_activations(into):
+        return lambda x, params: into.append(x @ params[0] + params[1])
+
+    h_ref, h_low = [], []                     # [steps][batch, units]
+    ref, _ = _train_mlp(opt_fn, None, watch=pre_activations(h_ref))
+    low, o = _train_mlp(opt_fn, "bfloat16", watch=pre_activations(h_low))
     for st in o.states.values():
         for name, arr in st.items():
             assert str(arr.dtype) == "bfloat16", (name, arr.dtype)
-    engaged = False
-    for a, b in zip(ref, low):
+    h_ref, h_low = np.asarray(h_ref), np.asarray(h_low)
+    flipped = (h_ref > 0) != (h_low > 0)
+    assert (np.abs(h_ref[flipped]) < atol).all(), (
+        "a sample changed sides of a ReLU the reference holds wide open")
+    on_a_kink = flipped.any((0, 1))                           # [units]
+    assert on_a_kink.sum() <= len(on_a_kink) // 8, on_a_kink.sum()
+    # fc1.W [12, 32] and fc1.b [32] feed the units; fc2 sees a unit's
+    # output, which is continuous across the kink
+    loosened = [np.broadcast_to(on_a_kink, ref[0].shape), on_a_kink,
+                np.zeros(ref[2].shape, bool), np.zeros(ref[3].shape, bool)]
+
+    def bounded(cand):
         # rtol for O(1) weights, atol for near-zero ones (a relative
         # bound on a ~1e-3 weight would measure noise, not drift)
-        np.testing.assert_allclose(b, a, rtol=5e-2, atol=5e-3,
-                                   err_msg="slot-dtype drift unbounded")
-        engaged = engaged or not np.array_equal(a, b)
-    assert engaged, "bf16 slots produced bit-identical params: not on?"
+        return all(
+            (np.abs(b - a) <= np.where(k, 4.0, 1.0)
+             * (atol + rtol * np.abs(a))).all()
+            for a, b, k in zip(ref, cand, loosened))
+
+    assert bounded(low), "slot-dtype drift unbounded"
+    assert not all(np.array_equal(a, b) for a, b in zip(ref, low)), (
+        "bf16 slots produced bit-identical params: not on?")
+    # what the bound still refuses: a drift of 10 %
+    assert not bounded([a * 1.1 for a in ref])
 
 
 def test_slot_dtype_graph_mode_trains_and_stays_bf16():
@@ -362,8 +402,8 @@ def test_auto_route_threshold_is_configurable():
 def test_auto_route_matches_walk_bitwise():
     """Auto-routing is a pure dispatch decision: the CIFAR CNN's loss
     under globally-enabled auto equals the forced walk bit-for-bit
-    (the acceptance criterion's correctness half; the <=5% step-time
-    half is measured by benchmarks/eager_overhead.py on hardware)."""
+    (the correctness half; its step time on the chip: not
+    measured)."""
     import sys
 
     sys.path.insert(0, os.path.join(

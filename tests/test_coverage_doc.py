@@ -74,7 +74,6 @@ def test_export_cache_row_and_readme_section_present():
     assert "## AOT warm start" in readme
     assert "set_export_cache" in readme
     assert "set_shape_buckets" in readme
-    assert "warm_start_speedup" in readme
     assert "export_cache_gc" in readme
 
 
@@ -91,7 +90,6 @@ def test_serving_row_and_readme_section_present():
     assert "## Serving" in readme
     assert "ServingEngine" in readme
     assert "set_serving" in readme
-    assert "serve_requests_per_sec" in readme
     assert "prewarm" in readme
     assert "BucketOverflowError" in readme
 
@@ -135,7 +133,6 @@ def test_autotune_row_and_readme_sections_present():
     assert "## Remat policies" in readme
     assert "set_remat_policy" in readme
     assert "peak_bytes_estimate" in readme
-    assert "--tuned" in readme
     assert "SINGA_TPU_TUNED_STORE" in readme
     for policy in ("dots_saveable", "nothing_saveable",
                    "save_anything_but_these_names"):
@@ -157,11 +154,8 @@ def test_parallel_trainer_row_and_readme_section_present():
     assert "set_parallel_plan" in readme
     assert "PipelineStack" in readme
     assert "1f1b" in readme and "gpipe" in readme
-    assert "pipeline_images_per_sec" in readme
-    assert "moe_tokens_per_sec" in readme
     assert "dropped_frac" in readme
     assert "mesh_geometry" in readme
-    assert "--stage parallel" in readme
 
 
 def test_fleet_row_and_readme_section_present():
@@ -182,7 +176,6 @@ def test_fleet_row_and_readme_section_present():
     assert "create_replica_device" in readme
     assert "--verify-store" in readme
     assert "serve_health.py --all" in readme
-    assert "--stage fleet" in readme
 
 
 def test_proc_fleet_row_and_readme_section_present():
@@ -205,7 +198,6 @@ def test_proc_fleet_row_and_readme_section_present():
     assert "heartbeat_interval_s" in readme
     assert "max_inflight" in readme
     assert "make_replicas" in readme
-    assert "--transport proc" in readme
     assert "proc_sigkill" in readme
     assert "ipc_deadline_ms" in readme
     # the boot gate stays documented next to the multi-process flow
@@ -246,22 +238,19 @@ def test_fleet_tracing_row_and_readme_section_present():
     assert "aggregate_fleet" in readme
     assert "fleet_top.py" in readme
     assert "ship_capacity" in readme
-    assert "latency_breakdown" in readme
-    assert "fleet_trace_overhead_pct" in readme
 
 
 def test_decode_serving_row_and_readme_section_present():
     """ISSUE 16 doc contract: the P24 continuous-batching decode-tier
     row and the README "Decode serving" section exist (KV-slot pool
     admission, cohort prefill, run-ahead blocks, warm_decode, the 4th
-    reconciliation equation, TTFT/TPOT SLOs, knobs, bench gate)."""
+    reconciliation equation, TTFT/TPOT SLOs, knobs)."""
     cov = open(os.path.join(_ROOT, "COVERAGE.md")).read()
     assert "| P24 |" in cov
     assert "tests/test_serve_decode.py" in cov
     assert "submit_decode" in cov
     assert "prefill_slab" in cov
     assert "warm_decode" in cov
-    assert "serve-decode" in cov
     assert "set_decode_serving" in cov
     readme = open(os.path.join(_ROOT, "README.md")).read()
     assert "## Decode serving" in readme
@@ -271,7 +260,6 @@ def test_decode_serving_row_and_readme_section_present():
     assert "warm_decode" in readme
     assert "decode_block" in readme
     assert "ttft" in readme and "tpot" in readme
-    assert "serve_decode_tokens_per_sec" in readme
     assert "set_decode_serving" in readme
 
 
@@ -279,15 +267,13 @@ def test_fleet_decode_row_and_readme_section_present():
     """ISSUE 17 doc contract: the P25 fleet-wide decode row and the
     README "Fleet decode serving" section exist (session-affine
     occupancy routing, live KV-slab migration, resume-vs-replay, the
-    error taxonomy, fleet-wide reconciliation, the 1.7x bench
-    gate)."""
+    error taxonomy, fleet-wide reconciliation)."""
     cov = open(os.path.join(_ROOT, "COVERAGE.md")).read()
     assert "| P25 |" in cov
     assert "tests/test_fleet_decode.py" in cov
     assert "export_decode_sessions" in cov
     assert "resume_decode" in cov
     assert "FleetDecodeReply" in cov
-    assert "fleet-decode" in cov
     assert "max_failover_hops" in cov
     readme = open(os.path.join(_ROOT, "README.md")).read()
     assert "## Fleet decode serving" in readme
@@ -296,10 +282,7 @@ def test_fleet_decode_row_and_readme_section_present():
     assert "export_decode_sessions" in readme
     assert "resume_decode" in readme
     assert "ServeMigratedError" in readme
-    assert "fleet_decode_tokens_per_sec" in readme
-    assert "1.7x" in readme
     assert "decode0=" in readme
-    assert "fleet-decode" in readme
 
 
 def test_tcp_transport_row_and_readme_section_present():
@@ -318,7 +301,6 @@ def test_tcp_transport_row_and_readme_section_present():
     assert "max_frame_bytes" in cov
     assert "tests/test_netchaos.py" in cov
     assert "tests/test_fleet_tcp.py" in cov
-    assert "--net-faults" in cov
     readme = open(os.path.join(_ROOT, "README.md")).read()
     assert "## Multi-host fleet" in readme
     assert "-m singa_tpu.fleet_worker" in readme
@@ -331,7 +313,6 @@ def test_tcp_transport_row_and_readme_section_present():
     assert "reconnect_window_s" in readme
     assert "max_frame_bytes" in readme
     assert "ChaosProxy" in readme
-    assert "--net-faults" in readme
 
 
 def test_quant_row_and_readme_section_present():
@@ -339,7 +320,7 @@ def test_quant_row_and_readme_section_present():
     the README "Quantized inference" section exist (the knob, the
     calibration recipe, the error taxonomy including the
     weight-dequant materialization regime, what is and is not
-    bit-exact, the packed migration form, the bench arms)."""
+    bit-exact, the packed migration form)."""
     cov = open(os.path.join(_ROOT, "COVERAGE.md")).read()
     assert "| P27 |" in cov
     assert "singa_tpu/quant.py" in cov
@@ -360,7 +341,6 @@ def test_quant_row_and_readme_section_present():
     assert "Error taxonomy" in readme
     assert "bytes_accessed" in readme
     assert "--quant int8" in readme
-    assert "--stage fleet-decode --quant int8" in readme
 
 
 def test_slo_row_and_readme_section_present():
@@ -368,7 +348,7 @@ def test_slo_row_and_readme_section_present():
     README "SLO monitoring" section exist (mergeable sketches with
     the bit-identical-merge claim, burn-rate windows + flap
     suppression, per-replica anomaly detectors, the knob, byte
-    absence when disabled, the bench crosscheck + chaos alert gate,
+    absence when disabled, the sketch-against-samples crosscheck,
     the tools)."""
     cov = open(os.path.join(_ROOT, "COVERAGE.md")).read()
     assert "| P28 |" in cov
@@ -389,5 +369,4 @@ def test_slo_row_and_readme_section_present():
     assert "uncertainty_us" in readme
     assert "fleet_segment_samples_ms" in readme
     assert "metrics_lint.py" in readme
-    assert "tpu_watch.sh slo" in readme
     assert "alerts JSONL" in readme

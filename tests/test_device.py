@@ -52,8 +52,9 @@ def test_cpu_is_handed_out_only_when_asked_for(monkeypatch):
 
 def test_importing_the_package_creates_no_backend():
     """A process that has created a jax backend holds the chip, so the
-    parents that spawn chip children (bench.py main, stage_pallas,
-    stage_parity) may import the package but nothing more."""
+    parents that spawn chip children (`fleet_proc.ProcReplica`'s,
+    `benchmarks/pallas_tune.py`) may import the package but nothing
+    more."""
     import subprocess
     import sys
 

@@ -520,6 +520,9 @@ def test_bucketed_forward_bounds_retraces_under_random_traffic():
     m, tx, ty = _build(x, y)
     m.eval()
     device.set_shape_buckets(max_batch=64)
+    # `buckets_seen` is one set a process: start this test's count
+    # clean, whatever ran in the worker before it
+    stats.reset_cache_stats()
     rs = np.random.RandomState(7)
     sizes = [int(s) for s in rs.randint(1, 65, size=30)]
     for n in sizes:

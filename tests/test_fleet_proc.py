@@ -195,7 +195,13 @@ def test_proc_fleet_sigkill_failover_respawn_and_health(tmp_path):
             got = f.result(60)
             assert got.tobytes() == refs[i].tobytes(), f"request {i}"
         assert all(f.done() for f in futs)
-        # the kill was DETECTED (exit code), not arranged
+        # the kill was DETECTED (exit code), not arranged; the reaper
+        # records it on its own thread, so wait for the record, not
+        # for the replies (failover can deliver them first)
+        deadline = time.time() + 60
+        while (victim.transport_snapshot()["generations"][1]["exit_code"]
+               is None and time.time() < deadline):
+            time.sleep(0.005)
         snap = victim.transport_snapshot()
         assert snap["generations"][1]["exit_code"] == -9
         # supervisor notices the death (killed flag via reader EOF),

@@ -538,27 +538,6 @@ def test_i_gpt2_slab_rows_are_generates_streams_through_growth(gpt2, quant,
     assert np.array_equal(got, want)
 
 
-@pytest.mark.parametrize("quant", ["plain", "int8"])
-def test_i_gpt2_a_pad_row_writes_nothing(gpt2, quant):
-    import jax
-
-    m = gpt2
-    params = (m._decode_params_quant() if quant == "int8"
-              else m._decode_params())
-    slab = m.new_slab(params, 2, 16, jax.devices()[0])
-    rows = [ids_of((5,), 41), ids_of((4,), 42)]
-    ids = np.zeros((2, 8), np.int32)
-    ids[0, :5], ids[1, :4] = rows
-    # row 0 goes to slot 1; row 1's slot is out of bounds
-    _, slab = m.prefill_slab(params, slab, put(ids),
-                             put(np.asarray([5, 4], np.int32)),
-                             put(np.asarray([1, 2], np.int32)))
-    for leaf in jax.tree_util.tree_leaves(slab):
-        leaf = np.asarray(leaf)
-        assert not leaf[:, 0].any() and leaf[:, 1].any()
-        assert not leaf[:, 1, ..., 8:].any()     # the bucket, no further
-
-
 @pytest.fixture(params=["gpt2", "hybrid"])
 def either(request):
     return request.getfixturevalue(

@@ -156,6 +156,27 @@ def test_microbatches_default_is_pipe_size(mesh4):
     assert note["microbatches"] == 4 and note["stages"] == 4
 
 
+@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
+@pytest.mark.parametrize("microbatches", [4, 8])
+def test_build_note_states_the_schedules_geometry(mesh4, microbatches,
+                                                  schedule):
+    """`cache_stats()["parallel"]["pipeline"]` after a build: the
+    analytic bubble (P-1)/(M+P-1) and the tick count, doubled where
+    1F1B runs forward and backward in one pass. (A measured bubble is
+    a device time and comes from a chip run, never from here.)"""
+    from singa_tpu import stats
+
+    P, M = 4, microbatches
+    stacked = place_stacked(stack_stage_params(_stages(P, 8)), mesh4)
+    pipeline_apply(_mlp_stage, stacked, jnp.zeros((8, 8), jnp.float32),
+                   mesh4, microbatches=M, schedule=schedule)
+    assert stats.cache_stats()["parallel"]["pipeline"] == {
+        "stages": P, "microbatches": M, "schedule": schedule,
+        "bubble_ticks": P - 1,
+        "ticks": (M + P - 1) * (2 if schedule == "1f1b" else 1),
+        "bubble_fraction": round((P - 1) / (M + P - 1), 6)}
+
+
 def test_unknown_schedule_raises(mesh4):
     stacked = place_stacked(stack_stage_params(_stages(4, 8)), mesh4)
     with pytest.raises(ValueError, match="schedule"):

@@ -152,9 +152,8 @@ def test_guard_counters_via_model_cache_stats():
 def test_guard_stays_one_fused_executable():
     """The ≤1 % overhead mechanism, asserted structurally: the guarded
     eager step still runs as ONE cached fused executable — warmup
-    traces only, zero retraces afterwards, one hit per step (the
-    wall-clock number is printed by benchmarks/eager_overhead.py's
-    step_guard A/B)."""
+    traces only, zero retraces afterwards, one hit per step (what it
+    costs on the chip: not measured)."""
     device.set_step_guard(True)
     m, tx, ty = _build()
     stats.reset_cache_stats()

@@ -318,10 +318,39 @@ def test_warm_decode_precompiles_ladder(lm):
     assert np.array_equal(np.asarray(got), want)
 
 
+def test_warm_decode_compiles_the_samplers_traffic_will_use(lm):
+    """`warm_decode(samplers=[(temperature, top_k)])` compiles that
+    pair's sampling program too (greedy has none), so a sampled
+    session admitted afterwards traces nothing inside its first
+    token's budget: no decode-tier retrace, no new entry in the
+    model's program cache, and its stream is `generate()`'s."""
+    p = _prompts(1)[0]
+    want = lm.generate(p, NEW, temperature=0.7, top_k=8, seed=5)
+    eng = serve.ServingEngine(lm, max_sessions=4, max_new_tokens=NEW,
+                              prefill_batch=4, decode_block=4).start()
+    try:
+        plain = eng.warm_decode(prompt_lens=(2, 3, 5), max_new_tokens=NEW,
+                                samplers=[(0.0, 0)])
+        warmed = eng.warm_decode(prompt_lens=(2, 3, 5),
+                                 max_new_tokens=NEW,
+                                 samplers=[(0.0, 0), (0.7, 8)])
+        held = len(lm._gen_cache)
+        traced = stats.cache_stats()["decode"]["retraces"]
+        got = eng.submit_decode(p, NEW, temperature=0.7, top_k=8,
+                                seed=5).result(timeout=60)
+    finally:
+        eng.stop()
+    assert warmed == plain + 1
+    assert len(lm._gen_cache) == held
+    assert stats.cache_stats()["decode"]["retraces"] == traced
+    assert np.array_equal(np.asarray(got), want)
+
+
 def test_ttft_tpot_spans_under_tracing(lm):
     """The decode tier emits the PR 15 SLO segments: one `ttft` span
     per session (submit → first token) and `tpot` spans for the
-    inter-token gaps — the segments bench.py aggregates into p50/p99."""
+    inter-token gaps — the segments `trace.aggregate_fleet` and the SLO
+    engine fold into p50/p99."""
     from singa_tpu import trace as trace_mod
 
     prompts = _prompts(3)

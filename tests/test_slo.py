@@ -432,6 +432,45 @@ def test_serving_engine_feeds_segments_end_to_end():
         device.set_slo(False)
 
 
+def test_online_sketches_agree_with_the_traces_own_samples():
+    """The cross-validation of the two telemetry paths, on a real
+    engine: with tracing and the SLO engine both armed, each request
+    segment's online sketch has counted exactly the spans the trace
+    holds (`trace.fleet_segment_samples_ms`), and its p99 sits within
+    twice the sketch's relative-error bound of the p99 of those raw
+    samples under the sketch's own rank convention
+    (`slo.rank_quantile`)."""
+    from benchmarks import fleet_factory
+    from singa_tpu import trace
+
+    rel_err = 0.02
+    device.set_tracing(True)
+    trace.clear()
+    device.set_slo(True, rel_err=rel_err, spec={"availability": 0.999})
+    try:
+        eng = serve.ServingEngine(
+            fleet_factory.create(feats=8, hidden=8, classes=4,
+                                 compile_batch=4),
+            max_batch=4, max_wait_ms=1.0).start()
+        x = np.arange(8, dtype=np.float32).reshape(1, 8) / 8.0
+        for _ in range(40):
+            eng.submit(x).result(timeout=30.0)
+        eng.stop()
+        online = slo.report()["segments"]
+        samples = trace.fleet_segment_samples_ms(spans=trace.records())
+    finally:
+        device.set_slo(False)
+        device.set_tracing(False)
+        trace.clear()
+    for seg in ("queue_wait", "dispatch", "reply"):
+        assert online[seg]["count"] == len(samples[seg]) >= 40, seg
+        assert samples[seg] == sorted(samples[seg])
+        exact = slo.rank_quantile(samples[seg], 0.99)
+        # the snapshot rounds to a microsecond
+        assert abs(online[seg]["p99_ms"] - exact) <= (
+            2 * rel_err * exact + 1e-3), (seg, online[seg], exact)
+
+
 def test_disabled_engine_health_has_no_alerts_key():
     """Byte-identity: with the SLO engine off, health snapshots carry
     no `alerts` key at all (old monitors parse unchanged)."""

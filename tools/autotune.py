@@ -4,9 +4,8 @@ Searches the step knob space (slot dtype x BN-stats dtype x XLA
 profile x accum geometry x scan-level remat policy x Pallas blocks)
 for a model WITHOUT a chip: candidates are scored by the CPU-side HLO
 meter + a roofline cost model (`singa_tpu.tuning`), the winner is
-persisted to the tuned-config store that `bench.py --tuned` and the
-serving tier load by default, and every candidate streams to a JSONL
-that `tools/tpu_watch.sh tune` pretty-tails.
+persisted to the tuned-config store that the serving tier loads by
+default, and every candidate streams to a JSONL.
 
     python tools/autotune.py --model resnet --budget 16
     python tools/autotune.py --model tiny-cnn --budget 8 --platform cpu
@@ -14,8 +13,8 @@ that `tools/tpu_watch.sh tune` pretty-tails.
         metrics/pallas_sweep.jsonl       # Pallas axis joins the search
 
 Fully deterministic under --seed: same seed, same proposals, same
-winner. Prints one final JSON line on stdout (the bench stage
-contract); progress goes to stderr.
+winner. Prints one final JSON line on stdout; progress goes to
+stderr.
 """
 import argparse
 import json
@@ -34,8 +33,7 @@ def log(msg):
 
 
 def _setup_platform(platform, devices=0):
-    """Force a jax platform before backend init (the bench.py
-    BENCH_PLATFORM idiom). `devices` > 0
+    """Force a jax platform before backend init. `devices` > 0
     requests that many VIRTUAL host devices (CPU only) so the
     multi-axis mesh-geometry knobs (ISSUE 10) can be scored without a
     chip — must land in XLA_FLAGS before the backend client exists."""
@@ -85,7 +83,7 @@ def _factories(args):
             return [x, y]
 
         # both granularities: the depth-keyed name AND the plain
-        # "resnet" that `bench.py --tuned` resolves
+        # "resnet" a caller resolves before a model exists
         return model_factory, make_inputs, [f"resnet-{args.depth}",
                                             "resnet"]
 
@@ -232,8 +230,7 @@ def main():
                    "tuned_configs.json)")
     p.add_argument("--jsonl", default="",
                    help="search-candidate JSONL (default: metrics/"
-                   "autotune_<model>.jsonl; tools/tpu_watch.sh tune "
-                   "tails it)")
+                   "autotune_<model>.jsonl)")
     p.add_argument("--pallas-jsonl", default="",
                    help="per-config sweep JSONL from benchmarks/"
                    "pallas_tune.py --jsonl: arms the Pallas "

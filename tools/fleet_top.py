@@ -3,8 +3,8 @@
 
 Rolls the fleet's telemetry — the router's control-plane metrics
 JSONL, the per-replica/worker serving JSONLs, and (optionally) the
-merged Chrome trace `FleetRouter.export_trace` / `bench.py --stage
-fleet` writes — into ONE aggregated view via
+merged Chrome trace `FleetRouter.export_trace` writes — into ONE
+aggregated view via
 `singa_tpu.trace.aggregate_fleet`:
 
   - availability (router replies / requests) + terminal counters

@@ -5,8 +5,8 @@ Every telemetry stream this repo writes is schema-stable by contract:
 a `MetricsLogger` record (training/serving metrics) and an SLO alert
 record each carry a `schema` version and a FIXED key set — fields are
 always present, `None` when unknown, and never renamed in place.
-Downstream folds (`aggregate_fleet`, `fleet_top`, `fold_onchip`)
-lean on that stability, so a drifted writer should fail a lint, not
+Downstream folds (`aggregate_fleet`, `fleet_top`) lean on that
+stability, so a drifted writer should fail a lint, not
 silently shade a dashboard.
 
 This linter validates streams against the schema-version registry:

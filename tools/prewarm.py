@@ -29,7 +29,7 @@ DESERIALIZE-only — the request path never traces (ISSUE 7 satellite;
         --quant int8 --verify-store
 
 `--dir` points at the artifact store (default `.export_cache/`, the
-same default `bench.py` and `SINGA_TPU_EXPORT_CACHE` use). Exit code:
+same default `SINGA_TPU_EXPORT_CACHE` uses). Exit code:
 0 when every bucket is present/built, 1 when `--dry-run` /
 `--verify-store` found missing artifacts (CI-able: "is this store
 provisioned for this config?").
