@@ -434,10 +434,35 @@ def _kernel_attention(b, h, s, d, dtype):
     return max(errs)
 
 
+def _kernel_decode_attend(b, h, d, t):
+    """One query a row against the blocks that row has written, rows
+    of every length in one call, against the two `einsum`s over the
+    rung at fp32 precision."""
+    import jax
+    import jax.numpy as jnp
+
+    from singa_tpu.models.transformer import rung_attend
+    from singa_tpu.ops import pallas_kernels as pk
+
+    rs = np.random.RandomState(0)
+    layer = jnp.asarray(rs.randn(2, b, h, d, t).astype(np.float32))
+    q = jnp.asarray(rs.randn(b, h, d).astype(np.float32))
+    pos = rs.randint(0, t, b).astype(np.int32)
+    pos[:4] = (0, 127, 128, t - 1)
+    pos = jnp.asarray(pos)
+    scale = 1.0 / float(np.sqrt(d))
+    got = jax.jit(lambda l, q, p: pk.decode_attend(l, q, p, scale))(
+        layer, q, pos)
+    want = jax.jit(lambda l, q, p: rung_attend(l, q, p, scale, "highest"))(
+        layer, q, pos)
+    return _close(f"decode_attend {b}x{h}x{d}x{t}", got, want, 1e-5, 1e-5)
+
+
 def phase_kernels(xent_shapes=((128, 1000), (8192, 32000)),
                   attn_cases=((8, 8, 1024, 64, "bfloat16"),
                               (8, 8, 1024, 64, "float32"),
                               (2, 12, 1024, 64, "float32")),
+                  decode_cases=((32, 12, 64, 1024), (64, 12, 64, 256)),
                   lm=(32000, 512, 8, 8), lm_batch=8, lm_seq=1024,
                   lm_steps=3, interpret=False, platform="tpu"):
     """The Pallas tier, compiled: each kernel fwd+bwd against the jnp
@@ -461,6 +486,9 @@ def phase_kernels(xent_shapes=((128, 1000), (8192, 32000)),
         for b, h, s, d, dtype in attn_cases:
             res["max_abs_err"][f"attn_{b}x{h}x{s}x{d}_{dtype}"] = \
                 _kernel_attention(b, h, s, d, dtype)
+        for b, h, d, t in decode_cases:
+            res["max_abs_err"][f"decode_attend_{b}x{h}x{d}x{t}"] = \
+                _kernel_decode_attend(b, h, d, t)
 
         vocab, d_model, heads, layers = lm
         _check(pk.attn_supported(lm_seq, d_model // heads),

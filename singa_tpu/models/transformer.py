@@ -59,8 +59,29 @@ class TransformerBlock(layer.Layer):
         return autograd.add(x, h)
 
 
+def rung_attend(layer, q, pos, scale, prec):
+    """One query a row against a slab layer [2, B, H, D, T] over the
+    WHOLE rung: row b (at position pos[b]) attends slots j <= pos[b],
+    the rest masked. What `decode_attend` computes from the row's own
+    blocks, and its plain reference."""
+    import jax
+    import jax.numpy as jnp
+
+    mask = pos[:, None] >= jnp.arange(layer.shape[-1])[None, :]
+    neg = jnp.asarray(jnp.finfo(q.dtype).min / 2, q.dtype)
+    s = jnp.einsum("bhd,bhdk->bhk", q, layer[0], precision=prec) * scale
+    s = jnp.where(mask[:, None], s, neg)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhk,bhdk->bhd", p, layer[1], precision=prec)
+
+
 class TransformerLM(DecodeLM):
     """Causal LM over int token ids [B, S] → logits [B, S, vocab]."""
+
+    # what `_slot_step` counts a step, summed over rows and layers: the
+    # 128-position blocks `decode_attend` read, and those the rung
+    # holds (0 and 0 where attention takes the `einsum`s)
+    step_counter_names = ("attn_blocks_read", "attn_blocks_rung")
 
     def __init__(self, vocab_size: int, d_model: int = 256,
                  num_heads: int = 8, num_layers: int = 4,
@@ -417,19 +438,31 @@ class TransformerLM(DecodeLM):
         a layer's write touches that layer alone, and positions LAST,
         which is how the chip lays a layer out whatever its shape
         says. Both rows of a step (key and value) go in through one
-        `cache_write`, in place in the donated slab, and the two
-        attention products contract against the layer as stored: no
-        operation of the program moves a whole layer but that write
-        (`tests/test_tpu_compile_widths.py`; in [2, B, H, T, D] the
-        write was re-laid out and back, 24 whole-layer copies a
-        program). The mathematics is `_stack_step`'s at S=1 (same
-        products over the same float32 values, same mask constant),
-        so a slab row decodes the same request's `generate()` stream.
-        Returns (logits [B, V], new per-layer cache list)."""
+        `cache_write`, in place in the donated slab, and attention
+        reads the layer as stored: no operation of the program moves a
+        whole layer but that write (`tests/test_tpu_compile_widths.py`;
+        in [2, B, H, T, D] the write was re-laid out and back, 24
+        whole-layer copies a program). The mathematics is
+        `_stack_step`'s at S=1 (same products over the same float32
+        values, same mask constant), so a slab row decodes the same
+        request's `generate()` stream.
+
+        Which attention, from the slab alone: a float32 rung of
+        several 128-position blocks goes through `decode_attend`,
+        which reads of row b the blocks 0..pos[b] // 128 and no more
+        (ISSUE 30: the sessions of `gpt2-serve-decode` hold a quarter
+        of their rung); a rung of one block has nothing to skip, and
+        an int8 slab is dequantized a whole layer at a time, so both
+        keep the two `einsum`s over the rung.
+
+        Returns (logits [B, V], new per-layer cache list, the step's
+        `step_counter_names`: blocks read, blocks the rung holds)."""
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pallas_kernels import cache_write
+        from ..ops.pallas_kernels import (DECODE_ATTEND_BLOCK, cache_write,
+                                          decode_attend,
+                                          decode_attend_blocks)
 
         H = self.blocks._seq[0].attn.num_heads
         B = tok.shape[0]
@@ -443,11 +476,9 @@ class TransformerLM(DecodeLM):
         E = h.shape[-1]
         D = E // H
         scale = 1.0 / float(np.sqrt(D))
-        # row b (absolute position pos[b]) may attend slot j <= pos[b]
-        mask = pos[:, None] >= jnp.arange(maxT)[None, :]      # [B, maxT]
-        neg = jnp.asarray(jnp.finfo(h.dtype).min / 2, h.dtype)
         at = jnp.concatenate([pos, pos])
         new_cache = []
+        nblk = 0 if qcache else decode_attend_blocks(maxT, cache[0].dtype)
 
         prec = tensor.get_matmul_precision()
 
@@ -491,18 +522,22 @@ class TransformerLM(DecodeLM):
             else:
                 kv_all = write(cache[li], kv)
                 new_cache.append(kv_all)
-            s = jnp.einsum("bhd,bhdk->bhk", q, kv_all[0],
-                           precision=prec) * scale
-            s = jnp.where(mask[:, None], s, neg)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhk,bhdk->bhd", p, kv_all[1], precision=prec)
+            # row b (absolute position pos[b]) attends slots j <= pos[b]
+            if nblk:
+                o = decode_attend(kv_all, q, pos, scale)
+            else:
+                o = rung_attend(kv_all, q, pos, scale, prec)
             h = h + lin(o.reshape(B, 1, E), blk["o"])
             x = self._ln(h, blk["ln2"], eps2)
             h = h + lin(jax.nn.gelu(lin(x, blk["fc1"]),
                                     approximate=False), blk["fc2"])
         h = self._ln(h, params["ln_f"], eps_f)
+        L = len(new_cache)
+        counters = jnp.array(
+            [L * jnp.sum(pos // DECODE_ATTEND_BLOCK + 1) if nblk else 0,
+             L * B * nblk], jnp.int32)
         return (self._head_matmul(h[:, -1], params["head"], prec),
-                new_cache)
+                new_cache, counters)
 
     def decode_step_hlo(self, params, cache, tok, pos,
                         optimized: bool = True) -> str:

@@ -627,3 +627,170 @@ def cache_write(cache, new, at, axis):
         input_output_aliases={1: 0},
         interpret=_interpret(),
     )(at.astype(jnp.int32), cache, new)
+
+
+# -- one query a row against the positions that row has written ---------------
+DECODE_ATTEND_BLOCK = 128   # positions a block: a lane tile, `cache_write`'s
+_DECODE_ATTEND_SLOTS = 4    # blocks in flight or in use at a time
+# the fewest blocks a rung must have for the kernel to be the faster
+# path (one block: nothing to skip)
+DECODE_ATTEND_MIN_BLOCKS = 2
+
+
+def decode_attend_blocks(T, dtype=jnp.float32):
+    """How many blocks `decode_attend` cuts a rung of `T` positions
+    into; 0 where the rung is read whole instead (it has fewer than
+    `DECODE_ATTEND_MIN_BLOCKS`, or no whole number of them, or is not
+    float32)."""
+    n = T // DECODE_ATTEND_BLOCK
+    return 0 if (T % DECODE_ATTEND_BLOCK or n < DECODE_ATTEND_MIN_BLOCKS
+                 or dtype != jnp.float32) else n
+
+
+def _decode_attend_kernel(pos_ref, q_ref, layer_ref, out_ref, buf, sem,
+                          qb, s_scr, acc, *, scale, neg, tb, nslots):
+    """Every row in turn: its key blocks 0..pos[b]//tb (scores into
+    `s_scr`), one exact softmax over the rung, its value blocks (sum
+    into `acc`), its output. The blocks come from HBM by DMAs of the
+    kernel's own, `nslots - 1` ahead of the one in use and straight on
+    from one row's last block to the next row's first, so a row costs
+    the blocks it has and nothing a grid step. All arithmetic is
+    float32 on the vector unit: q is spread over the lanes once a row
+    (`qb`), a key block is multiplied by it and summed over D, a value
+    block by the row of probabilities and summed over the lanes at the
+    row's end."""
+    B, D, H = q_ref.shape
+    nblk = s_scr.shape[0]
+
+    # `lax.div` / `lax.rem` on what is never negative: one operation
+    # each where `//` and `%` lower through a sign correction, which is
+    # most of what lowering this kernel costs a program that holds it
+    def blocks(b):
+        # of a row; never past the rung, whatever `pos` says, and the
+        # cursor that runs ahead of the last row reads no `pos` past it
+        at = pos_ref[jnp.minimum(b, B - 1)]
+        return jnp.minimum(jax.lax.div(at, tb), nblk - 1) + 1
+
+    def dma(b, j, slot):
+        # item j of row b: key block j, then value block j - blocks(b)
+        n = blocks(b)
+        kv = (j >= n).astype(jnp.int32)
+        at = pl.multiple_of((j - kv * n) * tb, tb)
+        return pltpu.make_async_copy(
+            layer_ref.at[kv, b, :, :, pl.ds(at, tb)], buf.at[slot],
+            sem.at[slot])
+
+    def after(b, j):
+        last = j + 1 == 2 * blocks(b)
+        return jnp.where(last, b + 1, b), jnp.where(last, 0, j + 1)
+
+    total = jax.lax.fori_loop(0, B, lambda b, n: n + 2 * blocks(b), 0)
+
+    def keys(b, j, slot):
+        @pl.when(j == 0)
+        def _():
+            for h in range(H):
+                qb[h] = jnp.broadcast_to(q_ref[b, :, h:h + 1], (D, tb))
+
+        for h in range(H):
+            s_scr[j, h] = jnp.sum(buf[slot, h] * qb[h], axis=0,
+                                  keepdims=True)
+
+        @pl.when(j == blocks(b) - 1)
+        def _():
+            s = s_scr[...] * scale                       # [nblk, H, 1, tb]
+            where = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) * tb
+                     + jax.lax.broadcasted_iota(jnp.int32, s.shape, 3))
+            s = jnp.where(where <= pos_ref[b], s, neg)
+            m = jnp.max(jnp.max(s, axis=3, keepdims=True), axis=0,
+                        keepdims=True)
+            e = jnp.exp(s - m)
+            s_scr[...] = e / jnp.sum(jnp.sum(e, axis=3, keepdims=True),
+                                     axis=0, keepdims=True)
+
+    def values(b, j, slot):
+        for h in range(H):
+            pv = buf[slot, h] * s_scr[j, h]              # [D, tb] * [1, tb]
+            # a row's first block starts the sum (what `acc` held is
+            # the row before's, or nothing)
+            acc[h] = jnp.where(j == 0, pv, acc[h] + pv)
+
+        @pl.when(j == blocks(b) - 1)
+        def _():
+            lane = jax.lax.broadcasted_iota(jnp.int32, (D, H), 1)
+            o = jnp.zeros((D, H), jnp.float32)
+            for h in range(H):
+                o = jnp.where(lane == h,
+                              jnp.sum(acc[h], axis=1, keepdims=True), o)
+            out_ref[b] = o
+
+    def start(i, cur):
+        b, j = cur
+
+        @pl.when(i < total)
+        def _():
+            dma(b, j, jax.lax.rem(i, nslots)).start()
+
+        return after(b, j)
+
+    def step(i, carry):
+        (b, j), ahead = carry
+        slot = jax.lax.rem(i, nslots)
+        dma(b, j, slot).wait()
+        n = blocks(b)
+
+        @pl.when(j < n)
+        def _():
+            keys(b, j, slot)
+
+        @pl.when(j >= n)
+        def _():
+            values(b, j - n, slot)
+
+        # the slot just read is the one the next DMA fills
+        return after(b, j), start(i + nslots, ahead)
+
+    zero = (jnp.int32(0), jnp.int32(0))
+    ahead = zero
+    for i in range(nslots):
+        ahead = start(jnp.int32(i), ahead)
+    jax.lax.fori_loop(0, total, step, (zero, ahead))
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def decode_attend(layer, q, pos, scale):
+    """softmax(q . K[:pos+1] * scale) . V[:pos+1] a row: `layer`
+    [2, B, H, D, T] float32 as the slab stores it (keys at [0], values
+    at [1], positions last), `q` [B, H, D], `pos` [B] int32 -> [B, H,
+    D] float32. Of row b only the blocks 0..pos[b] // 128 leave HBM;
+    the last block's tail beyond pos[b] is masked with the constant
+    `_slot_step` masks with, and every product is a float32 product
+    whatever the matmul policy says (the policy rounds operands for
+    the matrix unit; nothing here runs on it)."""
+    _, B, H, D, T = layer.shape
+    tb = DECODE_ATTEND_BLOCK
+    if T % tb:
+        raise ValueError(f"decode_attend: {T} positions do not divide "
+                         f"into blocks of {tb}")
+    nslots = _DECODE_ATTEND_SLOTS
+    neg = float(jnp.finfo(jnp.float32).min / 2)
+    out = pl.pallas_call(
+        functools.partial(_decode_attend_kernel, scale=float(scale),
+                          neg=neg, tb=tb, nslots=nslots),
+        out_shape=jax.ShapeDtypeStruct((B, D, H), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((nslots, H, D, tb), jnp.float32),
+                pltpu.SemaphoreType.DMA((nslots,)),
+                pltpu.VMEM((H, D, tb), jnp.float32),
+                pltpu.VMEM((T // tb, H, 1, tb), jnp.float32),
+                pltpu.VMEM((H, D, tb), jnp.float32)]),
+        name="decode_attend",
+        interpret=_interpret(),
+    )(pos.astype(jnp.int32), jnp.swapaxes(q, 1, 2).astype(jnp.float32),
+      layer)
+    return jnp.swapaxes(out, 1, 2)

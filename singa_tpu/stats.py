@@ -699,6 +699,11 @@ class _DecodeStats:
         self.moe_assignments_local = 0
         self.moe_experts_touched = 0
         self.moe_expert_load_max = 0
+        # `TransformerLM`'s: 128-position blocks of the slab its
+        # length-aware attention read, and those the rung holds, summed
+        # over rows, layers and steps (0 and 0 on the `einsum` path)
+        self.attn_blocks_read = 0
+        self.attn_blocks_rung = 0
         # KV migration (ISSUE 17). `migrated` counts sessions exported
         # off this engine's books (each decrements `sessions` too, so
         # the 4-equation reconciliation stays exact per engine: the
@@ -726,6 +731,8 @@ class _DecodeStats:
             "moe_assignments_local": self.moe_assignments_local,
             "moe_experts_touched": self.moe_experts_touched,
             "moe_expert_load_max": self.moe_expert_load_max,
+            "attn_blocks_read": self.attn_blocks_read,
+            "attn_blocks_rung": self.attn_blocks_rung,
             "migrated": self.migrated,
             "resumed": self.resumed,
             "slots": self.slots,

@@ -426,7 +426,8 @@ def test_h_gpt2_slab_and_streams_as_before_the_model_stated_them(gpt2,
     import jax.numpy as jnp
 
     m = gpt2
-    assert m.step_counter_names == () and m.scan_unroll == 1
+    assert m.step_counter_names == ("attn_blocks_read", "attn_blocks_rung")
+    assert m.scan_unroll == 1
     device.set_inference_quant(quant)
     eng = serve.ServingEngine(m, max_sessions=3, max_new_tokens=40,
                               prefill_batch=2, decode_block=4).start()

@@ -74,6 +74,7 @@ def test_kernels_phase_toy(monkeypatch):
         xent_shapes=((16, 40),),
         attn_cases=((1, 2, 64, 32, "bfloat16"),
                     (1, 2, 64, 32, "float32")),
+        decode_cases=((5, 2, 16, 256),),
         lm=(97, 64, 4, 2), lm_batch=2, lm_seq=64, lm_steps=3,
         interpret=True, platform="cpu")
     assert res["ok"] and res["lm_loss_last"] < res["lm_loss_first"]
