@@ -1779,9 +1779,11 @@ class ServingEngine:
         return params, Sb, Tslab
 
     def _note_slab_bytes(self, dst) -> None:
+        """A gauge for each kind of entry the model states its slab
+        has; a kind this model does not state reads 0."""
         by_kind = self.model.slab_bytes(self._slab)
-        dst.cache_bytes_ring = int(by_kind["ring"])
-        dst.cache_bytes_context = int(by_kind["context"])
+        dst.cache_bytes = {kind: int(by_kind.get(kind, 0))
+                           for kind in {**dst.cache_bytes, **by_kind}}
 
     def _grow_slab(self, need_t: int):
         """Climb the sequence ladder mid-stream: the model pads what

@@ -673,11 +673,13 @@ class _DecodeStats:
         # `jax.Array` (counted once at slab build). Each one is a
         # host-to-device transfer on EVERY decode-tier call; must be 0
         self.host_leaves_per_call = 0
-        # gauges: the live slab's bytes by kind, as the model states
-        # them at `_build_slab` / `_grow_slab`: layers that hold a ring
-        # of window positions, layers that hold the whole context
-        self.cache_bytes_ring = 0
-        self.cache_bytes_context = 0
+        # gauges `cache_bytes_<kind>`: the live slab's bytes by kind, as
+        # the model states them at `_build_slab` / `_grow_slab`: layers
+        # that hold a ring of window positions, layers that hold the
+        # whole context, layers that hold a fixed-size state (these
+        # three read 0 before any slab; `_note_slab_bytes` adds a
+        # further kind when a model states one)
+        self.cache_bytes = {"ring": 0, "context": 0, "state": 0}
 
     def reset(self) -> None:
         self.cache.reset()
@@ -738,8 +740,8 @@ class _DecodeStats:
             "slots": self.slots,
             "slots_in_use": self.slots_in_use,
             "host_leaves_per_call": self.host_leaves_per_call,
-            "cache_bytes_ring": self.cache_bytes_ring,
-            "cache_bytes_context": self.cache_bytes_context,
+            **{f"cache_bytes_{kind}": n
+               for kind, n in self.cache_bytes.items()},
         })
         return out
 

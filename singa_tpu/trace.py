@@ -812,7 +812,7 @@ class MetricsLogger:
     _GAUGE_KEYS = frozenset({
         # decode slot pool / LRU cache occupancy
         "slots", "slots_in_use", "size", "negative_size", "capacity",
-        "host_leaves_per_call", "cache_bytes_ring", "cache_bytes_context",
+        "host_leaves_per_call",
         # serve queue live state, watermarks, derived ratios
         "queue_depth", "max_queue_depth", "effective_wait_ms",
         "coalesce_mean", "occupancy", "max_coalesce",
@@ -821,6 +821,8 @@ class MetricsLogger:
         # dag_route config knob
         "flops_per_op_threshold",
     })
+    # and the live slab's bytes by kind, whatever kinds a model states
+    _GAUGE_PREFIX = "cache_bytes_"
 
     # -- record construction ----------------------------------------------
     def _cache_delta(self, snap: Dict) -> Dict:
@@ -848,6 +850,7 @@ class MetricsLogger:
                     p = {}
                 out[name] = {
                     k: (v if k in self._GAUGE_KEYS
+                        or k.startswith(self._GAUGE_PREFIX)
                         else round(v - p.get(k, 0), 6)
                         if isinstance(v, float) else v - p.get(k, 0))
                     for k, v in s.items()}

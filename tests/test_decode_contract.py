@@ -16,6 +16,8 @@ here for each form alike, at toy widths on the CPU:
                       every row (the decode step of the mimo cell)
   hybrid-sorted       the same model, assignments sorted by expert
                       (its prefill path)
+  shortconv           `ShortConvMoELM`: convolution states beside
+                      contexts, every expert held (the lfm2 cell)
 
 A form that cannot meet a point says so as a skipped case, with the
 model's own reason. The next decode-tier model adds one row to FORMS.
@@ -25,13 +27,14 @@ import pytest
 
 from singa_tpu import device, stats, tensor
 from singa_tpu.models.hybrid_moe import HybridWindowMoELM
+from singa_tpu.models.shortconv_moe import ShortConvMoELM
 from singa_tpu.models.transformer import TransformerLM
 
 V, D = 64, 32
 MAXLEN = 64
 WINDOW = 4
 FORMS = ["lm-layernorm-tied", "lm-rmsnorm-untied", "lm-int8",
-         "hybrid-dense", "hybrid-sorted"]
+         "hybrid-dense", "hybrid-sorted", "shortconv"]
 
 
 @pytest.fixture(autouse=True)
@@ -51,9 +54,22 @@ class Form:
         dev = device.get_default_device()
         dev.SetRandSeed(11)
         self.name = name
-        self.hybrid = name.startswith("hybrid")
+        # the models drawn on the device (`DrawnDecodeLM`): a slab of
+        # more than one kind of entry, slots first in every leaf
+        self.hybrid = name.startswith(("hybrid", "shortconv"))
         self.int8 = name == "lm-int8"
-        if self.hybrid:
+        # what does not climb the ladder beside the contexts
+        self.fixed_kind = {"hybrid": "ring", "shortc": "state"}.get(name[:6])
+        if name == "shortconv":
+            # d_model 48: the contract's rungs (16, 32) are no axis of
+            # a state [slots, 2, d_model]
+            m = ShortConvMoELM(
+                V, d_model=48, num_heads=4, kv_heads=2, head_dim=12,
+                layer_types=("conv", "full_attention", "conv"),
+                num_dense_layers=1, d_ff=64, d_ff_expert=16, n_experts=8,
+                experts_per_token=2, held=(0, 8), max_len=MAXLEN,
+                init_std=0.3)
+        elif self.hybrid:
             m = HybridWindowMoELM(
                 V, d_model=D, num_heads=4, head_dim=12, v_head_dim=8,
                 kv_heads_full=1, kv_heads_window=2, window=WINDOW,
@@ -73,7 +89,8 @@ class Form:
         m.eval()
         self.m = m
         # a leaf's slots: axis 1 of [2, B, H, D, T] and of the int8
-        # scales [2, B, T]; axis 0 of the hybrid model's keys and values
+        # scales [2, B, T]; axis 0 of the drawn models' keys, values
+        # and states
         self.slot_axis = 0 if self.hybrid else 1
 
     @property
@@ -251,7 +268,7 @@ def test_a_row_whose_slot_is_out_of_bounds_is_dropped(form):
                                   slot_of(form, b, kept))
         assert not slot_of(form, a, 1).any()
         assert slot_of(form, b, 1).any()
-        if rung in b.shape:                 # a context, not a ring
+        if rung in b.shape:                 # a context: no ring, no state
             past = np.take(b, np.arange(bucket, rung),
                            axis=b.shape.index(rung))
             assert not past.any()
@@ -302,17 +319,19 @@ def test_exported_rows_import_into_another_slot_bit_for_bit(form):
 # -- 5 ---------------------------------------------------------------------
 def test_slab_bytes_are_the_leaves_and_growth_keeps_what_was_written(form):
     """`slab_bytes` by kind adds up to the leaves' bytes (rings only
-    where the model has window layers); `grow_slab` moves the slab to
-    a longer rung with every written position where it was, zeros
-    behind, rings as they were; and the rows decode on from there."""
+    where the model has window layers, states only where it has
+    convolution layers); `grow_slab` moves the slab to a longer rung
+    with every written position where it was, zeros behind, rings and
+    states as they were; and the rows decode on from there."""
     tok, pos, slab = started(form)
     lg, slab = step(form, slab, tok, pos)
     tok, pos = lg.argmax(-1).astype(np.int32), pos + 1
     by_kind = form.m.slab_bytes(slab)
-    assert set(by_kind) == {"ring", "context"}
+    assert set(by_kind) == {form.fixed_kind or "ring", "context"}
     assert sum(by_kind.values()) == sum(
         leaf.size * leaf.dtype.itemsize for leaf in leaves(slab))
-    assert (by_kind["ring"] > 0) == form.hybrid and by_kind["context"] > 0
+    assert by_kind["context"] > 0
+    assert (by_kind.get(form.fixed_kind, 0) > 0) == form.hybrid
     assert form.m.slab_dims(slab) == (3, 16)
     small = host(slab)
     grown = form.m.grow_slab(slab, 32)
@@ -320,7 +339,7 @@ def test_slab_bytes_are_the_leaves_and_growth_keeps_what_was_written(form):
     longer = 0
     for a, b in zip(small, host(grown)):
         assert a.dtype == b.dtype
-        if a.shape == b.shape:              # a ring
+        if a.shape == b.shape:              # a ring or a state
             assert np.array_equal(a, b)
             continue
         longer += 1

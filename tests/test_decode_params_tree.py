@@ -176,12 +176,34 @@ def _build_hybrid(seed=3):
     return m
 
 
+def _build_shortconv(seed=3):
+    """The short-convolution mixture-of-experts LM at a toy size: its
+    tree holds the taps, the head norms' gains, the router's bias and
+    every expert's matrices, and no head (the embedding again)."""
+    from singa_tpu.models.shortconv_moe import ShortConvMoELM
+
+    dev = device.get_default_device()
+    dev.SetRandSeed(seed)
+    m = ShortConvMoELM(
+        V, d_model=D, num_heads=4, kv_heads=2, head_dim=8,
+        layer_types=("conv", "full_attention", "conv"), num_dense_layers=1,
+        d_ff=64, d_ff_expert=16, n_experts=8, experts_per_token=2,
+        held=(0, 8), max_len=MAXLEN, init_std=0.3)
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32),
+                                 device=dev)],
+              is_train=False, use_graph=False)
+    m.eval()
+    return m
+
+
 @pytest.mark.parametrize("norm,quant", [("layer", "off"),
                                         ("layer", "int8"),
                                         ("rms", "off"),
-                                        ("hybrid", "off")])
+                                        ("hybrid", "off"),
+                                        ("shortconv", "off")])
 def test_warmed_engine_counts_no_host_leaf(norm, quant):
-    m = _build_hybrid() if norm == "hybrid" else _build(norm)
+    m = ({"hybrid": _build_hybrid, "shortconv": _build_shortconv}[norm]()
+         if norm in ("hybrid", "shortconv") else _build(norm))
     prompt = np.array([[3, 1, 4]], np.int32)
     device.set_inference_quant(quant)
     dst = stats.decode_stats()
@@ -195,7 +217,7 @@ def test_warmed_engine_counts_no_host_leaf(norm, quant):
         eng.stop()
         device.set_inference_quant("off")
     assert stats.cache_stats()["decode"]["host_leaves_per_call"] == 0
-    if norm == "hybrid":
+    if norm in ("hybrid", "shortconv"):
         import jax
 
         assert all(isinstance(leaf, jax.Array) for leaf in

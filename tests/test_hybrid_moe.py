@@ -250,14 +250,14 @@ def test_d_growth_leaves_rings_alone_and_streams_unchanged(model):
 
 
 # -- (e) the shares add up ------------------------------------------------------
-def _uncut_layer(x, ffn, K):
+def _uncut_layer(x, ffn, K, eps):
     """One routed layer over ALL experts in numpy float64."""
     x = x.astype(np.float64)
     sig = 1 / (1 + np.exp(-(x @ ffn["W_r"].astype(np.float64))))
     idx = np.argsort(-(sig + ffn["b"]), -1, kind="stable")[:, :K]
     out = np.zeros_like(x)
     for n in range(x.shape[0]):
-        share = sig[n, idx[n]] / sig[n, idx[n]].sum()
+        share = sig[n, idx[n]] / (sig[n, idx[n]].sum() + eps)
         for e, w_ in zip(idx[n], share):
             g = x[n] @ ffn["W_g"][e].astype(np.float64)
             u = x[n] @ ffn["W_u"][e].astype(np.float64)
@@ -266,32 +266,62 @@ def _uncut_layer(x, ffn, K):
     return out
 
 
+def _shortconv(n_experts):
+    """The other model that calls the one routed layer
+    (`models/routed_experts.py`), at this file's width."""
+    from singa_tpu.models.shortconv_moe import ShortConvMoELM
+
+    return ShortConvMoELM(V, d_model=D, d_ff_expert=32, n_experts=n_experts,
+                          experts_per_token=4, held=(0, n_experts),
+                          layer_types=("conv", "full_attention"),
+                          num_dense_layers=1)
+
+
+# (the model, its experts, the experts a share holds, the epsilon of
+# its normalising sum): mimo-v2.5's eighths of 32 through
+# `HybridWindowMoELM`, lfm2-24b-a2b's published 64 through
+# `ShortConvMoELM`, whose served `held` is all of them
+NUMBERS = {"hybrid_8_shares_of_4": (build, 32, 4, 0.0),
+           "shortconv_8_shares_of_8": (lambda: _shortconv(64), 64, 8, 1e-6)}
+
+
 @pytest.mark.parametrize("dense_rows", [256, 0], ids=["dense", "sorted"])
-def test_e_the_eight_shares_sum_to_the_uncut_layer(dense_rows):
-    """held = each eighth of 32 experts: the eight partial results add
+@pytest.mark.parametrize("numbers", list(NUMBERS))
+def test_e_the_eight_shares_sum_to_the_uncut_layer(numbers, dense_rows):
+    """held = each eighth of the experts: the eight partial results add
     up to the whole layer (nothing is computed twice: no shared
-    expert)."""
+    expert), whichever model's numbers go through the one shared
+    routed layer; eight shares of 8 sum to `held = (0, 64)`, which is
+    what the served lfm2 configuration holds."""
+    make, E, share, eps = NUMBERS[numbers]
     rng = np.random.default_rng(1)
-    m = build()
+    m = make()
     m.dense_rows = dense_rows
     f = 32
-    ffn = {"W_r": rng.normal(0, 0.3, (D, 32)).astype(np.float32),
-           "b": rng.normal(0, 0.1, 32).astype(np.float32),
-           "W_g": rng.normal(0, 0.3, (32, D, f)).astype(np.float32),
-           "W_u": rng.normal(0, 0.3, (32, D, f)).astype(np.float32),
-           "W_d": rng.normal(0, 0.3, (32, f, D)).astype(np.float32)}
+    ffn = {"W_r": rng.normal(0, 0.3, (D, E)).astype(np.float32),
+           "b": rng.normal(0, 0.1, E).astype(np.float32),
+           "W_g": rng.normal(0, 0.3, (E, D, f)).astype(np.float32),
+           "W_u": rng.normal(0, 0.3, (E, D, f)).astype(np.float32),
+           "W_d": rng.normal(0, 0.3, (E, f, D)).astype(np.float32)}
     x = rng.normal(0, 1, (37, D)).astype(np.float32)
+    whole = _uncut_layer(x, ffn, 4, eps)
     total, counted = 0, 0
-    for first in range(0, 32, 4):
-        m.held = (first, 4)
+    for first in range(0, E, share):
+        m.held = (first, share)
         part = {"W_r": put(ffn["W_r"]), "b": put(ffn["b"]),
-                **{k: put(ffn[k][first:first + 4])
+                **{k: put(ffn[k][first:first + share])
                    for k in ("W_g", "W_u", "W_d")}}
         y, counts = m._experts(part, put(x), "highest")
         total = total + np.asarray(y, np.float64)
         counted += int(np.asarray(counts).sum())
     assert counted == 37 * 4                    # every assignment, once
-    np.testing.assert_allclose(total, _uncut_layer(x, ffn, 4), **TOL)
+    np.testing.assert_allclose(total, whole, **TOL)
+    if share * 8 == E == 64:
+        m.held = (0, E)
+        y, counts = m._experts({k: put(v) for k, v in ffn.items()}, put(x),
+                               "highest")
+        np.testing.assert_allclose(np.asarray(y), whole, **TOL)
+        assert int(np.asarray(counts).sum()) == 37 * 4
 
 
 # -- (f) adversarial routing: nothing is dropped -------------------------------

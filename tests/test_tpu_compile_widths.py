@@ -58,10 +58,9 @@ def _no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def served(one_chip):
-    """The configuration's model (nothing drawn), its parameter tree
-    and its slab as shapes on the described chip."""
+def _served(config_path, slots, rung, one_chip):
+    """A configuration's model (nothing drawn), its parameter tree and
+    its slab as shapes on the described chip."""
     import jax
 
     from perfbench.harness import cell
@@ -69,7 +68,7 @@ def served(one_chip):
 
     from singa_tpu.ops import pallas_kernels
 
-    with open(CONFIG) as f:
+    with open(config_path) as f:
         config = json.load(f)
     model = cell.build(config["builder"])
     tensor.set_matmul_precision(config["serve"]["matmul_precision"])
@@ -85,11 +84,16 @@ def served(one_chip):
              for name, shape, dtype, _, _ in model._param_table()}
     params = model._tree(table.__getitem__)
     slab = jax.eval_shape(
-        lambda: model.new_slab(params, SLOTS, RUNG, None))
+        lambda: model.new_slab(params, slots, rung, None))
     slab = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), slab)
     yield model, params, slab, sds
     monkey.undo()
     tensor.set_matmul_precision("highest")
+
+
+@pytest.fixture(scope="module")
+def served(one_chip):
+    yield from _served(CONFIG, SLOTS, RUNG, one_chip)
 
 
 def _fits(compiled, what):
@@ -170,6 +174,118 @@ def test_expert_product_compiles_at_real_widths(served, rows):
                    model.d_ff_expert)
         # the buffer's rows * K assignments, once: not times 16 experts
         assert flops < 1.5 * (2 * rows * K * 3 * d * f)
+
+
+# -- LFM2-24B-A2B's served programs at their real widths (ISSUE 32) ------------
+LFM2 = os.path.join(os.path.dirname(CONFIG), "lfm2-24b-a2b.json")
+LFM2_RUNG, LFM2_BUCKET = 2048, 1024
+
+
+@pytest.fixture(scope="module")
+def lfm2(one_chip):
+    """`lfm2-24b-a2b-serve-decode128`'s geometry: 128 slots on the
+    2,048 rung, prompts up to the 1,024 bucket."""
+    yield from _served(LFM2, SLOTS, LFM2_RUNG, one_chip)
+
+
+def _lfm2_moves(model, slab, text):
+    """Opcodes of what moves a whole attention layer of the slab, or
+    rebuilds a whole convolution state (a `copy` or `transpose` with a
+    state's shape, a `dynamic-update-slice` whose update is a whole
+    state; a prefill's write of one row is the write itself). Reading
+    a 1 MB state is not moving it: XLA prefetches it into VMEM by a
+    `copy-start`, as it does the weights."""
+    from singa_tpu.models.shortconv_moe import CONV
+
+    layer = min(int(np.prod(c["k"].shape))
+                for kind, c in zip(model.layer_types, slab) if kind != CONV)
+    moves = _whole_layer_moves(text, layer)
+    state = ",".join(str(d) for d in slab[0]["u"].shape)
+    whole, size = int(np.prod(slab[0]["u"].shape)), {}
+    for name, dims, opcode, operands in _INSTRUCTION.findall(text):
+        size[name] = int(np.prod([int(d) for d in dims.split(",") if d]))
+        if dims != state:
+            continue
+        if opcode in ("copy", "transpose"):
+            moves.append(opcode)
+        elif opcode == "dynamic-update-slice":
+            update = operands.split(",")[1].strip().lstrip("%")
+            if size.get(update, whole) >= whole:
+                moves.append(opcode)
+    return moves
+
+
+def _lfm2_program(model, params, slab, sds, program, monkeypatch):
+    """The executable `ServingEngine` would get for `program`."""
+    lowered = []
+    monkeypatch.setattr(model, "_aot_step",
+                        lambda kind, jitted, args, extras: lowered.append(
+                            jitted.lower(*args).compile()) or (lambda *a: a))
+    monkeypatch.setattr(model, "_program_cache", dict)
+    vec = sds((SLOTS,), np.int32)
+    if program == "step":
+        model.decode_step(params, slab, vec, vec)
+    elif program.startswith("block"):
+        model.decode_scan(params, slab, vec, vec, int(program[5:]))
+    else:
+        rows = int(program[7:])
+        model.prefill_slab(params, slab, sds((rows, LFM2_BUCKET), np.int32),
+                           sds((rows,), np.int32), sds((rows,), np.int32))
+    (compiled,) = lowered
+    return compiled
+
+
+@pytest.mark.parametrize("program,temporaries", [
+    ("step", 0.1e9), ("block2", 0.1e9), ("block8", 0.3e9),
+    ("prefill1", 0.2e9), ("prefill2", 0.3e9)])
+def test_lfm2_programs_hold_the_slab_in_place(lfm2, program, temporaries,
+                                              monkeypatch):
+    """The fused step, run-ahead blocks of 2 and 8 and the cohort
+    prefill of one and of two 1,024-token prompts, over 10.36 GB of
+    weights and the 1.08 GB slab: each fits, the slab (two contexts and
+    seven states) is aliased whole, no operation moves a whole layer or
+    rebuilds a whole state, no held expert's matrix is copied into
+    another layout (a block is its steps in a row: as a loop it does
+    not fit, 17.7 GB), and the temporaries are what is stated: 0.02 GB
+    a step (with the values held [T, 64] the step carried both layers
+    re-laid, 0.55 GB), 0.04 and 0.12 GB a block, 0.06 and 0.08 GB a
+    prefill (compiled for a described v5e; no chip, no device metric)."""
+    model, params, slab, sds = lfm2
+    compiled = _lfm2_program(model, params, slab, sds, program, monkeypatch)
+    m = _fits(compiled, f"LFM2 {program}")
+    by_kind = model.slab_bytes(slab)
+    assert by_kind == {"context": 2 * 2 * 128 * 8 * 64 * 2048 * 2,
+                       "state": 7 * 128 * 2 * 2048 * 2}
+    assert m.alias_size_in_bytes >= sum(by_kind.values())
+    assert m.temp_size_in_bytes < temporaries
+    text = compiled.as_text()
+    assert not _lfm2_moves(model, slab, text)
+    E, d, f = model.held[1], model.d_model, model.d_ff_expert
+    assert not re.findall(rf"= bf16\[{E},(?:{d},{f}|{f},{d})\]\S* copy\(",
+                          text)
+
+
+@pytest.mark.parametrize("rows", [SLOTS, 2 * LFM2_BUCKET],
+                         ids=["decode_rows_dense", "prefill_rows_sorted"])
+def test_lfm2_expert_product_compiles_at_real_widths(lfm2, rows):
+    """The one routed layer with all 64 experts held. 128 rows: every
+    expert over every row (weight-bound). 2,048 rows: all 8,192 sorted
+    assignments straight through `ragged_dot`, once: no quarter-rows
+    branch, and not times 64 experts."""
+    import jax
+
+    model, params, _, sds = lfm2
+    ffn = params["blocks"][1]["ffn"]
+    x = sds((rows, model.d_model), ffn["W_g"].dtype)
+    compiled = jax.jit(
+        lambda f, x: model._experts(f, x, "default")).lower(ffn, x).compile()
+    _fits(compiled, f"LFM2 expert product over {rows} rows")
+    if rows > model.dense_rows:
+        K, d, f = (model.experts_per_token, model.d_model,
+                   model.d_ff_expert)
+        assert compiled.cost_analysis()["flops"] \
+            < 1.5 * (2 * rows * K * 3 * d * f)
+        assert " conditional(" not in compiled.as_text()
 
 
 # -- GPT-2's served programs update the slab where it lies (ISSUE 28) ----------

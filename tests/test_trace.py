@@ -533,3 +533,23 @@ def test_killed_run_leaves_parseable_log(tmp_path):
 
 def test_read_metrics_missing_file_is_empty():
     assert trace.read_metrics("/nonexistent/nowhere.jsonl") == []
+
+
+@pytest.mark.parametrize("kind", ["context", "state", "a_kind_no_file_names"])
+def test_metrics_slab_bytes_gauges_are_absolute_whatever_the_kind(tmp_path,
+                                                                  kind):
+    """`cache_bytes_<kind>` is a gauge for every kind of slab entry a
+    model states (`ServingEngine._note_slab_bytes` adds the kinds it is
+    given): matched by prefix, so a slab that does not grow between
+    two records reads its bytes in both, not a delta of 0."""
+    d = stats.decode_stats()
+    saved = dict(d.cache_bytes)
+    try:
+        with trace.MetricsLogger(str(tmp_path / "m.jsonl")) as ml:
+            d.cache_bytes = {**saved, kind: 4096}
+            r1 = ml.log_step(1, loss=0.0, step_s=0.1)
+            r2 = ml.log_step(2, loss=0.0, step_s=0.1)
+    finally:
+        d.cache_bytes = saved
+    assert r1["cache"]["decode"]["cache_bytes_" + kind] == 4096
+    assert r2["cache"]["decode"]["cache_bytes_" + kind] == 4096
