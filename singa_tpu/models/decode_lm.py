@@ -151,27 +151,39 @@ class DecodeLM(model.Model):
         return self._keep_counters(fn(*args))
 
     def decode_scan(self, params, cache, tok, pos, k):
-        """`k` GREEDY fused decode steps in ONE program (`lax.scan`
-        over `_slot_step` + in-graph argmax). The scan's cache carry
-        is the donated slab, updated in place step after step, so a
-        block costs its steps and one dispatch, one readback of
-        [k, B] tokens instead of k of [B, V] logits: that, and not a
-        saved copy, is what a block is for. In-graph `jnp.argmax` is
-        the exact greedy program
+        """`k` GREEDY fused decode steps in ONE program (`_slot_step`
+        + in-graph argmax, under `lax.scan` where `k` > 1). The scan's
+        cache carry is the donated slab, updated in place step after
+        step, so a block costs its steps and one dispatch, one readback
+        of [k, B] tokens instead of k of [B, V] logits: that, and not a
+        saved copy, is what a block is for. `k` == 1 is the token
+        program of a single step: `decode_step` and the argmax in a
+        straight line, no loop around them (a loop hoists what a step
+        fuses: GPT-2's block holds its weights converted to bfloat16),
+        so the device pays `decode_step` and an argmax and the host
+        reads [1, B] int32 where it read [B, V] logits. In-graph
+        `jnp.argmax` is the exact greedy program
         `generate()` scans with (and equals host `np.argmax` on
-        identical logits bits — both first-max-wins), so a block
-        decodes bit-identically to k single steps. Returns
+        identical logits bits — both first-max-wins, NaN the largest),
+        so a block decodes bit-identically to k single steps. Returns
         (toks [k, B] — one sampled token per step per row, new
         cache). The caller only dispatches a block when no session
         joins, leaves, expires, or samples within it."""
         import jax
         import jax.numpy as jnp
 
+        def greedy_step(p, c, t, po):
+            logits, c, *counters = self._slot_step(p, c, t, po)
+            return jnp.argmax(logits, -1).astype(jnp.int32), c, counters
+
         def scan_k(p, c, t, po):
+            if int(k) == 1:
+                t2, c, counters = greedy_step(p, c, t, po)
+                return (t2[None], c, *counters)
+
             def body(carry, _):
                 c, t, po = carry
-                logits, c, *counters = self._slot_step(p, c, t, po)
-                t2 = jnp.argmax(logits, -1).astype(jnp.int32)
+                t2, c, counters = greedy_step(p, c, t, po)
                 return (c, t2, po + 1), (t2, *counters)
 
             (c, _t, _po), (toks, *counters) = jax.lax.scan(

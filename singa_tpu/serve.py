@@ -1401,7 +1401,9 @@ class ServingEngine:
                     samplers=()) -> int:
         """Pre-compile (or AOT-load, when the export_cache store is
         armed) every decode-tier executable this engine can dispatch:
-        the fused `decode_step`, each pow2 `decode_scan` rung up to
+        the fused `decode_step` (a sampled session's logits), its
+        greedy token program `decode_scan(k=1)`, each pow2
+        `decode_scan` rung up to
         `decode_block`, and a cohort prefill per (batch rung up to
         `prefill_batch`, prompt bucket). Continuous batching admits
         sessions MID-STREAM, so the first-ever cohort size or
@@ -1460,11 +1462,11 @@ class ServingEngine:
         for t_k in samplers:
             t, k = float(t_k[0]), int(t_k[1])
             if t == 0.0:
-                continue  # greedy is an argmax on host, nothing to warm
+                continue  # greedy is `decode_scan`'s argmax, warmed below
             key, sub = jax.random.split(jax.random.PRNGKey(0))
             np.asarray(model.sample_fn(t, k)(lg[0:1], sub))
             warmed += 1
-        ks = set()
+        ks = {1}    # the token program of a single greedy step
         k = 2
         while k <= self.decode_block:
             ks.add(k)
@@ -2272,10 +2274,16 @@ class ServingEngine:
 
     def _decode_fused_step(self, live, geom, dst) -> None:
         """ONE warm dispatch advancing every live slot — a single
-        `decode_step`, or a `decode_scan` block of up to
+        step, or a `decode_scan` block of up to
         `decode_block` steps when `_decode_run_ahead` proves nothing
         joins/leaves inside it — with the forward tier's
-        retry/backoff discipline. Tokens are streamed only AFTER the
+        retry/backoff discipline. While every live session is greedy
+        the token is chosen in the program that computed the logits
+        (`decode_scan`, k = 1 for a single step) and [k, Sb] int32
+        comes back; only a step with a sampled session among the live
+        ones is `decode_step`, whose logits [Sb, V] cross to the host
+        for `sample_fn` and its host-side key splits (the greedy rows
+        beside it take the host's argmax). Tokens are streamed only AFTER the
         dispatch completes and only from its output — a retried
         dispatch recomputes from the UNCHANGED slab, so a delivered
         stream is never torn or duplicated."""
@@ -2290,9 +2298,11 @@ class ServingEngine:
             Sb = self._slab_dims()[0]
             tokv = np.zeros(Sb, np.int32)
             posv = np.zeros(Sb, np.int32)
+            sampled = False
             for slot, sess in live:
                 tokv[slot] = sess.tok
                 posv[slot] = sess.pos
+                sampled = sampled or sess.temperature != 0.0
             k = self._decode_run_ahead(live)
         inj = self.fault_injector
         t0 = time.perf_counter()
@@ -2307,7 +2317,7 @@ class ServingEngine:
                     raise RuntimeError(
                         f"injected decode step failure (step {idx})")
                 with trace_mod.span("decode.step.dispatch", steps=k):
-                    if k == 1:
+                    if sampled:     # k == 1: its keys split on the host
                         out, new_slab = model.decode_step(
                             params, self._slab, put(tokv), put(posv))
                     else:
@@ -2317,9 +2327,10 @@ class ServingEngine:
                 with trace_mod.span("decode.step.readback", steps=k):
                     out = np.asarray(out)  # completes the dispatch
                     counted = model.take_step_counters()
-                # logits [Sb, V] of a single step, tokens [k, Sb] of
-                # a run-ahead block
-                lg, toks = (out, None) if k == 1 else (None, out)
+                # tokens [k, Sb], chosen where the logits were
+                # computed; the logits [Sb, V] of a single step only
+                # when a live session samples
+                lg, toks = (out, None) if sampled else (None, out)
                 break
             except BaseException as e:  # noqa: BLE001 — retry below
                 if attempt >= self.max_retries or self._slab_lost():
@@ -2352,6 +2363,8 @@ class ServingEngine:
             rate if not self._decode_tokens_ema
             else 0.8 * self._decode_tokens_ema + 0.2 * rate)
         dst.decode_steps += k
+        if not sampled:
+            dst.decode_steps_tokens += k
         for name, n in counted.items():
             setattr(dst, name, getattr(dst, name) + n)
         trace_mod.record_span("decode_step", t0, t0 + block_s,

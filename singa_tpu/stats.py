@@ -693,6 +693,9 @@ class _DecodeStats:
         self.retires = 0        # slots freed back to the pool
         self.tokens_streamed = 0
         self.decode_steps = 0   # fused decode_step dispatches
+        # those of them whose result came to the host as tokens [k, B]
+        # int32 (a greedy single step or a block's steps), not logits
+        self.decode_steps_tokens = 0
         self.prefills = 0       # prefill dispatches
         # what a model's fused step counts itself (`DecodeLM.
         # step_counter_names`), summed over decode steps and expert
@@ -729,6 +732,7 @@ class _DecodeStats:
             "retires": self.retires,
             "tokens_streamed": self.tokens_streamed,
             "decode_steps": self.decode_steps,
+            "decode_steps_tokens": self.decode_steps_tokens,
             "prefills": self.prefills,
             "moe_assignments_local": self.moe_assignments_local,
             "moe_experts_touched": self.moe_experts_touched,

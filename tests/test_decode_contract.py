@@ -2,7 +2,8 @@
 serves (ISSUE 29).
 
 `serve.py` drives a model through `DecodeLM`'s three programs
-(`decode_step`, `decode_scan`, `prefill_slab`) and the slab's geometry
+(`decode_step`, `decode_scan` and its k = 1 case, the token program of
+a single greedy step, `prefill_slab`) and the slab's geometry
 (`new_slab / grow_slab / slab_dims / slab_bytes`, `export_slab_rows /
 import_slab_rows`) and never looks inside. What it relies on is held
 here for each form alike, at toy widths on the CPU:
@@ -25,7 +26,7 @@ model's own reason. The next decode-tier model adds one row to FORMS.
 import numpy as np
 import pytest
 
-from singa_tpu import device, stats, tensor
+from singa_tpu import device, serve, stats, tensor
 from singa_tpu.models.hybrid_moe import HybridWindowMoELM
 from singa_tpu.models.shortconv_moe import ShortConvMoELM
 from singa_tpu.models.transformer import TransformerLM
@@ -443,3 +444,141 @@ def test_a_row_decodes_as_if_it_were_alone(form):
             np.testing.assert_allclose(lg[1], together[s][r], rtol=0,
                                        atol=1e-5)
             assert lg[1].argmax() == together[s][r].argmax()
+
+
+# -- 9 ---------------------------------------------------------------------
+def test_the_token_program_is_the_step_and_the_hosts_argmax(form,
+                                                            monkeypatch):
+    """`decode_scan(k=1)` is what the engine dispatches for a single
+    step while nobody samples: [1, B] int32, the token the host's
+    `np.argmax` picks from `decode_step`'s logits (both first-max-wins
+    on the same float bits), and the slab `decode_step` leaves, over 6
+    steps (past the hybrid model's ring of 4). No loop is traced
+    around one step. The hybrid forms' float leaves get the tolerance
+    `test_a_block_is_its_steps` gives them."""
+    import jax
+
+    tok, pos, slab = started(form)
+    want = []
+    for _ in range(6):
+        lg, slab = step(form, slab, tok, pos)
+        tok, pos = lg.argmax(-1).astype(np.int32), pos + 1
+        want.append(tok)
+    by_logits = host(slab)
+    tok, pos, slab = started(form)
+    traced, program = [], form.m._slab_program
+
+    def slab_program(kind, key_, fn, args, extras):
+        traced.append(str(jax.make_jaxpr(fn)(*args)))
+        return program(kind, key_, fn, args, extras)
+
+    monkeypatch.setattr(form.m, "_slab_program", slab_program)
+    for w in want:
+        toks, slab = form.m.decode_scan(form.params, slab, put(tok),
+                                        put(pos), 1)
+        assert toks.shape == (1, 3) and toks.dtype == np.int32
+        assert np.array_equal(np.asarray(toks)[0], w)
+        tok, pos = w, pos + 1
+    for a, b in zip(host(slab), by_logits):
+        assert a.dtype == b.dtype
+        if form.hybrid and a.dtype != np.int8:
+            np.testing.assert_allclose(a, b, rtol=0, atol=2e-6)
+        else:
+            assert np.array_equal(a, b)
+    assert len(traced) == 6
+    assert not any(" scan[" in j or " while[" in j for j in traced)
+
+
+# -- 10 --------------------------------------------------------------------
+class _Paths:
+    """Which program each fused step of an engine took, beside the
+    temperatures of the sessions that were live in it."""
+
+    def __init__(self, eng, monkeypatch):
+        self.steps = []
+        m, fused = eng.model, eng._decode_fused_step
+        step_, scan_ = m.decode_step, m.decode_scan
+
+        def decode_step(*a):
+            self.steps[-1][1] = "logits"
+            return step_(*a)
+
+        def decode_scan(params, slab, tok, pos, k):
+            self.steps[-1][1] = f"tokens{k}"
+            return scan_(params, slab, tok, pos, k)
+
+        def fused_step(live, geom, dst):
+            self.steps.append([[s.temperature for _, s in live], None])
+            return fused(live, geom, dst)
+
+        monkeypatch.setattr(m, "decode_step", decode_step)
+        monkeypatch.setattr(m, "decode_scan", decode_scan)
+        monkeypatch.setattr(eng, "_decode_fused_step", fused_step)
+
+
+def _stream(eng, requests):
+    """[(prompt, n, temperature, seed)] submitted in one go -> arrays."""
+    replies = [eng.submit_decode(p, n, temperature=t, top_k=8 if t else 0,
+                                 seed=seed) for p, n, t, seed in requests]
+    return [np.asarray(r.result(timeout=300))[0] for r in replies]
+
+
+@pytest.mark.parametrize("block", [1, 8])
+@pytest.mark.parametrize(
+    "form", ["lm-layernorm-tied", "hybrid-dense", "shortconv"], indirect=True)
+def test_greedy_streams_are_the_same_by_tokens_and_by_logits(
+        form, block, monkeypatch):
+    """Through `ServingEngine`, `decode_block` 1 and the default.
+    All-greedy traffic never brings logits to the host: every fused
+    step is `decode_scan` (k = 1 for a single step), and
+    `decode_steps_tokens` counts every one of `decode_steps`. With a
+    sampled session live beside them every step is `decode_step`, its
+    logits on the host, the sampled row through `sample_fn` on its own
+    key splits and the greedy rows through the host's argmax; the
+    counter stands still for those steps. The greedy streams are the
+    same streams both ways (and `generate()`'s, where the model has
+    one); the sampled stream is the one it streams alone, which is
+    today's path whole (and `generate()`'s with that seed)."""
+    m = form.m
+    greedy = [(p, 5, 0.0, 0) for p in PROMPTS]
+    pilot = (ids_of(2, 9), 14, 0.8, 5)       # outlives the greedy ones
+    eng = serve.ServingEngine(m, max_sessions=4, max_new_tokens=16,
+                              prefill_batch=4, decode_block=block).start()
+    try:
+        eng.warm_decode(prompt_lens=(2, 7), max_new_tokens=16,
+                        samplers=[(0.8, 8)])
+        paths = _Paths(eng, monkeypatch)
+        before = stats.decode_stats().snapshot()
+        by_tokens = _stream(eng, greedy)
+        mid = stats.decode_stats().snapshot()
+        assert paths.steps and not any(
+            any(temps) for temps, _ in paths.steps)
+        assert {path for _, path in paths.steps} <= (
+            {"tokens1"} if block == 1
+            else {"tokens1", "tokens2", "tokens4", "tokens8"})
+        assert (mid["decode_steps_tokens"] - before["decode_steps_tokens"]
+                == mid["decode_steps"] - before["decode_steps"] > 0)
+        alone = _stream(eng, [pilot])[0]
+        del paths.steps[:]
+        mid = stats.decode_stats().snapshot()
+        beside, *by_logits = _stream(eng, [pilot] + greedy)
+        after = stats.decode_stats().snapshot()
+    finally:
+        eng.stop()
+    for temps, path in paths.steps:
+        assert (path == "logits") == any(temps), (temps, path)
+    mixed = [temps for temps, _ in paths.steps
+             if any(temps) and not all(temps)]
+    assert len(mixed) >= 4          # greedy rows beside the sampled one
+    assert (after["decode_steps_tokens"] - mid["decode_steps_tokens"]
+            == sum(int(path[6:]) for _, path in paths.steps
+                   if path != "logits")
+            < after["decode_steps"] - mid["decode_steps"])
+    for a, b in zip(by_tokens, by_logits):
+        assert np.array_equal(a, b)
+    assert np.array_equal(beside, alone)
+    if hasattr(m, "generate"):
+        for (p, n, _, _), got in zip(greedy, by_tokens):
+            assert np.array_equal(got, m.generate(p[None], n)[0])
+        assert np.array_equal(alone, m.generate(
+            pilot[0][None], pilot[1], temperature=0.8, top_k=8, seed=5)[0])

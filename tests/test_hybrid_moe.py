@@ -582,10 +582,14 @@ def test_i_a_program_that_fails_after_it_took_the_slab(either, program,
     """Every program donates the slab. One that fails after its
     dispatch leaves nothing to retry from: the live sessions fail
     loudly (a failed prefill's cohort with them), the slab is rebuilt
-    at its geometry, and queued work goes on as if nothing had been."""
+    at its geometry, and queued work goes on as if nothing had been.
+    (`decode_step` runs only while a live session samples, so its case
+    samples; greedy sessions' single steps are `decode_scan`'s.)"""
     import jax
 
     m = either
+    how = (dict(temperature=0.8, top_k=8, seed=3)
+           if program == "decode_step" else {})
     real = getattr(m, program)
     fail = [True]
 
@@ -608,9 +612,9 @@ def test_i_a_program_that_fails_after_it_took_the_slab(either, program,
         stats.reset_cache_stats()
         geom = eng._slab_dims()
         monkeypatch.setattr(m, program, consumed_then_failed)
-        r1 = eng.submit_decode(*first)
+        r1 = eng.submit_decode(*first, **how)
         next(r1.tokens(timeout=60))              # live and streaming
-        r2 = eng.submit_decode(*late)            # its prefill may be it
+        r2 = eng.submit_decode(*late, **how)     # its prefill may be it
         for r in (r1, r2):
             try:
                 r.result(timeout=60)

@@ -54,13 +54,18 @@ def lm():
     """One tiny eval-compiled TransformerLM for the whole module —
     decode executables cache on the model, so sharing it keeps the
     per-test compile cost to the first user of each ladder rung."""
-    dev = device.get_default_device()
-    dev.SetRandSeed(0)
+    device.get_default_device().SetRandSeed(0)
     tensor.set_matmul_precision("default")
+    return _fresh_lm()
+
+
+def _fresh_lm():
+    """A tiny eval-compiled model of its own: nothing in its program
+    cache."""
     m = TransformerLM(V, d_model=D, num_heads=H, num_layers=L,
                       max_len=MAXLEN)
     m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32),
-                                 device=dev)],
+                                 device=device.get_default_device())],
               is_train=False, use_graph=False)
     m.eval()
     return m
@@ -344,6 +349,95 @@ def test_warm_decode_compiles_the_samplers_traffic_will_use(lm):
     assert len(lm._gen_cache) == held
     assert stats.cache_stats()["decode"]["retraces"] == traced
     assert np.array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("block", [1, 4])
+def test_a_greedy_step_after_warm_decode_compiles_nothing(block):
+    """`warm_decode` warms the token program of a single greedy step
+    (`decode_scan`, k = 1) beside `decode_step`: greedy sessions
+    admitted afterwards, whose sessions leave one by one so that single
+    steps run, trace no decode program and add none to the model's
+    cache, with `decode_block` 1 and with run-ahead blocks."""
+    m = _fresh_lm()
+    prompts = _prompts(3)
+    want = [m.generate(p, n) for p, n in zip(prompts, (3, 4, 5))]
+    eng = serve.ServingEngine(m, max_sessions=4, max_new_tokens=NEW,
+                              prefill_batch=4, decode_block=block).start()
+    try:
+        cold = stats.cache_stats()["decode"]["retraces"]
+        warmed = eng.warm_decode(prompt_lens=(2, 3, 5), max_new_tokens=NEW)
+        # the step, its token program, the blocks' rungs, 3 cohort
+        # sizes x 3 prompt buckets
+        assert warmed == 2 + {1: 0, 4: 2}[block] + 9
+        held = len(m._gen_cache)
+        traced = stats.cache_stats()["decode"]["retraces"]
+        assert traced - cold == warmed
+        replies = [eng.submit_decode(p, n)
+                   for p, n in zip(prompts, (3, 4, 5))]
+        got = [r.result(timeout=60) for r in replies]
+    finally:
+        eng.stop()
+    assert len(m._gen_cache) == held
+    assert stats.cache_stats()["decode"]["retraces"] == traced
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), w)
+
+
+def test_decode_steps_tokens_counts_the_steps_that_returned_tokens(lm):
+    """`cache_stats()["decode"]["decode_steps_tokens"]`: every step of
+    all-greedy traffic (single steps and blocks' steps alike), none of
+    the steps a sampled session was live in."""
+    prompts = _prompts(4)
+    eng = serve.ServingEngine(lm, max_sessions=4, max_new_tokens=NEW,
+                              prefill_batch=4, decode_block=4).start()
+    try:
+        _, greedy = _decode_delta(lambda: [
+            r.result(timeout=60)
+            for r in [eng.submit_decode(p, NEW) for p in prompts]])
+        _, sampled = _decode_delta(lambda: eng.submit_decode(
+            prompts[0], NEW, temperature=0.8, top_k=8,
+            seed=1).result(timeout=60))
+    finally:
+        eng.stop()
+    assert greedy["decode_steps_tokens"] == greedy["decode_steps"] > 0
+    assert sampled["decode_steps"] == NEW - 1
+    assert sampled["decode_steps_tokens"] == 0
+    assert "decode_steps_tokens" in stats.cache_stats()["decode"]
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_ties_and_a_nan_row_pick_what_np_argmax_picks(lm, k, monkeypatch):
+    """The token program chooses with `jnp.argmax` what the host chose
+    with `np.argmax` from the same logits: the FIRST of equal maxima,
+    and the first NaN of a row that holds one (NaN counts as the
+    largest to both). Planted logits behind `_slot_step`, through
+    `decode_step` (the logits as the host gets them) and through
+    `decode_scan` (the tokens), a single step and a block."""
+    import jax.numpy as jnp
+
+    rows = np.random.default_rng(3).normal(size=(4, V)).astype(np.float32)
+    rows[0, [5, 9, 40]] = rows[0].max() + 1.0       # three equal maxima
+    rows[1, :] = 0.25                               # every logit equal
+    rows[2, [7, 30]] = np.nan                       # NaN twice
+    rows[2, 3] = np.inf
+    rows[3, 11] = -np.inf
+    planted = jnp.asarray(rows)
+    monkeypatch.setattr(lm, "_slot_step",
+                        lambda p, c, t, po: (planted, c))
+    monkeypatch.setattr(lm, "_program_cache", dict)   # keep none of these
+    params = lm._decode_params()
+    vec = jnp.zeros(4, jnp.int32)
+
+    def slab():
+        return lm.new_slab(params, 4, MAXLEN, planted.devices().pop())
+
+    lg, _ = lm.decode_step(params, slab(), vec, vec)
+    assert np.array_equal(np.asarray(lg), rows, equal_nan=True)
+    want = np.argmax(np.asarray(lg), -1)
+    assert list(want[:3]) == [5, 0, 7]
+    toks, _ = lm.decode_scan(params, slab(), vec, vec, k)
+    assert toks.shape == (k, 4) and toks.dtype == jnp.int32
+    assert np.array_equal(np.asarray(toks), np.tile(want, (k, 1)))
 
 
 def test_ttft_tpot_spans_under_tracing(lm):

@@ -96,6 +96,18 @@ def served(one_chip):
     yield from _served(CONFIG, SLOTS, RUNG, one_chip)
 
 
+def _as_served(model, monkey):
+    """`decode_step`, `decode_scan` and `prefill_slab` build and donate
+    as for `ServingEngine`; the returned list keeps what they compiled
+    (shapes go in, so nothing runs and nothing is cached)."""
+    compiled = []
+    monkey.setattr(model, "_aot_step",
+                   lambda kind, jitted, args, extras: compiled.append(
+                       jitted.lower(*args).compile()) or (lambda *a: a))
+    monkey.setattr(model, "_program_cache", dict)
+    return compiled
+
+
 def _fits(compiled, what):
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -128,11 +140,7 @@ def test_run_ahead_block_reads_the_experts_as_stored(served, monkeypatch):
     (around a loop XLA does both: `HybridWindowMoELM.scan_unroll`)."""
     model, params, slab, sds = served
     vec = sds((SLOTS,), np.int32)
-    lowered = []
-    monkeypatch.setattr(model, "_aot_step",
-                        lambda kind, jitted, args, extras: lowered.append(
-                            jitted.lower(*args).compile()) or (lambda *a: a))
-    monkeypatch.setattr(model, "_program_cache", dict)
+    lowered = _as_served(model, monkeypatch)
     model.decode_scan(params, slab, vec, vec, 2)
     (compiled,) = lowered
     E, d, f = model.held[1], model.d_model, model.d_ff_expert
@@ -217,11 +225,7 @@ def _lfm2_moves(model, slab, text):
 
 def _lfm2_program(model, params, slab, sds, program, monkeypatch):
     """The executable `ServingEngine` would get for `program`."""
-    lowered = []
-    monkeypatch.setattr(model, "_aot_step",
-                        lambda kind, jitted, args, extras: lowered.append(
-                            jitted.lower(*args).compile()) or (lambda *a: a))
-    monkeypatch.setattr(model, "_program_cache", dict)
+    lowered = _as_served(model, monkeypatch)
     vec = sds((SLOTS,), np.int32)
     if program == "step":
         model.decode_step(params, slab, vec, vec)
@@ -313,14 +317,7 @@ def gpt2(one_chip):
     model.eval()
     monkey = pytest.MonkeyPatch()
     monkey.setattr(pallas_kernels, "_interpret", lambda: False)
-    # the programs as `ServingEngine` gets them: `decode_step`,
-    # `decode_scan` and `prefill_slab` build and donate, this keeps
-    # what they compiled
-    compiled = []
-    monkey.setattr(model, "_aot_step",
-                   lambda kind, jitted, args, extras: compiled.append(
-                       jitted.lower(*args).compile()) or (lambda *a: a))
-    monkey.setattr(model, "_program_cache", dict)
+    compiled = _as_served(model, monkey)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
@@ -403,6 +400,58 @@ def test_gpt2_programs_move_no_whole_layer_but_the_write(gpt2, slots, rung,
         heads = model.blocks._seq[0].attn.num_heads
         assert len(re.findall(r"%decode_attend\S* = ", text)) >= len(slab)
         assert not re.findall(rf"= \w+\[{slots},{heads},{rung}\]", text)
+
+
+# -- the token program of a single greedy step (ISSUE 33) ----------------------
+@pytest.mark.parametrize("cell_", ["mimo-v2.5-serve-mixedlen",
+                                   "lfm2-24b-a2b-serve-decode128",
+                                   "gpt2-serve-decode", "gpt2-serve-short"])
+def test_the_token_program_is_the_step_and_an_argmax(request, cell_,
+                                                     monkeypatch):
+    """`decode_scan(k=1)`, what `ServingEngine` dispatches for a single
+    step while every live session is greedy, beside `decode_step` at
+    the cell's geometry, both as the engine gets them: the result is
+    [1, slots] int32 where the step's is [slots, vocabulary] float32
+    (33.6 MB in the lfm2 cell), the slab is aliased whole by both, and
+    the device pays the step and an argmax: no more bytes moved than
+    the step moves (the logits are not written out), a hundredth more
+    operations at most, and temporaries that hold the logits the step
+    had among its results and little else: 0.024 GB for GPT-2, where a
+    block as a loop holds 0.3 GB of weights converted once before it
+    (compiled for a described v5e; no chip, no device metric)."""
+    import jax
+
+    if cell_.startswith("gpt2"):
+        model, params, sds, compiled = request.getfixturevalue("gpt2")
+        slots, rung = (32, 1024) if cell_ == "gpt2-serve-decode" else (64, 256)
+        slab = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype),
+            jax.eval_shape(lambda: model.new_slab(params, slots, rung, None)))
+        del compiled[:]
+    else:
+        model, params, slab, sds = request.getfixturevalue(
+            "lfm2" if cell_.startswith("lfm2") else "served")
+        slots, compiled = SLOTS, _as_served(model, monkeypatch)
+    vec = sds((slots,), np.int32)
+    model.decode_step(params, slab, vec, vec)
+    model.decode_scan(params, slab, vec, vec, 1)
+    step, token = compiled
+    logits, toks = step.out_info[0], token.out_info[0]
+    assert (toks.shape, toks.dtype) == ((1, slots), np.int32)
+    assert (logits.shape[0], logits.dtype) == (slots, np.float32)
+    ms = _fits(step, f"{cell_} step")
+    mt = _fits(token, f"{cell_} token program")
+    slab_bytes = sum(model.slab_bytes(slab).values())
+    assert mt.alias_size_in_bytes == ms.alias_size_in_bytes >= slab_bytes
+    logits_bytes = 4 * int(np.prod(logits.shape))
+    assert mt.output_size_in_bytes <= ms.output_size_in_bytes - logits_bytes \
+        + 4096
+    assert mt.temp_size_in_bytes < ms.temp_size_in_bytes + logits_bytes \
+        + 0.02e9
+    cs, ct = step.cost_analysis(), token.cost_analysis()
+    assert ct["bytes accessed"] <= cs["bytes accessed"]
+    assert cs["flops"] <= ct["flops"] < 1.01 * cs["flops"]
+    assert " while(" not in token.as_text()
 
 
 def test_the_one_chip_resnet_step_holds_what_the_memory_meter_misses(
