@@ -16,7 +16,18 @@ Open loop: a request is timed from when it was DUE, sent or not.
 Closed loop: from just before `submit_decode`. Limits are judged here,
 afterwards; no request carries a deadline (a deadline switches the
 engine's run-ahead off).
+
+A late answer is late, not failed. The engine sheds a session it has no
+slot for with `ServeOverloadError.retry_after_ms`, which its contract
+calls "come back in N ms" and not a terminal failure: the client here
+comes back, and the request's times still run from when it was due.
+After the close an open-loop run waits for every request of the window
+that has no answer yet, up to `LATE_S`. So a host that stands still for
+some seconds (a burst of the arrivals it held back meets a full slot
+pool) shows in the latencies and the rate, which is where it belongs,
+and `failed` counts what never got an answer or got an error.
 """
+import collections
 import time
 
 import numpy as np
@@ -26,18 +37,19 @@ from perfbench.harness import compare, numbers, profiler, traffic
 
 UNATTRIBUTED_GAP = "engine-thread"
 SWEEP_S = 0.0005
+LATE_S = 60.0   # past the close, how long an open-loop answer is waited for
 
 
 class Rec:
     """One request as the client saw it (times on perf_counter)."""
     __slots__ = ("req", "ids", "reply", "t_due", "t_sent", "t_first",
-                 "t_last", "seen", "t_end", "error", "late")
+                 "t_last", "seen", "t_end", "error", "late", "sheds")
 
     def __init__(self, req, ids, t_due):
         self.req, self.ids, self.t_due = req, ids, t_due
         self.reply = self.t_sent = self.t_first = self.t_last = None
         self.t_end = self.error = self.late = None
-        self.seen = 0
+        self.seen = self.sheds = 0
 
 
 class Load:
@@ -53,6 +65,10 @@ class Load:
         self.overload = serve.ServeOverloadError
         self.closed = self.w["loop"] == "closed"
         self.live, self.ended = [], []
+        # shed requests waiting out the engine's hint, oldest first, and
+        # when the hint says a slot may be free (one clock for them all:
+        # the pool that was full for one is full for the next)
+        self.shed, self.t_retry = collections.deque(), 0.0
         self.t_open = None          # perf_counter of the window's opening
         self.tokens_in_window = 0
         self.window = (float("inf"), float("inf"))
@@ -69,24 +85,40 @@ class Load:
         request is due now, and `t_ready` is when the engine delivered
         the client's previous reply (None for its first)."""
         rec = Rec(req, traffic.prompt_ids(req, self.vocab), t_due)
+        if self.closed:
+            rec.t_due = time.perf_counter()
+            rec.late = None if t_ready is None else rec.t_due - t_ready
+        if not self._send(rec):
+            self.shed.append(rec)
+        if not self.closed:
+            rec.late = rec.t_sent - rec.t_due
+
+    def _send(self, rec):
+        """One attempt. True: the engine has the request. False: it shed
+        it at admission, and its hint says when to come back."""
+        req = rec.req
         with self.run.annotate("submit_decode"):
-            if self.closed:
-                rec.t_due = time.perf_counter()
-                rec.late = None if t_ready is None else rec.t_due - t_ready
             try:
                 rec.reply = self.engine.submit_decode(
                     rec.ids, req.n_new, temperature=req.temperature,
                     top_k=req.top_k, seed=req.index)
-            except self.overload as e:       # shed at admission
-                rec.error = e
-            rec.t_sent = time.perf_counter()
-        if not self.closed:
-            rec.late = rec.t_sent - rec.t_due
-        if rec.error is None:
+            except self.overload as e:
+                rec.sheds += 1
+                self.t_retry = (time.perf_counter()
+                                + max(float(e.retry_after_ms), 1.0) / 1e3)
+            if rec.t_sent is None:
+                rec.t_sent = time.perf_counter()
+        if rec.reply is not None:
             self.live.append(rec)
-        else:
-            rec.t_end = rec.t_sent
-            self.ended.append(rec)
+        return rec.reply is not None
+
+    def _come_back(self, now):
+        """Re-send what was shed, oldest first, once the hinted wait is
+        over; stop at the first the engine sheds again."""
+        while self.shed and now >= self.t_retry:
+            if not self._send(self.shed[0]):
+                return
+            self.shed.popleft()
 
     def _client_next(self, client, t_ready=None):
         k = self.next_k[client]
@@ -132,6 +164,7 @@ class Load:
             if now >= until:
                 return
             nap = SWEEP_S
+            self._come_back(now)
             if not self.closed:
                 while (self.next_i < len(self.schedule) and self.t_open
                        + self.schedule[self.next_i].due_s <= now):
@@ -145,7 +178,22 @@ class Load:
             time.sleep(nap)
 
     def all_streaming(self):
-        return all(r.seen > 0 for r in self.live)
+        return not self.shed and all(r.seen > 0 for r in self.live)
+
+    def unsent(self, t0, t1):
+        """Open loop: the schedule's requests due in [t0, t1) that were
+        never sent (the host stood still up to now), as records."""
+        if self.closed:
+            return []
+        return [Rec(q, None, self.t_open + q.due_s)
+                for q in self.schedule[self.next_i:]
+                if t0 <= self.t_open + q.due_s < t1]
+
+    def unanswered(self, t0, t1):
+        """How many requests due in [t0, t1) have no answer yet: not
+        sent, shed and waiting, or in flight."""
+        return len(self.unsent(t0, t1)) + sum(
+            1 for r in list(self.shed) + self.live if t0 <= r.t_due < t1)
 
 
 def build(run):
@@ -238,8 +286,10 @@ def run(run):
     tail = float(w["trace_seconds"]) if run.trace else 0.0
     model, engine = build(run)
     try:
+        # arrivals go on through the wait for late answers and the
+        # traced tail: one uninterrupted stream, however long the wait
         load = Load(run, engine, model.vocab_size,
-                    seconds + grace + tail + 1.0)
+                    seconds + max(grace, LATE_S) + tail + 1.0)
         if run.trace:
             device.set_tracing(True, ring_capacity=4_000_000)
         if load.closed:
@@ -268,6 +318,9 @@ def run(run):
         if run.trace:
             run.spans = trace.records()
         load.drive(t_open + seconds + grace)   # open loop: stragglers
+        while (not load.closed and load.unanswered(t_open, t_open + seconds)
+               and time.perf_counter() < t_open + seconds + LATE_S):
+            load.drive(time.perf_counter() + 0.05)     # late, not failed
         t_judged = time.perf_counter()
         if run.trace:
             with profiler.DeviceTrace(run):
@@ -290,7 +343,8 @@ def summarize(run, load, t_open, t_judged):
     the requests that finished inside the window, in order."""
     seconds = run.seconds
     t_close = t_open + seconds
-    everything = load.ended + load.live
+    everything = (load.ended + load.live + list(load.shed)
+                  + load.unsent(t_open, t_close))
     if load.closed:
         asked = [r for r in everything if t_open <= r.t_due < t_close]
         judged = [r for r in load.ended if t_open <= r.t_end < t_close]
@@ -324,6 +378,7 @@ def summarize(run, load, t_open, t_judged):
         r.late for r in everything
         if r.late is not None and t_open <= r.t_due < t_close]
     errors = sorted({type(r.error).__name__ for r in failed if r.error})
+    came_back = [r for r in judged if r.sheds]
     run.notes["serve"] = (
         f"{load.tokens_in_window} tokens in {seconds:.1f} s; ttft p50 "
         f"{1e3 * numbers.median(ttft):.2f} ms p90 "
@@ -331,6 +386,9 @@ def summarize(run, load, t_open, t_judged):
         f"requests; tpot p50 {1e3 * numbers.median(tpot):.3f} ms p90 "
         f"{1e3 * numbers.percentile(tpot, 90):.3f} ms over {len(tpot)} "
         f"finished; attempted {run.attempted} failed {run.failed} {errors}; "
+        f"{len(came_back)} shed at admission and sent again "
+        f"({sum(r.sheds for r in came_back)} times), judged "
+        f"{t_judged - t_close:.2f} s after the close; "
         f"{waiting} without a first token at the close left out; in flight "
         f"at the close {len(load.live)}")
     return finished

@@ -1,9 +1,9 @@
 """BENCHMARK.json against the contract's limits, and every name in it
 resolves to a file; the command refuses to run off the TPU.
 
-Every check of the file is a function of (bench, root), so that
-`test_perfbench_rehearsal.py` holds a root grown by new files and
-appended entries to the same checks as this repository's."""
+Every check of the file is a function of (bench, root), and every test
+takes `root` from `conftest.py`: this repository's, and one grown by
+new files and appended entries, held to the same checks."""
 import functools
 import importlib.util
 import json
@@ -20,9 +20,6 @@ from perfbench.harness import cell, moe_trace
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-BENCH = cell.benchmark()
-CELLS = [w["name"] for w in BENCH["workloads"]]
-METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
 
 
 def _line_ok(s):
@@ -44,6 +41,13 @@ def module_of(root, package, name):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def config_of(root, name):
+    """perfbench/configs/<name>.json of `root`."""
+    with open(os.path.join(root, "perfbench", "configs",
+                           name + ".json")) as f:
+        return json.load(f)
 
 
 def check_top_level(bench, root):
@@ -158,64 +162,67 @@ def check_all(bench, root):
     check_lists_grow_at_their_end(bench, root)
 
 
-def test_top_level_keys_and_sizes():
-    check_top_level(BENCH, cell.ROOT)
+def test_top_level_keys_and_sizes(root):
+    check_top_level(cell.benchmark(root), root)
 
 
-@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
-def test_config_entry(entry):
-    check_config_entry(BENCH, cell.ROOT, entry)
+@pytest.mark.entries("configs")
+def test_config_entry(root, entry):
+    check_config_entry(cell.benchmark(root), root, entry)
 
 
-@pytest.mark.parametrize("entry", BENCH["workloads"], ids=lambda w: w["name"])
-def test_cell_entry_resolves(entry):
-    check_cell_entry(BENCH, cell.ROOT, entry)
+@pytest.mark.entries("workloads")
+def test_cell_entry_resolves(root, entry):
+    check_cell_entry(cell.benchmark(root), root, entry)
 
 
-def test_cells_are_unique_and_few_take_four_chips():
-    check_cells(BENCH, cell.ROOT)
+def test_cells_are_unique_and_few_take_four_chips(root):
+    check_cells(cell.benchmark(root), root)
 
 
-@pytest.mark.parametrize("m", METRICS, ids=lambda m: m["name"])
-def test_metric_entry(m):
-    check_metric_entry(BENCH, cell.ROOT, m)
+@pytest.mark.entries("end_to_end", "per_layer")
+def test_metric_entry(root, entry):
+    check_metric_entry(cell.benchmark(root), root, entry)
 
 
-def test_metric_names_are_unique_and_every_cell_is_covered():
-    check_coverage(BENCH, cell.ROOT)
+def test_metric_names_are_unique_and_every_cell_is_covered(root):
+    check_coverage(cell.benchmark(root), root)
 
 
-def test_unknown_names_fail_loudly():
+def test_unknown_names_fail_loudly(root):
     with pytest.raises(KeyError, match="no cell"):
-        cell.load_cell("no-such-cell")
+        cell.load_cell("no-such-cell", root)
     with pytest.raises(ModuleNotFoundError, match="layer_metrics/nope.py"):
         cell.module("layer_metrics", "nope")
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_command_refuses_any_platform_but_tpu(name):
+@pytest.mark.entries("workloads", roots=("ours",))
+def test_command_refuses_any_platform_but_tpu(root, entry):
     """Exit non-zero and no result line: there is no CPU mode."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
-        [sys.executable, "-m", "perfbench.run", "--workload", name,
+        [sys.executable, "-m", "perfbench.run", "--workload", entry["name"],
          "--seed", "0", "--seconds", "1", "--trace", "0"],
-        cwd=cell.ROOT, env=env, capture_output=True, text=True, timeout=120)
+        cwd=root, env=env, capture_output=True, text=True, timeout=120)
     assert p.returncode != 0
     assert "no CPU mode" in p.stderr
     assert not any(line.lstrip().startswith("{") for line in
                    p.stdout.splitlines())
 
 
-def test_command_refuses_a_directory_without_the_program(tmp_path):
+@pytest.mark.parametrize("root", ["ours"], indirect=True)
+def test_command_refuses_a_directory_without_the_program(root, tmp_path):
     """In a directory that holds only BENCHMARK.json and the files under
     `paths`: another exit code than 0, and no result."""
-    shutil.copy(os.path.join(cell.ROOT, "BENCHMARK.json"), tmp_path)
-    for path in BENCH["paths"]:
-        shutil.copytree(os.path.join(cell.ROOT, path), tmp_path / path,
+    bench = cell.benchmark(root)
+    shutil.copy(os.path.join(root, "BENCHMARK.json"), tmp_path)
+    for path in bench["paths"]:
+        shutil.copytree(os.path.join(root, path), tmp_path / path,
                         ignore=shutil.ignore_patterns("__pycache__"))
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     p = subprocess.run(
-        [sys.executable, "-m", "perfbench.run", "--workload", CELLS[0],
+        [sys.executable, "-m", "perfbench.run", "--workload",
+         bench["workloads"][0]["name"],
          "--seed", "0", "--seconds", "1", "--trace", "0"],
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
     assert p.returncode != 0

@@ -183,6 +183,27 @@ def test_the_share_of_the_rung_read_is_the_block_counters_quotient():
     assert set(reader.COUNTERS) == {"attn_blocks_read", "attn_blocks_rung"}
 
 
+def test_the_share_of_steps_that_came_back_as_tokens():
+    """`steps_tokens_only_pct` on a Run with the program's counters: 100
+    from equal counters, less where some steps brought their logits
+    back; nothing to read from a program that does not count them."""
+    reader = cell_mod.module("layer_metrics", "steps_tokens_only_pct")
+    cell, config, workload = cell_mod.load_cell("toy-serve-closed", TOY)
+    run = cell_mod.Run(cell=cell, config=config, workload=workload,
+                       seconds=1.0, trace=True)
+    assert reader.read(run) is None
+    run.counters["decode"] = {"decode_steps": 971, "tokens_streamed": 9000}
+    assert reader.read(run) is None         # the parent of PR 33
+    run.counters["decode"]["decode_steps_tokens"] = 971
+    assert reader.read(run) == 100.0
+    run.counters["decode"]["decode_steps_tokens"] = 600
+    assert reader.read(run) == pytest.approx(100 * 600 / 971)
+    run.counters["decode"].update(decode_steps=0, decode_steps_tokens=0)
+    assert reader.read(run) is None         # no step in the window
+    assert (reader.LAYER, reader.UNIT, reader.MOVES) == (
+        "serving control plane", "%", "out_tokens_per_s")
+
+
 def test_a_compile_in_the_window_makes_the_run_incorrect(monkeypatch):
     cell, config, workload = cell_mod.load_cell("toy-train-lm", TOY)
     run = cell_mod.Run(cell=cell, config=config, workload=workload,
@@ -201,3 +222,136 @@ def test_a_metric_the_driver_did_not_measure_is_an_error():
                        seconds=1.0, trace=False, end_to_end={"setup_s": 1.0})
     with pytest.raises(KeyError, match="train_items_per_s"):
         run_mod.result_line(run, jax.devices()[:1], "host-loop", TOY)
+
+
+class _FakeReply:
+    """What `Load` reads of a `ServeReply`: a token every `step_s` on
+    the wall clock from its admission, `n` of them."""
+
+    def __init__(self, n, step_s):
+        self.n, self.step_s, self.t0 = n, step_s, time.perf_counter()
+        self.t_reply = None
+
+    @property
+    def _stream(self):
+        return range(min(self.n, int((time.perf_counter() - self.t0)
+                                     / self.step_s)))
+
+    def done(self):
+        return len(self._stream) == self.n
+
+    def result(self, timeout=None):
+        return None
+
+
+class _FakeEngine:
+    """A slot pool that sheds as `ServingEngine.submit_decode` does:
+    no free slot, `ServeOverloadError` with the hinted wait."""
+
+    def __init__(self, slots, step_s=0.02, hint_ms=5.0):
+        self.slots, self.step_s, self.hint_ms = slots, step_s, hint_ms
+        self.live, self.attempts, self.shed = [], 0, 0
+
+    def submit_decode(self, ids, n_new, **_):
+        from singa_tpu import serve
+
+        self.attempts += 1
+        self.live = [r for r in self.live if not r.done()]
+        if len(self.live) >= self.slots:
+            self.shed += 1
+            raise serve.ServeOverloadError("decode slot pool exhausted",
+                                           retry_after_ms=self.hint_ms)
+        self.live.append(_FakeReply(n_new, self.step_s))
+        return self.live[-1]
+
+
+def test_a_shed_request_comes_back_after_the_hint_and_is_late_not_failed():
+    """A burst meets a full slot pool, as after a host that stood
+    still: the engine sheds with its `retry_after_ms`, the client comes
+    back when the hint is over, oldest first, and every request gets
+    its answer; its times run from when it was DUE, so the wait is in
+    the latency and `failed` stays 0."""
+    from perfbench.drivers import serve as serve_driver
+
+    cell, config, workload = cell_mod.load_cell("toy-serve-open", TOY)
+    run = cell_mod.Run(cell=cell, config=config, workload=workload,
+                       seconds=5.0, trace=False, seed=7)
+    engine = _FakeEngine(slots=4)
+    load = serve_driver.Load(run, engine, 97, 1.0)
+    n = len(load.schedule)          # the lead-in's 2 and one block's 10
+    assert n == 12
+    # the host stood still for the window's first second: all are due
+    load.t_open = t_open = time.perf_counter() - 1.0
+    load.window = (t_open, t_open + 5.0)
+    load.drive(time.perf_counter() + 0.003)
+    assert len(load.live) == 4 and len(load.shed) == 8
+    assert [r.req.index for r in load.shed] == list(range(4, 12))
+    t_judged = time.perf_counter() + 2.0
+    while load.unanswered(t_open - 1.0, t_open + 5.0):
+        assert time.perf_counter() < t_judged, "the shed never came back"
+        load.drive(time.perf_counter() + 0.05)
+    assert not load.shed and not load.live and len(load.ended) == n
+    assert all(r.error is None and r.seen == r.req.n_new
+               for r in load.ended)
+    came_back = [r for r in load.ended if r.sheds]
+    assert len(came_back) == 8 and engine.shed == sum(
+        r.sheds for r in came_back) >= 8
+    # a retry waits out the hint: no storm of attempts at a full pool
+    assert engine.attempts < n + 2.0 / (engine.hint_ms / 1e3)
+    # admitted oldest first, and timed from when each was due
+    order = sorted(came_back, key=lambda r: r.reply.t0)
+    assert [r.req.index for r in order] == list(range(4, 12))
+    assert all(r.t_first - r.t_due > 1.0 - r.req.due_s - 0.3
+               and r.late == r.t_sent - r.t_due for r in came_back)
+    serve_driver.summarize(run, load, t_open, time.perf_counter())
+    assert (run.attempted, run.failed) == (10, 0)
+    assert "8 shed at admission and sent again" in run.notes["serve"]
+
+
+def test_a_request_never_answered_is_failed():
+    """What `failed` still counts: a request the engine sheds to the
+    end has no answer when judging ends."""
+    from perfbench.drivers import serve as serve_driver
+
+    cell, config, workload = cell_mod.load_cell("toy-serve-open", TOY)
+    run = cell_mod.Run(cell=cell, config=config, workload=workload,
+                       seconds=1.0, trace=False, seed=7)
+    engine = _FakeEngine(slots=4, step_s=10.0)      # no slot ever frees
+    load = serve_driver.Load(run, engine, 97, 1.0)
+    load.t_open = t_open = time.perf_counter() - 1.0
+    load.window = (t_open, t_open + 1.0)
+    load.drive(time.perf_counter() + 0.1)
+    assert len(load.shed) == 8 and load.unanswered(t_open, t_open + 1.0)
+    serve_driver.summarize(run, load, t_open, time.perf_counter())
+    # 10 due in the window; the 4 admitted ones are the lead-in's 2 and
+    # the window's first 2, none finished; nothing to judge a rate from
+    assert (run.attempted, run.failed) == (10, 10)
+    assert any("nothing to judge" in w for w in run.wrong)
+
+
+def test_a_host_that_stands_still_past_the_close_fails_no_request(
+        monkeypatch, policies):
+    """The whole open-loop run at toy size with the sending thread
+    stopped from the middle of the window until after the close AND its
+    grace: the arrivals it held back go out in one burst to a pool of 4
+    slots, are shed, come back, and are waited for: late, not failed."""
+    from perfbench.drivers import serve as serve_driver
+
+    sweep, stood = serve_driver.Load._sweep, []
+
+    def stalled(self, now):
+        if not stood and now >= self.window[0] + 1.0:
+            stood.append(now)
+            time.sleep(3.3)                  # window 2.0 + grace 2.0 = 4.0
+        return sweep(self, now)
+
+    monkeypatch.setattr(serve_driver.Load, "_sweep", stalled)
+    run, line = _drive("toy-serve-open", False, monkeypatch, seconds=2.0)
+    assert stood and line["correct"] is True, run.wrong
+    assert line["attempted"] == 20 and line["failed"] == 0
+    note = run.notes["serve"]
+    shed = int(note.split(" shed at admission")[0].split()[-1])
+    assert shed > 0, note
+    # the stall is in the numbers it belongs in: the generator was late
+    assert max(run.samples["gen_late_s"]) > 2.0
+    assert max(run.samples["ttft_s"]) > 2.0

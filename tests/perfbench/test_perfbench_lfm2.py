@@ -6,7 +6,6 @@ reachable from the command); `conv_step_ms` reads a trace given as
 tuples whose instruction texts are a traced chip run's; the new cell's
 files say what ISSUE 32 asked of them; the control comes out not
 correct."""
-import json
 import os
 import time
 
@@ -20,13 +19,11 @@ from perfbench.layer_metrics import (attn_full_step_ms, conv_step_ms,
                                      expert_load_max_over_mean,
                                      moe_experts_roofline_pct, moe_step_ms)
 from perfbench.reference import lfm2_moe_control
+from test_perfbench_contract import config_of
 from test_perfbench_drivers import FakeDeviceTrace, _meter, policies  # noqa: F401
 
 TOY = os.path.join(os.path.dirname(__file__), "toy_lfm2")
 CELL = "lfm2-24b-a2b-serve-decode128"
-CONFIG = os.path.join(cell_mod.ROOT, "perfbench", "configs",
-                      "lfm2-24b-a2b.json")
-TOY_CONFIG = os.path.join(TOY, "perfbench", "configs", "toy_lfm2.json")
 # the catalog row `LFM2-24B-A2B` (the model-configs guide's
 # architectures.jsonl, read when ISSUE 32 was written): its `source_url`
 # and every key of its `config`, written out so that the test reads
@@ -164,12 +161,11 @@ DENSE = ("%fusion.156 = (f32[128], bf16[128,2048]) fusion(bf16[128,2048] "
          "%fusion.387), kind=kOutput")
 
 
-def _config(path=CONFIG):
-    with open(path) as f:
-        return json.load(f)
+def _config(root):
+    return config_of(root, "lfm2-24b-a2b")
 
 
-def test_conv_step_ms_is_read_from_a_trace_by_the_files_own_parts():
+def test_conv_step_ms_is_read_from_a_trace_by_the_files_own_parts(root):
     mods = [("jit_slot_step(123)", 0, 1000),
             ("jit_prefill_rows(5)", 1000, 3000),
             ("jit_slot_scan_4(77)", 3000, 8000)]
@@ -179,14 +175,15 @@ def test_conv_step_ms_is_read_from_a_trace_by_the_files_own_parts():
            (HEAD, 920, 990),
            (CONV_IN, 1500, 2500),              # a prefill's: not a step's
            (CONV_IN, 3000, 3100), (TAPS, 3100, 3140), (GATE, 3200, 4000)]
-    rx = moe_trace.part_patterns(_config()["step_parts"], 128)
+    config = _config(root)
+    rx = moe_trace.part_patterns(config["step_parts"], 128)
     assert list(rx) == ["moe_experts", "attn_full", "short_conv"]
     red = moe_trace.reduce(ops, mods, rx, 0, 10000)
     assert red["steps"] == 5
     assert red["seconds"]["short_conv"] == pytest.approx(190e-9)
     assert red["seconds"]["attn_full"] == pytest.approx(150e-9)
     assert red["seconds"]["moe_experts"] == pytest.approx(1500e-9)
-    run = cell_mod.Run(cell={"name": CELL}, config=_config(), workload={},
+    run = cell_mod.Run(cell={"name": CELL}, config=config, workload={},
                        seconds=30.0, trace=True, peaks=V5E)
     run.moe_trace = red
     assert conv_step_ms.read(run) == pytest.approx(1e3 * 190e-9 / 5)
@@ -197,30 +194,31 @@ def test_conv_step_ms_is_read_from_a_trace_by_the_files_own_parts():
     # a configuration without the part (the hybrid model's), or a run
     # without a trace, leaves the reader with nothing: the metric is
     # left out, as on the parent of the PR that added it
-    mimo = _config(os.path.join(os.path.dirname(CONFIG), "mimo-v2.5.json"))
+    mimo = config_of(root, "mimo-v2.5")
     other = cell_mod.Run(cell={"name": "x"}, config=mimo, workload={},
                          seconds=30.0, trace=True, peaks=V5E)
     other.moe_trace = moe_trace.reduce(
         ops, mods, moe_trace.part_patterns(mimo["step_parts"], 128), 0, 10000)
     assert conv_step_ms.read(other) is None
-    bare = cell_mod.Run(cell={"name": CELL}, config=_config(), workload={},
+    bare = cell_mod.Run(cell={"name": CELL}, config=config, workload={},
                         seconds=30.0, trace=False, peaks=V5E)
     assert conv_step_ms.read(bare) is None
 
 
-def test_the_roofline_share_with_every_expert_held():
+def test_the_roofline_share_with_every_expert_held(root):
     """128 rows x 4 experts = 512 assignments a layer over 64 experts,
     8 routed layers: the need is the touched experts' weights once
     (memory-bound), so a kernel that streams all 512 expert-layers at
     80 % of the peak while 410 are touched reads 64 %."""
     steps, touched = 100, 410
-    spec = _config()["opcount"]["kwargs"]
+    config = _config(root)
+    spec = config["opcount"]["kwargs"]
     one = 3 * spec["d_model"] * spec["d_ff_expert"] * spec["itemsize"]
     assert 512 * one == 9663676416                  # the 9.66 GB a step
     red = {"steps": steps, "step_seconds": 2.0, "seconds": {
         "moe_experts": steps * 512 * one / (0.8 * V5E["hbm_bytes_per_s"]),
         "attn_full": 0.2, "short_conv": 0.04}}
-    run = cell_mod.Run(cell={"name": CELL}, config=_config(), workload={},
+    run = cell_mod.Run(cell={"name": CELL}, config=config, workload={},
                        seconds=30.0, trace=True, peaks=V5E)
     run.moe_trace = red
     run.counters["decode"] = {"decode_steps": 1000,
@@ -232,14 +230,13 @@ def test_the_roofline_share_with_every_expert_held():
     assert "memory-bound" in run.notes["moe_experts_roofline"]
     # the fullest of 64 experts holds 40 rows where the mean holds 8
     assert expert_load_max_over_mean.read(run) == pytest.approx(5.0)
-    ops, nbytes = getattr(opcount_moe, _config()["opcount"]["function"])(
+    ops, nbytes = getattr(opcount_moe, config["opcount"]["function"])(
         512, 64, **spec)
     assert opcount.roofline_seconds(ops, nbytes, V5E)[1] == "memory"
 
 
-@pytest.mark.parametrize("path", [CONFIG, TOY_CONFIG],
-                         ids=["lfm2-24b-a2b", "toy_lfm2"])
-def test_the_files_parts_are_its_own_widths(path):
+@pytest.mark.parametrize("name", ["lfm2-24b-a2b", "toy_lfm2"])
+def test_the_files_parts_are_its_own_widths(root, name):
     """`step_parts` is written out from a traced run's instruction
     texts, not derived, so hold it to the widths the same file gives
     the model: the held experts' matrices (their [E * f, d] view, a
@@ -248,7 +245,7 @@ def test_the_files_parts_are_its_own_widths(path):
     projection [d, 3d], its result [slots, 1, 3d] (which the output
     projection's fusion takes the gate from), the states [slots, L-1,
     d] and the taps [L, d]."""
-    config = _config(path)
+    config = config_of(TOY if name == "toy_lfm2" else root, name)
     k = config["builder"]["kwargs"]
     e, d, f = k["held"][1], k["d_model"], k["d_ff_expert"]
     qkv = (k["num_heads"] + 2 * k["kv_heads"]) * k["head_dim"]
@@ -267,13 +264,13 @@ def test_the_files_parts_are_its_own_widths(path):
 
 
 # -- the new cell's files say what was asked -------------------------------------
-def test_the_serving_cell_is_the_issues():
-    cell, config, w = cell_mod.load_cell(CELL)
+def test_the_serving_cell_is_the_issues(root):
+    cell, config, w = cell_mod.load_cell(CELL, root)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("lfm2-24b-a2b", "serve-decode128", 1)
     assert len(cell["why"]) <= 200
     assert "9 of 40" in cell["why"] and "host" in cell["why"]
-    assert cell_mod.benchmark()["workloads"][-1] == cell
+    assert cell in cell_mod.benchmark(root)["workloads"]
     assert (w["loop"], w["clients"]) == ("closed", 128)
     assert w["prompt_len"] == {"dist": "lognormal", "median": 256,
                                "sigma": 0.5, "min": 64, "max": 1024}
@@ -325,16 +322,16 @@ def test_the_serving_cell_is_the_issues():
             pub["max_position_embeddings"]) == (40, 2, 128000)
 
 
-def test_every_width_is_the_catalogs_and_reduced_lists_the_rest():
+def test_every_width_is_the_catalogs_and_reduced_lists_the_rest(root):
     """Every key of the catalog row's `config` is in the file under the
     same name; the four in `reduced` (depth, `layer_types`,
     `num_dense_layers`, positions) are the only ones that differ, and
     `published` holds those four as the row has them. None is a
     width."""
     row = CATALOG_ROW
-    config = _config()
-    entry = [c for c in cell_mod.benchmark()["configs"]
-             if c["name"] == "lfm2-24b-a2b"][0]
+    config = _config(root)
+    (entry,) = [c for c in cell_mod.benchmark(root)["configs"]
+                if c["name"] == "lfm2-24b-a2b"]
     assert entry["source"] == config["source"] == row["source_url"]
     reduced = ["num_hidden_layers", "layer_types", "num_dense_layers",
                "max_position_embeddings"]
@@ -348,32 +345,39 @@ def test_every_width_is_the_catalogs_and_reduced_lists_the_rest():
                    key.replace("num_hidden_layers", "") for key in reduced)
 
 
-def test_the_new_cell_joins_the_lists_the_issue_names():
-    """Every list that names both `gpt2-serve-decode` and the hybrid
-    model's cell, the routed layer's four, and its own `conv_step_ms`;
-    not the window's reader, not `decode_attend`'s counter."""
-    bench = cell_mod.benchmark()
-    mine = {m["name"] for m in cell_mod.metrics_for(CELL, "per_layer")}
+def test_the_new_cell_joins_the_lists_the_issue_names(root):
+    """At least every list that names both `gpt2-serve-decode` and the
+    hybrid model's cell, the routed layer's four, and its own
+    `conv_step_ms`; not the window's reader, not `decode_attend`'s
+    counter. (That a list names cells in `workloads`' order, so that a
+    cell joins at its end, is `check_lists_grow_at_their_end`'s.)"""
+    bench = cell_mod.benchmark(root)
+    mine = {m["name"] for m in cell_mod.metrics_for(CELL, "per_layer", root)}
     both = {m["name"] for m in bench["per_layer"]
             if {"gpt2-serve-decode", "mimo-v2.5-serve-mixedlen"}
             <= set(m.get("workloads", []))}
-    assert mine == both | {
+    assert mine >= both | {
         "compiles_in_window", "moe_step_ms", "attn_full_step_ms",
         "moe_experts_roofline_pct", "expert_load_max_over_mean",
         "conv_step_ms"}
     assert not {"attn_window_step_ms", "attn_rung_read_pct"} & mine
-    assert {m["name"] for m in cell_mod.metrics_for(CELL, "end_to_end")} \
+    assert {m["name"]
+            for m in cell_mod.metrics_for(CELL, "end_to_end", root)} \
         == {"out_tokens_per_s", "tpot_p50_ms", "setup_s"}
     (entry,) = [m for m in bench["per_layer"] if m["name"] == "conv_step_ms"]
+    listed = entry.pop("workloads")
     assert entry == {"name": "conv_step_ms", "unit": "ms", "better": "lower",
                      "source": "device_trace", "layer": "model math",
-                     "moves": "tpot_p50_ms", "workloads": [CELL]}
-    assert bench["per_layer"][-1] == entry
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL, m["name"]
+                     "moves": "tpot_p50_ms"}
+    # a reader of the device trace lists the cells whose model has the
+    # operations: those whose configuration gives the part it sums
+    assert CELL in listed
+    for name in listed:
+        _, config, _ = cell_mod.load_cell(name, root)
+        assert "short_conv" in config.get("step_parts", {}), name
     # the step counters the readers need are the model's
-    model = cell_mod.resolve_callable(_config()["builder"]["callable"])
+    model = cell_mod.resolve_callable(
+        _config(root)["builder"]["callable"], root)
     assert model.step_counter_names == (
         "moe_assignments_local", "moe_experts_touched", "moe_expert_load_max")
 

@@ -5,7 +5,6 @@ tests/perfbench/toy_moe/, a root of its own: never a cell, never
 reachable from the command); the new per-layer readers read counters
 and a trace given as tuples; the new cells' files say what ISSUE 27
 asked of them."""
-import json
 import os
 import time
 
@@ -20,7 +19,7 @@ from perfbench.layer_metrics import (expert_load_max_over_mean,
                                      moe_experts_roofline_pct, moe_step_ms)
 from perfbench.reference import mimo_v2_control
 from test_perfbench_contract import (check_lists_grow_at_their_end,
-                                     module_of)
+                                     config_of, module_of)
 from test_perfbench_drivers import FakeDeviceTrace, _meter, policies  # noqa: F401
 
 TOY = os.path.join(os.path.dirname(__file__), "toy_moe")
@@ -67,14 +66,13 @@ def test_a_traced_run_reports_the_counter_metrics(monkeypatch,
 
 
 # -- the device-time readers, on tuples -----------------------------------------
-MIMO = os.path.join(cell_mod.ROOT, "perfbench", "configs", "mimo-v2.5.json")
-TOY_CONFIG = os.path.join(TOY, "perfbench", "configs", "toy_moe.json")
+def _config(root):
+    return config_of(root, "mimo-v2.5")
 
 
-def _parts():
+def _parts(root):
     """The configuration's own `step_parts`, at the cell's 128 slots."""
-    with open(MIMO) as f:
-        return moe_trace.part_patterns(json.load(f)["step_parts"], 128)
+    return moe_trace.part_patterns(_config(root)["step_parts"], 128)
 
 
 GATE = ("%fusion.7 = bf16[16,128,2048]{2,1,0} fusion(bf16[128,4096]{1,0} %x, "
@@ -91,7 +89,7 @@ WHILE = ("%while.2 = (s32[], bf16[16,4096,2048]{2,1,0}, bf16[128,4,192,4096]"
          "{3,2,1,0}) while((s32[], bf16[16,4096,2048]{2,1,0}) %t), body=%b")
 
 
-def test_decode_steps_and_their_parts_are_found_by_module_and_shape():
+def test_decode_steps_and_their_parts_are_found_by_module_and_shape(root):
     mods = [("jit_slot_step(123)", 0, 1000), ("jit_prefill_rows(5)", 1000,
                                               3000),
             ("jit_slot_scan_4(77)", 3000, 8000), ("jit_slot_step(123)",
@@ -101,7 +99,7 @@ def test_decode_steps_and_their_parts_are_found_by_module_and_shape():
            (GATE, 1500, 2500),                 # a prefill's: not a step's
            (WHILE, 3000, 8000), (GATE, 3100, 3500), (FULL, 3500, 3900),
            (GATE, 9600, 9700)]                 # its program ends outside
-    red = moe_trace.reduce(ops, mods, _parts(), 0, 10000)
+    red = moe_trace.reduce(ops, mods, _parts(root), 0, 10000)
     assert list(red["seconds"]) == ["moe_experts", "attn_full", "attn_window"]
     assert red["steps"] == 5                   # 1 + a block of 4
     assert red["seconds"]["moe_experts"] == pytest.approx(700e-9)
@@ -110,21 +108,19 @@ def test_decode_steps_and_their_parts_are_found_by_module_and_shape():
     assert red["step_seconds"] == pytest.approx(6000e-9)
 
 
-def test_a_program_without_named_modules_leaves_nothing_to_read():
+def test_a_program_without_named_modules_leaves_nothing_to_read(root):
     mods = [("jit__lambda_(123)", 0, 1000)]
-    red = moe_trace.reduce([(GATE, 100, 300)], mods, _parts(), 0, 1000)
+    red = moe_trace.reduce([(GATE, 100, 300)], mods, _parts(root), 0, 1000)
     assert red["steps"] == 0
 
 
-@pytest.mark.parametrize("path", [MIMO, TOY_CONFIG],
-                         ids=["mimo-v2.5", "toy_moe"])
-def test_the_files_parts_are_its_own_widths(path):
+@pytest.mark.parametrize("name", ["mimo-v2.5", "toy_moe"])
+def test_the_files_parts_are_its_own_widths(root, name):
     """`step_parts` is written out, not derived, so hold it to the
     widths the same file gives the model: the held experts' matrices
     (and their [E * f, d] view, and a step's [E, slots, f] product),
     and [slots, key/value heads of the kind, ., .] for attention."""
-    with open(path) as f:
-        config = json.load(f)
+    config = config_of(TOY if name == "toy_moe" else root, name)
     k = config["builder"]["kwargs"]
     e, d, f = k["held"][1], k["d_model"], k["d_ff_expert"]
     assert config["step_parts"] == {
@@ -141,12 +137,12 @@ def test_the_files_parts_are_its_own_widths(path):
         f"f32[128,{k['kv_heads_full']},16,4096]")
 
 
-def test_a_run_reads_the_parts_its_configuration_gives():
+def test_a_run_reads_the_parts_its_configuration_gives(root):
     """`{slots}` comes from the engine settings, which the traffic
     file may override (the pool is the next power of two); a part the
     configuration lacks, or a configuration without the section, reads
     None."""
-    run = _run_with({"steps": 10, "step_seconds": 1.0, "seconds": {
+    run = _run_with(root, {"steps": 10, "step_seconds": 1.0, "seconds": {
         "moe_experts": 0.5, "attn_full": 0.0}}, {})
     assert moe_trace.step_ms(run, "moe_experts") == pytest.approx(50.0)
     assert moe_trace.step_ms(run, "attn_full") is None      # nothing ran
@@ -157,7 +153,7 @@ def test_a_run_reads_the_parts_its_configuration_gives():
     assert moe_trace.patterns_of(run)["attn_window"].pattern \
         == r"(?:\[64,8,\d+,\d+\])"
     # no section: nothing to read, whatever the trace holds
-    bare = _run_with(None, {})
+    bare = _run_with(root, None, {})
     del bare.moe_trace, bare.config["step_parts"]
     bare.device_trace, bare.trace_window_ns = object(), (0, 1)
     assert moe_trace.of_run(bare) is None
@@ -165,17 +161,15 @@ def test_a_run_reads_the_parts_its_configuration_gives():
     assert moe_experts_roofline_pct.read(bare) is None
 
 
-def _run_with(red, counters):
-    with open(MIMO) as f:
-        config = json.load(f)
-    run = cell_mod.Run(cell={"name": "x"}, config=config, workload={},
+def _run_with(root, red, counters):
+    run = cell_mod.Run(cell={"name": "x"}, config=_config(root), workload={},
                        seconds=30.0, trace=True, peaks=V5E)
     run.moe_trace = red
     run.counters["decode"] = counters
     return run
 
 
-def test_the_roofline_share_is_the_touched_weights_over_the_time_taken():
+def test_the_roofline_share_is_the_touched_weights_over_the_time_taken(root):
     """64 assignments on 15.6 of 16 experts a layer, 6 layers, a step:
     the need is those experts' 50 MB once (memory-bound), and a kernel
     that streams all 96 expert-layers at the peak reads 97 %."""
@@ -184,7 +178,7 @@ def test_the_roofline_share_is_the_touched_weights_over_the_time_taken():
     red = {"steps": steps, "step_seconds": 2.0, "seconds": {
         "moe_experts": steps * weights / V5E["hbm_bytes_per_s"],
         "attn_full": 0.4, "attn_window": 0.0}}
-    run = _run_with(red, {"decode_steps": 1000,
+    run = _run_with(root, red, {"decode_steps": 1000,
                           "moe_assignments_local": 1000 * 6 * 64,
                           "moe_experts_touched": 1000 * 93.5,
                           "moe_expert_load_max": 1000 * 6 * 9})
@@ -195,8 +189,8 @@ def test_the_roofline_share_is_the_touched_weights_over_the_time_taken():
         1e3 * weights / V5E["hbm_bytes_per_s"])
     # the fullest of 16 experts holds 9 where the mean holds 4
     assert expert_load_max_over_mean.read(run) == pytest.approx(9 / 4)
-    assert moe_experts_roofline_pct.read(_run_with(None, {})) is None
-    assert expert_load_max_over_mean.read(_run_with(None, {})) is None
+    assert moe_experts_roofline_pct.read(_run_with(root, None, {})) is None
+    assert expert_load_max_over_mean.read(_run_with(root, None, {})) is None
 
 
 def test_the_control_tier_comes_out_not_correct(policies):  # noqa: F811
@@ -214,7 +208,7 @@ def test_the_control_tier_comes_out_not_correct(policies):  # noqa: F811
     assert not out["control_correct"] and out["control_worst"] > 0.01
 
 
-def test_opcount_moe_counts_what_is_needed():
+def test_opcount_moe_counts_what_is_needed(root):
     ops, nbytes = opcount_moe.expert_products(64, 16, 4096, 2048, 2)
     assert ops == 64 * 3 * 2 * 4096 * 2048
     assert nbytes == (16 * 3 * 4096 * 2048 + 64 * 2 * 4096) * 2
@@ -224,14 +218,14 @@ def test_opcount_moe_counts_what_is_needed():
     ops, nbytes = opcount_moe.expert_products(16 * 2048, 16, 4096, 2048, 2)
     assert opcount.roofline_seconds(ops, nbytes, V5E)[1] == "compute"
     # the configuration names this function and the published widths
-    spec = json.load(open(MIMO))["opcount"]
+    spec = _config(root)["opcount"]
     assert getattr(opcount_moe, spec["function"])(64, 16, **spec["kwargs"]) \
         == (64 * 3 * 2 * 4096 * 2048, nbytes_of_64)
 
 
 # -- the new cells' files say what was asked ---------------------------------------
-def test_the_serving_cell_is_the_issues():
-    cell, config, w = cell_mod.load_cell("mimo-v2.5-serve-mixedlen")
+def test_the_serving_cell_is_the_issues(root):
+    cell, config, w = cell_mod.load_cell("mimo-v2.5-serve-mixedlen", root)
     assert (w["loop"], w["clients"]) == ("closed", 128)
     assert w["prompt_len"] == {"dist": "lognormal", "median": 384,
                                "sigma": 1.0, "min": 64, "max": 2048}
@@ -275,7 +269,7 @@ def test_the_serving_cell_is_the_issues():
     assert {**k, **config["reference"]["kwargs"]} == k
 
 
-def counted_by(config, root=cell_mod.ROOT):
+def counted_by(config, root):
     """The step counters the configuration's model returns beside its
     logits (`DecodeLM.step_counter_names`, on the class the `builder`
     names)."""
@@ -308,11 +302,12 @@ def check_the_hybrid_cell_reports_what_the_decode_cell_does(root):
             "reader's COUNTERS the step counter its model does not count")
 
 
-def test_the_new_cell_joins_the_decode_cells_metrics_and_only_grows_lists():
+def test_the_new_cell_joins_the_decode_cells_metrics_and_only_grows_lists(
+        root):
     """For any number of cells: a metric's list names cells in
     BENCHMARK.json's own order, so lists only grow at their end; and
     this cell reports every per-layer metric `gpt2-serve-decode`
     reports, but for those whose reader cannot read its program. (That
     few cells take four chips is the contract test's.)"""
-    check_lists_grow_at_their_end(cell_mod.benchmark(), cell_mod.ROOT)
-    check_the_hybrid_cell_reports_what_the_decode_cell_does(cell_mod.ROOT)
+    check_lists_grow_at_their_end(cell_mod.benchmark(root), root)
+    check_the_hybrid_cell_reports_what_the_decode_cell_does(root)

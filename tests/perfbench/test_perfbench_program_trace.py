@@ -322,26 +322,27 @@ def the_nine(bench):
 
 
 @pytest.fixture
-def toy_root(tmp_path):
-    """The toy root with this PR's entries for its serving cells: the
+def toy_root(root, tmp_path):
+    """The toy root with the nine's entries for its serving cells: the
     toy's own BENCHMARK.json is not this file's to edit."""
-    root = tmp_path / "toy"
-    shutil.copytree(TOY, root)
-    with open(root / "BENCHMARK.json") as f:
+    toy = tmp_path / "toy"
+    shutil.copytree(TOY, toy)
+    with open(toy / "BENCHMARK.json") as f:
         bench = json.load(f)
     names = {m["name"] for m in bench["per_layer"]}
-    for m in the_nine(cell_mod.benchmark()):
+    for m in the_nine(cell_mod.benchmark(root)):
         assert m["name"] not in names
         bench["per_layer"].append(
             {**m, "workloads": ["toy-serve-closed", "toy-serve-open"]})
-    with open(root / "BENCHMARK.json", "w") as f:
+    with open(toy / "BENCHMARK.json", "w") as f:
         json.dump(bench, f)
-    return str(root)
+    return str(toy)
 
 
 _METER = []
 
 
+@pytest.mark.parametrize("root", ["ours"], indirect=True)  # it runs a model
 @pytest.mark.parametrize("name", ["toy-serve-closed", "toy-serve-open"])
 def test_traced_toy_run_reports_the_nine_metrics(name, toy_root, trace_dir,
                                                  monkeypatch, policies):

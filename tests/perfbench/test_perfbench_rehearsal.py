@@ -1,16 +1,22 @@
-"""A later PR's addition, rehearsed: one configuration, one cell and
-two per-layer metrics (one for the new cell, one for the GPT-2 serving
-cells alone) join the benchmark by NEW files and APPENDED entries
-alone, and every check the benchmark has of BENCHMARK.json still passes
-on the grown root. A check that pins today's census (a count of cells,
-the name of the last, a list held equal to today's, a table of today's
-exceptions) fails here before it can refuse that PR."""
+"""Later PRs' additions, rehearsed: the `grown` root of `conftest.py`
+(`additions.py`: a configuration with its cell and two per-layer
+metrics, and a second cell on a configuration the benchmark has) joins
+the benchmark by NEW files and APPENDED entries alone. Every test of
+this directory that reads the repository's BENCHMARK.json or perfbench/
+runs on that root too, through the fixture `root`; here are what only
+the grown root can show, and the guard that keeps a test file from
+stepping round the fixture."""
+import ast
+import glob
 import json
 import os
 import shutil
 
 import pytest
 
+from additions import (AGAIN_CELL, AGAIN_OF, GPT2_ENTRY, GPT2_METRIC,
+                       GPT2_ONLY, GPT2_READER, JOINS, NEW_CELL, NEW_CONFIG,
+                       NEW_ENTRY, NEW_METRIC, TOY_MOE, grow, joined)
 from perfbench.harness import cell, moe_trace
 from test_perfbench_contract import check_all, module_of
 from test_perfbench_moe import (
@@ -18,42 +24,6 @@ from test_perfbench_moe import (
 from test_perfbench_program_trace import the_nine
 
 HERE = os.path.dirname(__file__)
-TOY_MOE = os.path.join(HERE, "toy_moe", "perfbench")
-NEW_CONFIG, NEW_CELL = "rehearsed", "rehearsed-serve"
-NEW_METRIC = "rehearsed_kernel_step_ms"
-JOINS = "mimo-v2.5-serve-mixedlen"      # the cell whose lists it joins
-READER = '''"""Device time a fused decode step spends in the attention kernel."""
-from perfbench.harness import moe_trace
-
-LAYER = "model math"
-UNIT = "ms"
-MOVES = "tpot_p50_ms"
-
-
-def read(run):
-    return moe_trace.step_ms(run, "attn_kernel")
-'''
-# a metric for the GPT-2 serving cells alone, as PR 30 wanted one: its
-# own file says which step counters it reads, and the hybrid model
-# counts none of them, so the cell of that model is left off its list
-GPT2_ONLY = ["gpt2-serve-decode", "gpt2-serve-short"]
-GPT2_METRIC = "rehearsed_blocks_read_per_step"
-GPT2_READER = '''"""Blocks the decode steps' attention read, a step."""
-LAYER = "kernels"
-UNIT = "blocks"
-MOVES = "out_tokens_per_s"
-COUNTERS = ("attn_blocks_read",)
-
-
-def read(run):
-    d = run.counters.get("decode", {})
-    if not d.get("decode_steps") or not d.get(COUNTERS[0]):
-        return None
-    return d[COUNTERS[0]] / d["decode_steps"]
-'''
-GPT2_ENTRY = {"name": GPT2_METRIC, "unit": "blocks", "better": "lower",
-              "source": "program_counter", "layer": "kernels",
-              "moves": "out_tokens_per_s", "workloads": GPT2_ONLY}
 # an attention kernel as a trace names it: found by its name, whatever
 # shapes its operands have
 KERNEL = ('%decode_attend.7 = bf16[4,8,16]{2,1,0} custom-call(bf16[4,2,16,64]'
@@ -62,66 +32,30 @@ KERNEL = ('%decode_attend.7 = bf16[4,8,16]{2,1,0} custom-call(bf16[4,2,16,64]'
 
 
 @pytest.fixture
-def grown(tmp_path):
-    """This repository's BENCHMARK.json and perfbench/, plus the files
-    and entries a PR that brings a configuration adds."""
-    root = tmp_path
-    shutil.copy(os.path.join(cell.ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(cell.ROOT, "perfbench"), root / "perfbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = {os.path.relpath(os.path.join(d, f), root)
-              for d, _, files in os.walk(root) for f in files}
-    # new files: a configuration (the toy, given a fourth part found by
-    # an operation's name and tried first), its traffic, a reader
-    with open(os.path.join(TOY_MOE, "configs", "toy_moe.json")) as f:
-        config = json.load(f)
-    config["step_parts"] = {"attn_kernel": ["decode_attend"],
-                            **config["step_parts"]}
-    with open(root / "perfbench" / "configs" / (NEW_CONFIG + ".json"),
-              "w") as f:
-        json.dump(config, f)
-    shutil.copy(os.path.join(TOY_MOE, "workloads", "toy-moe-serve.json"),
-                root / "perfbench" / "workloads" / (NEW_CELL + ".json"))
-    (root / "perfbench" / "layer_metrics" / (NEW_METRIC + ".py")).write_text(
-        READER)
-    (root / "perfbench" / "layer_metrics" / (GPT2_METRIC + ".py")).write_text(
-        GPT2_READER)
-    # appended entries, and the cell's name at the end of the lists the
-    # routed-expert cell is on
-    bench = cell.benchmark(str(root))
-    bench["configs"].append({
-        "name": NEW_CONFIG, "source": config["source"],
-        "file": f"perfbench/configs/{NEW_CONFIG}.json", "reduced": [],
-        "why": "rehearsal"})
-    bench["workloads"].append({
-        "name": NEW_CELL, "config": NEW_CONFIG, "traffic": "serve",
-        "chips": 1, "why": "rehearsal"})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if JOINS in m.get("workloads", []):
-            m["workloads"].append(NEW_CELL)
-    bench["per_layer"].append({
-        "name": NEW_METRIC, "unit": "ms", "better": "lower",
-        "source": "device_trace", "layer": "model math",
-        "moves": "tpot_p50_ms", "workloads": [NEW_CELL]})
-    bench["per_layer"].append(dict(GPT2_ENTRY))
-    with open(root / "BENCHMARK.json", "w") as f:
-        json.dump(bench, f, indent=1)
-    after = {os.path.relpath(os.path.join(d, f), root)
-             for d, _, files in os.walk(root) for f in files}
-    assert before <= after and len(after - before) == 4
-    return str(root)
+def own(grown, tmp_path):
+    """A copy of the grown root of the test's own, to rewrite."""
+    return str(shutil.copytree(grown, tmp_path / "grown"))
 
 
+# `root` is the repository's here, beside the grown one
+@pytest.mark.parametrize("root", ["ours"], indirect=True)
 def test_an_addition_by_new_files_and_appended_entries_passes_every_check(
-        grown):
+        root, grown):
     bench = cell.benchmark(grown)
-    ours = cell.benchmark()
-    assert len(bench["workloads"]) == len(ours["workloads"]) + 1
+    ours = cell.benchmark(root)
+    # what `conftest.py` parametrises over is what the root holds: this
+    # repository's entries first, then two cells, two per-layer entries
+    assert bench == grow(ours)
+    assert bench["workloads"][:-2] == ours["workloads"]
+    assert [w["name"] for w in bench["workloads"][-2:]] \
+        == [NEW_CELL, AGAIN_CELL]
     assert len(bench["configs"]) == len(ours["configs"]) + 1
+    assert bench["per_layer"][-2:] == [NEW_ENTRY, GPT2_ENTRY]
     check_all(bench, grown)
     # the rules the other test files hold this repository's file to
     check_the_hybrid_cell_reports_what_the_decode_cell_does(grown)
-    assert all(m["workloads"][-1] == NEW_CELL for m in the_nine(bench))
+    assert all({NEW_CELL, AGAIN_CELL} <= set(m["workloads"])
+               for m in the_nine(bench))
     # the new cell resolves to its files, and reports what the cell it
     # joined reports (less that cell's own) and its own metric
     c, config, workload = cell.load_cell(NEW_CELL, grown)
@@ -132,14 +66,24 @@ def test_an_addition_by_new_files_and_appended_entries_passes_every_check(
     assert {"idle_readback_pct", "moe_step_ms", "tokens_per_step"} <= mine
     assert module_of(grown, "layer_metrics", NEW_METRIC).MOVES \
         == "tpot_p50_ms"
+    # the second cell of a configuration the benchmark has resolves to
+    # that configuration and a traffic file of its own, and reports
+    # what the first does, the configuration's own metric included
+    c, config, workload = cell.load_cell(AGAIN_CELL, grown)
+    first, config_of_first, _ = cell.load_cell(AGAIN_OF, grown)
+    assert c["config"] == first["config"] and config == config_of_first
+    assert c["traffic"] != first["traffic"]
+    for group in ("end_to_end", "per_layer"):
+        assert cell.metrics_for(AGAIN_CELL, group, grown) \
+            == cell.metrics_for(AGAIN_OF, group, grown)
+    assert "conv_step_ms" in {
+        m["name"] for m in cell.metrics_for(AGAIN_CELL, "per_layer", grown)}
     # nothing this repository's cells report has moved; the two GPT-2
     # serving cells report the metric brought for them, last
     for name in (w["name"] for w in ours["workloads"]):
         for group in ("end_to_end", "per_layer"):
             assert cell.metrics_for(name, group, grown) \
-                == [{**m, "workloads": m["workloads"] + [NEW_CELL]}
-                    if JOINS in m.get("workloads", []) else m
-                    for m in cell.metrics_for(name, group)] \
+                == [joined(m) for m in cell.metrics_for(name, group, root)] \
                 + [GPT2_ENTRY] * (group == "per_layer" and name in GPT2_ONLY)
 
 
@@ -150,34 +94,35 @@ def _rewrite(root, change):
         json.dump(bench, f, indent=1)
 
 
-def test_a_metric_for_the_gpt2_cells_alone_brings_its_own_exception(grown):
+def test_a_metric_for_the_gpt2_cells_alone_brings_its_own_exception(own):
     """The rule that the hybrid model's cell reports what
     `gpt2-serve-decode` does takes its exception from the reader's own
     file, so it passes the root that a PR such as PR 30 would leave;
     and it is a rule still: the same entry with a reader that names no
     counter, or only counters the hybrid model counts, is refused."""
-    check_the_hybrid_cell_reports_what_the_decode_cell_does(grown)
-    mine = {m["name"] for m in cell.metrics_for(JOINS, "per_layer", grown)}
+    check_the_hybrid_cell_reports_what_the_decode_cell_does(own)
+    mine = {m["name"] for m in cell.metrics_for(JOINS, "per_layer", own)}
     assert GPT2_METRIC not in mine and "attn_rung_read_pct" not in mine
-    reader = os.path.join(grown, "perfbench", "layer_metrics",
+    reader = os.path.join(own, "perfbench", "layer_metrics",
                           GPT2_METRIC + ".py")
     for counters in ('()', '("moe_experts_touched",)'):
         with open(reader, "w") as f:
             f.write(GPT2_READER.replace('("attn_blocks_read",)', counters))
         module_of.cache_clear()
         with pytest.raises(AssertionError, match=GPT2_METRIC):
-            check_the_hybrid_cell_reports_what_the_decode_cell_does(grown)
+            check_the_hybrid_cell_reports_what_the_decode_cell_does(own)
     # a reader of the device trace finds one model's operations: free
-    _rewrite(grown, lambda b: b["per_layer"][-1].update(
-        source="device_trace"))
-    check_the_hybrid_cell_reports_what_the_decode_cell_does(grown)
+    _rewrite(own, lambda b: [m.update(source="device_trace")
+                             for m in b["per_layer"]
+                             if m["name"] == GPT2_METRIC])
+    check_the_hybrid_cell_reports_what_the_decode_cell_does(own)
     # and a span reader that `serve.py` feeds under either model is not:
     # taking the hybrid cell off one of the nine is refused
-    _rewrite(grown, lambda b: [m["workloads"].remove(JOINS)
-                               for m in b["per_layer"]
-                               if m["name"] == "token_scatter_ms_p50"])
+    _rewrite(own, lambda b: [m["workloads"].remove(JOINS)
+                             for m in b["per_layer"]
+                             if m["name"] == "token_scatter_ms_p50"])
     with pytest.raises(AssertionError, match="token_scatter_ms_p50"):
-        check_the_hybrid_cell_reports_what_the_decode_cell_does(grown)
+        check_the_hybrid_cell_reports_what_the_decode_cell_does(own)
 
 
 def test_a_fourth_part_found_by_name_is_summed_like_the_three(grown):
@@ -216,3 +161,66 @@ def test_a_fourth_part_found_by_name_is_summed_like_the_three(grown):
     red = moe_trace.reduce(ops, mods, ours, 0, 10000)
     assert red["seconds"]["attn_full"] == pytest.approx(550e-9)
     assert red["seconds"]["moe_experts"] == pytest.approx(600e-9)
+
+
+# -- the guard: no test file steps round the fixture -----------------------------
+# positional arguments before `root` of the functions of `harness/cell.py`
+# that read a root, which default it to the repository
+ROOTED = {"benchmark": 0, "load_cell": 1, "metrics_for": 2,
+          "resolve_callable": 1, "build": 1}
+
+
+def rootless(source, filename="<planted>"):
+    """"file:line: what" for every call in `source` of one of `ROOTED`
+    that passes no root, and for every use of `ROOT`: either reads this
+    repository whatever root the test runs on."""
+    found = []
+    for node in ast.walk(ast.parse(source, filename)):
+        what = None
+        if isinstance(node, ast.Call):
+            f = node.func
+            name = f.attr if isinstance(f, ast.Attribute) else getattr(
+                f, "id", None)
+            if name in ROOTED and len(node.args) <= ROOTED[name] \
+                    and not any(k.arg in ("root", None)
+                                for k in node.keywords):
+                what = f"{name}() with no root"
+        elif isinstance(node, ast.Attribute) and node.attr == "ROOT":
+            what = "ROOT"
+        elif isinstance(node, ast.ImportFrom) and any(
+                a.name == "ROOT" for a in node.names):
+            what = "ROOT"
+        if what:
+            found.append(f"{filename}:{node.lineno}: {what}")
+    return found
+
+
+@pytest.mark.parametrize("planted, refused", [
+    ("bench = cell_mod.benchmark()", True),
+    ("bench = cell_mod.benchmark(root)", False),
+    ("c, config, w = cell.load_cell(NAME)", True),
+    ("c, config, w = cell.load_cell(NAME, root)", False),
+    ("c, config, w = load_cell(NAME, root=TOY)", False),
+    ("ms = cell.metrics_for(NAME, 'per_layer')", True),
+    ("model = cell.resolve_callable(spec)", True),
+    ("model = cell.build(spec, root)", False),
+    ("path = os.path.join(cell.ROOT, 'perfbench')", True),
+    ("from perfbench.harness.cell import ROOT", True),
+    ("def counted_by(config, root=cell_mod.ROOT): pass", True),
+], ids=lambda v: v if isinstance(v, str) else ("passes", "refused")[v])
+def test_the_guard_refuses_a_planted_read_of_the_repository(planted, refused):
+    assert bool(rootless(planted)) == refused, rootless(planted)
+
+
+def test_every_test_file_takes_its_root_from_the_fixture():
+    """Every `test_*.py` of this directory, the files later PRs bring
+    too: a test that reads the repository's benchmark does so through
+    `root` (conftest.py), so that it runs on the grown root in the PR
+    that writes it. `conftest.py` alone names the repository."""
+    files = sorted(glob.glob(os.path.join(HERE, "test_*.py")))
+    assert os.path.abspath(__file__) in files
+    found = []
+    for path in files:
+        with open(path) as f:
+            found += rootless(f.read(), os.path.relpath(path, HERE))
+    assert not found, "\n".join(found)

@@ -10,17 +10,23 @@ from perfbench.harness import cell, traffic
 SERVE = ["gpt2-serve-decode", "gpt2-serve-short"]
 
 
-def _workload(name):
-    with open(os.path.join(cell.ROOT, "perfbench", "workloads",
+def _workload(root, name):
+    with open(os.path.join(root, "perfbench", "workloads",
                            name + ".json")) as f:
         return json.load(f)
 
 
-OPEN = _workload("gpt2-serve-short")
-CLOSED = _workload("gpt2-serve-decode")
+@pytest.fixture
+def OPEN(root):
+    return _workload(root, "gpt2-serve-short")
 
 
-def test_open_schedule_is_a_pure_function_of_the_seed():
+@pytest.fixture
+def CLOSED(root):
+    return _workload(root, "gpt2-serve-decode")
+
+
+def test_open_schedule_is_a_pure_function_of_the_seed(OPEN):
     a = traffic.open_schedule(OPEN, 7, 5.0)
     b = traffic.open_schedule(OPEN, 7, 5.0)
     c = traffic.open_schedule(OPEN, 8, 5.0)
@@ -30,7 +36,7 @@ def test_open_schedule_is_a_pure_function_of_the_seed():
     assert all(np.array_equal(x, y) for x, y in zip(ids, again))
 
 
-def test_open_schedule_rate_lead_and_order():
+def test_open_schedule_rate_lead_and_order(OPEN):
     reqs = traffic.open_schedule(OPEN, 3, 30.0)
     due = [r.due_s for r in reqs]
     assert due == sorted(due) and due[0] >= -OPEN["lead_s"] and due[-1] < 30
@@ -38,7 +44,7 @@ def test_open_schedule_rate_lead_and_order():
     assert len(reqs) == round(rate * OPEN["lead_s"]) + rate * 30
 
 
-def test_every_seed_offers_the_same_work():
+def test_every_seed_offers_the_same_work(OPEN):
     """Steady by a fixed amount of work drawn from the seed: the same
     count, the same prompt and reply tokens in the window and in every
     block; only order, pairing and timing differ."""
@@ -61,8 +67,8 @@ def test_every_seed_offers_the_same_work():
 
 
 @pytest.mark.parametrize("name", SERVE)
-def test_lengths_respect_the_clips_and_the_median(name):
-    w = _workload(name)
+def test_lengths_respect_the_clips_and_the_median(root, name):
+    w = _workload(root, name)
     rng = np.random.default_rng(0)
     for key in ("prompt_len", "output_len"):
         xs = [traffic.draw_length(w[key], rng) for _ in range(4000)]
@@ -71,16 +77,16 @@ def test_lengths_respect_the_clips_and_the_median(name):
 
 
 @pytest.mark.parametrize("name", SERVE)
-def test_traffic_fits_the_configuration(name):
+def test_traffic_fits_the_configuration(root, name):
     """Choose traffic on which no operation fails: the longest prompt
     plus the longest reply fit the model and the engine's ceiling."""
-    c, config, w = cell.load_cell(name)
+    c, config, w = cell.load_cell(name, root)
     _, longest, new = traffic.limits(w)
     assert longest + new <= config["n_positions"]
     assert new <= config["serve"]["engine"]["max_new_tokens"]
 
 
-def test_closed_requests_and_the_steady_state_start():
+def test_closed_requests_and_the_steady_state_start(CLOSED):
     a = traffic.closed_request(CLOSED, 5, 3, 2)
     assert a == traffic.closed_request(CLOSED, 5, 3, 2)
     assert a != traffic.closed_request(CLOSED, 5, 4, 2)
@@ -93,13 +99,13 @@ def test_closed_requests_and_the_steady_state_start():
     assert min(later) >= CLOSED["output_len"]["min"]
 
 
-def test_prompt_buckets_cover_the_rungs_the_traffic_reaches():
+def test_prompt_buckets_cover_the_rungs_the_traffic_reaches(OPEN, CLOSED):
     assert traffic.prompt_buckets(CLOSED) == [32, 64, 128, 256]
     assert traffic.prompt_buckets(OPEN) == [8, 16, 32, 64, 128]
     assert traffic.limits(CLOSED) == (32, 256, 512)
 
 
-def test_bursts_keep_the_mean_rate_and_classes_mix():
+def test_bursts_keep_the_mean_rate_and_classes_mix(OPEN):
     w = dict(OPEN, arrivals={"rate_per_s": 50, "bursts": {
         "period_s": 4.0, "on_s": 1.0, "factor": 3.0}}, lead_s=0.0)
     reqs = traffic.open_schedule(w, 1, 80.0)
