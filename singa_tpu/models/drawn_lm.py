@@ -10,6 +10,12 @@ A subclass gives `_param_table()` (every parameter by dotted name),
 `_tree(leaf)` (the tree its programs receive), `_eval_logits(params,
 ids)` (the stack with no cache) and its slab; `_slab_words` says what
 the slab holds, for the messages of what is not implemented.
+
+`DrawnDecodeLM` is what EVERY such model shares (the draw, the norm,
+`forward`, the not-implemented messages); `RoutedDrawnLM` adds what
+the two routed models share (the held range of experts, the one routed
+layer with its constants, the three `moe_*` step counters). A dense
+model (`chunked_attn.py`) carries none of that.
 """
 from __future__ import annotations
 
@@ -149,36 +155,20 @@ def put_rows(cache, rows, slots):
 
 
 class DrawnDecodeLM(DecodeLM):
-    """Base of `HybridWindowMoELM` and `ShortConvMoELM`."""
+    """Base of every decode-tier model whose parameters are drawn on
+    the device: `RoutedDrawnLM`'s two and `ChunkedAttnLM`."""
 
-    # a run-ahead block is its steps in a row, not a loop: around a
-    # loop XLA re-lays every held expert's gate and up matrices out
-    # (12 copies of 0.83 ms a block on the chip, which the single step
-    # reads as stored at the same speed) and carries a second slab
-    # among its temporaries (3.4 GB at the served size)
-    scan_unroll = True
-    step_counter_names = ("moe_assignments_local", "moe_experts_touched",
-                          "moe_expert_load_max")
-    # `routed_experts`'s: one constant for every model that calls it,
-    # and the number in its normalising sum an architecture may set
-    dense_rows = DENSE_ROWS
-    router_sum_eps = 0.0
-    # the subclass's: what its slab holds, and what a training path
-    # lacks ("with no backward for ..., and no optimizer state for ...")
+    # the subclass's: what its slab holds, what a training path lacks
+    # ("with no backward for ..., and no optimizer state for ..."), and
+    # why it has no sharded path
     _slab_words = ""
     _training_lacks = ""
+    _one_chip_holds = ""
 
     def _init_drawn(self, vocab_size, max_len, norm_eps, param_dtype,
-                    init_std, n_experts, experts_per_token, held):
+                    init_std):
         import jax.numpy as jnp
 
-        first, count = (int(v) for v in held)
-        if not (0 <= first and count >= 1
-                and first + count <= n_experts):
-            raise ValueError(f"held {held} is no range of {n_experts} experts")
-        self.n_experts = int(n_experts)
-        self.experts_per_token = int(experts_per_token)
-        self.held = (first, count)
         self.vocab_size, self.max_len = int(vocab_size), int(max_len)
         self.norm_eps = float(norm_eps)
         if param_dtype not in ("float32", "bfloat16"):
@@ -197,7 +187,7 @@ class DrawnDecodeLM(DecodeLM):
         if kwargs.get("mesh") is not None or kwargs.get("plan") is not None:
             raise NotImplementedError(
                 f"{type(self).__name__}: no sharded (mesh / ParallelPlan) "
-                "path; one chip holds its share of the experts")
+                f"path; one chip holds {self._one_chip_holds}")
         from ..device import get_default_device
 
         dev = inputs[0].device if inputs else get_default_device()
@@ -236,9 +226,6 @@ class DrawnDecodeLM(DecodeLM):
     def _norm_eps(self):
         return (self.norm_eps,)
 
-    def _trace_key(self):
-        return super()._trace_key() + (self.dense_rows,)
-
     # -- what is not implemented, by mechanism -----------------------------
     def _no_training(self):
         return (f"{type(self).__name__} has no training path: its stack "
@@ -265,6 +252,16 @@ class DrawnDecodeLM(DecodeLM):
             f"cache rows) is not implemented for a slab of "
             f"{self._slab_words}; a resumed session replays its ledger")
 
+    # -- the slab every such model states: a dict of arrays a layer --------
+    @staticmethod
+    def _slab_sig(slab):
+        return (tuple(tuple(tuple(a.shape) for a in c.values())
+                      for c in slab), next(iter(slab[0].values())).dtype.name)
+
+    @staticmethod
+    def _slab_extra(slab):
+        return [[list(a.shape) for a in c.values()] for c in slab]
+
     # -- the mathematics every such stack has ------------------------------
     def _rms(self, h, gamma):
         import jax.numpy as jnp
@@ -274,15 +271,6 @@ class DrawnDecodeLM(DecodeLM):
         y = hf * lax.rsqrt(jnp.mean(hf * hf, -1, keepdims=True)
                            + self.norm_eps) * gamma
         return y.astype(h.dtype)
-
-    def _experts(self, ffn, x, prec):
-        """The held experts' part of a routed layer for x [N, d], and
-        the held experts' assignment counts [count]: the decode tier's
-        one routed layer, with this architecture's numbers."""
-        return routed_experts(ffn, x, prec, held=self.held,
-                              experts_per_token=self.experts_per_token,
-                              dense_rows=self.dense_rows,
-                              sum_eps=self.router_sum_eps)
 
     def forward(self, x):
         """Logits [B, S, vocab] of ids [B, S]: the same stack with no
@@ -295,3 +283,46 @@ class DrawnDecodeLM(DecodeLM):
         if fn is None:
             fn = cache[key_] = jax.jit(self._eval_logits)
         return tensor.from_raw(fn(self._decode_params(), x.data), x.device)
+
+
+class RoutedDrawnLM(DrawnDecodeLM):
+    """Base of `HybridWindowMoELM` and `ShortConvMoELM`: a drawn model
+    with routed layers, of which this chip holds a range of experts."""
+
+    # a run-ahead block is its steps in a row, not a loop: around a
+    # loop XLA re-lays every held expert's gate and up matrices out
+    # (12 copies of 0.83 ms a block on the chip, which the single step
+    # reads as stored at the same speed) and carries a second slab
+    # among its temporaries (3.4 GB at the served size)
+    scan_unroll = True
+    step_counter_names = ("moe_assignments_local", "moe_experts_touched",
+                          "moe_expert_load_max")
+    # `routed_experts`'s: one constant for every model that calls it,
+    # and the number in its normalising sum an architecture may set
+    dense_rows = DENSE_ROWS
+    router_sum_eps = 0.0
+    _one_chip_holds = "its share of the experts"
+
+    def _init_drawn(self, vocab_size, max_len, norm_eps, param_dtype,
+                    init_std, n_experts, experts_per_token, held):
+        first, count = (int(v) for v in held)
+        if not (0 <= first and count >= 1
+                and first + count <= n_experts):
+            raise ValueError(f"held {held} is no range of {n_experts} experts")
+        self.n_experts = int(n_experts)
+        self.experts_per_token = int(experts_per_token)
+        self.held = (first, count)
+        super()._init_drawn(vocab_size, max_len, norm_eps, param_dtype,
+                            init_std)
+
+    def _trace_key(self):
+        return super()._trace_key() + (self.dense_rows,)
+
+    def _experts(self, ffn, x, prec):
+        """The held experts' part of a routed layer for x [N, d], and
+        the held experts' assignment counts [count]: the decode tier's
+        one routed layer, with this architecture's numbers."""
+        return routed_experts(ffn, x, prec, held=self.held,
+                              experts_per_token=self.experts_per_token,
+                              dense_rows=self.dense_rows,
+                              sum_eps=self.router_sum_eps)

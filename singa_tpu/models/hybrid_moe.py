@@ -38,13 +38,13 @@ from __future__ import annotations
 import numpy as np
 
 from .. import tensor
-from .drawn_lm import (DrawnDecodeLM, attend_cache, attend_prompts, dense_mlp,
+from .drawn_lm import (RoutedDrawnLM, attend_cache, attend_prompts, dense_mlp,
                        put_rows, rope, softmax_probs)
 
 FULL, WINDOW = 0, 1
 
 
-class HybridWindowMoELM(DrawnDecodeLM):
+class HybridWindowMoELM(RoutedDrawnLM):
     """Causal LM over int token ids [B, S] -> logits [B, S, vocab]."""
 
     _slab_words = "rings and contexts"
@@ -307,15 +307,6 @@ class HybridWindowMoELM(DrawnDecodeLM):
             out["context" if kind == FULL else "ring"] += sum(
                 a.size * a.dtype.itemsize for a in c.values())
         return out
-
-    @staticmethod
-    def _slab_sig(slab):
-        return (tuple((tuple(c["k"].shape), tuple(c["v"].shape))
-                      for c in slab), slab[0]["k"].dtype.name)
-
-    @staticmethod
-    def _slab_extra(slab):
-        return [[list(c["k"].shape), list(c["v"].shape)] for c in slab]
 
     # -- the programs' step functions --------------------------------------
     def _slot_step(self, params, slab, tok, pos):

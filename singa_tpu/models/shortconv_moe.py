@@ -41,13 +41,13 @@ from __future__ import annotations
 import numpy as np
 
 from .. import tensor
-from .drawn_lm import (DrawnDecodeLM, attend_cache, attend_prompts, dense_mlp,
+from .drawn_lm import (RoutedDrawnLM, attend_cache, attend_prompts, dense_mlp,
                        put_rows, rope)
 
 CONV, ATTN = "conv", "full_attention"
 
 
-class ShortConvMoELM(DrawnDecodeLM):
+class ShortConvMoELM(RoutedDrawnLM):
     """Causal LM over int token ids [B, S] -> logits [B, S, vocab]."""
 
     _slab_words = "contexts and convolution states"
@@ -287,15 +287,6 @@ class ShortConvMoELM(DrawnDecodeLM):
             out["state" if kind == CONV else "context"] += sum(
                 a.size * a.dtype.itemsize for a in c.values())
         return out
-
-    @staticmethod
-    def _slab_sig(slab):
-        return (tuple(tuple(tuple(a.shape) for a in c.values())
-                      for c in slab), next(iter(slab[0].values())).dtype.name)
-
-    @staticmethod
-    def _slab_extra(slab):
-        return [[list(a.shape) for a in c.values()] for c in slab]
 
     # -- the programs' step functions --------------------------------------
     def _slot_step(self, params, slab, tok, pos):

@@ -629,8 +629,175 @@ def cache_write(cache, new, at, axis):
     )(at.astype(jnp.int32), cache, new)
 
 
+# -- a closing chunk's pooled key and value into a summary list, in place ------
+def _chunk_summary_kernel(at_ref, to_ref, closes_ref, k_ref, v_ref, phi_ref,
+                          mu_ref, sk_ref, sv_ref, sk_out, sv_out, *, per,
+                          tb, ts):
+    """A row's group of heads: the `per` buffer entries of the chunk
+    that holds position at[b], pooled with weights softmax(k . phi)
+    over them; where the row's chunk closes, the pooled key (+ mu) and
+    value replace entry to[b] of its summary block, else the block goes
+    back as it came. Everything is a select or a sum over a whole block
+    of lanes: no load or store has a dynamic lane."""
+    b = pl.program_id(0)
+    here = at_ref[b] % tb
+    lo = here - here % per
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tb), 1)
+    inside = (lane >= lo) & (lane < lo + per)
+    hit = ((jax.lax.broadcasted_iota(jnp.int32, sk_ref.shape[2:], 1)
+            == to_ref[b] % ts) & (closes_ref[b] != 0))
+    for h in range(k_ref.shape[1]):
+        k = k_ref[0, h].astype(jnp.float32)                  # [D, tb]
+        v = v_ref[0, h].astype(jnp.float32)
+        logit = jnp.where(inside, jnp.sum(k * phi_ref[h], axis=0,
+                                          keepdims=True), -1e30)
+        e = jnp.where(inside, jnp.exp(
+            logit - jnp.max(logit, axis=1, keepdims=True)), 0.0)
+        a = e / jnp.sum(e, axis=1, keepdims=True)            # [1, tb]
+        pk = jnp.sum(k * a, axis=1, keepdims=True) + mu_ref[h]   # [D, 1]
+        pv = jnp.sum(v * a, axis=1, keepdims=True)
+        sk_out[0, h] = jnp.where(hit, pk.astype(sk_out.dtype), sk_ref[0, h])
+        sv_out[0, h] = jnp.where(hit, pv.astype(sv_out.dtype), sv_ref[0, h])
+
+
+@functools.partial(jax.jit, static_argnames=("per",))
+def chunk_summary(k_buf, v_buf, sk, sv, phi, mu, at, to, closes, per):
+    """Row b's chunk of `per` buffer entries around position at[b] of
+    `k_buf`, `v_buf` [B, H, D, W] (aligned: entries at[b] - at[b] % per
+    ...), pooled over its positions with weights softmax_j(k_j . phi):
+    sum_j a_j k_j + mu and sum_j a_j v_j (`phi`, `mu` [H, D] float32),
+    written as entry to[b] of the summary lists `sk`, `sv` [B, H, D, R]
+    for the rows with `closes[b]`, IN PLACE: the results alias `sk` and
+    `sv` (donate them), a row whose chunk stays open keeps what its
+    entry held, and of each row only the 128-position blocks around
+    at[b] and to[b] cross VMEM. XLA's own gather of the chunk re-lays
+    the whole buffer first, positions major, and its gather of the held
+    entry the whole list (compiled for a described v5e, PR 35)."""
+    B, H, D, W = k_buf.shape
+    R = sk.shape[3]
+    tb, ts = min(128, W), min(128, R)
+    if W % tb or R % ts or tb % per:
+        raise ValueError(f"chunk_summary: a buffer of {W} and a list of {R} "
+                         f"entries in blocks of {tb} and {ts}, chunks of "
+                         f"{per}")
+    hb = 8 if H % 8 == 0 else H
+
+    def buf_map(b, g, at_ref, to_ref, closes_ref):
+        return b, g, 0, at_ref[b] // tb
+
+    def list_map(b, g, at_ref, to_ref, closes_ref):
+        return b, g, 0, to_ref[b] // ts
+
+    def head_map(b, g, *_):
+        return g, 0, 0
+
+    column = pl.BlockSpec((hb, D, 1), head_map)
+    return pl.pallas_call(
+        functools.partial(_chunk_summary_kernel, per=per, tb=tb, ts=ts),
+        out_shape=(jax.ShapeDtypeStruct(sk.shape, sk.dtype),
+                   jax.ShapeDtypeStruct(sv.shape, sv.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, H // hb),
+            in_specs=[pl.BlockSpec((1, hb, D, tb), buf_map),
+                      pl.BlockSpec((1, hb, D, tb), buf_map),
+                      column, column,
+                      pl.BlockSpec((1, hb, D, ts), list_map),
+                      pl.BlockSpec((1, hb, D, ts), list_map)],
+            out_specs=(pl.BlockSpec((1, hb, D, ts), list_map),
+                       pl.BlockSpec((1, hb, D, ts), list_map))),
+        input_output_aliases={7: 0, 8: 1},
+        name="chunk_summary",
+        interpret=_interpret(),
+    )(at.astype(jnp.int32), to.astype(jnp.int32), closes.astype(jnp.int32),
+      k_buf, v_buf, phi.astype(jnp.float32)[:, :, None],
+      mu.astype(jnp.float32)[:, :, None], sk, sv)
+
+
+# -- a block of a prompt over itself and a list of summaries, one softmax ------
+_BLOCK_ATTEND_TQ = 256   # queries a tile, and keys a pass over the block
+
+
+def _block_attend_kernel(seen_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref,
+                         o_ref, *, scale, tq, ts, prec):
+    """One head of one row, one tile of `tq` queries: the block's keys
+    up to the tile's own (whole tiles before it, the causal triangle on
+    it), then the first `seen` summaries `ts` at a time, folded into
+    one running softmax in float32; no score leaves VMEM."""
+    i = pl.program_id(1)
+    q = q_ref[0]
+
+    def fold(carry, kk, vv, mask):
+        m, l, acc = carry
+        s = _dot(q, kk, ((1,), (1,)), prec) * scale
+        if mask is not None:
+            s = jnp.where(mask, s, -1e30)
+        m2 = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
+        p, a = jnp.exp(s - m2), jnp.exp(m - m2)
+        return (m2, a * l + jnp.sum(p, -1, keepdims=True),
+                a * acc + _dot(p.astype(vv.dtype), vv, ((1,), (0,)), prec))
+
+    def keys(j, carry, mask=None):
+        at = pl.ds(pl.multiple_of(j * tq, tq), tq)
+        return fold(carry, k_ref[0, at], v_ref[0, at], mask)
+
+    def summaries(j, carry):
+        at = pl.ds(pl.multiple_of(j * ts, ts), ts)
+        col = j * ts + jax.lax.broadcasted_iota(jnp.int32, (tq, ts), 1)
+        return fold(carry, sk_ref[0, at], sv_ref[0, at], col < seen_ref[0])
+
+    carry = (jnp.full((tq, 1), -1e30, jnp.float32),
+             jnp.zeros((tq, 1), jnp.float32),
+             jnp.zeros((tq, q.shape[1]), jnp.float32))
+    carry = jax.lax.fori_loop(0, i, keys, carry)
+    row = jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 1)
+    carry = keys(i, carry, col <= row)
+    _, l, acc = jax.lax.fori_loop(0, pl.cdiv(seen_ref[0], ts), summaries,
+                                  carry)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def block_attend(q, k, v, sk, sv, seen, precision=None):
+    """Causal self-attention over ONE block of a prompt whose queries
+    also see, of everything before the block, one pooled key and value
+    a chunk of positions, all in one softmax: q, k, v [B, H, L, D] at
+    the block's L positions; sk, sv [B, H, R, D], a summary list of
+    which the first `seen` (a traced scalar) entries are those of the
+    earlier blocks -> [B, H, L, D]. The scores of a tile of queries
+    stay in VMEM and the tiles of keys behind a query's own are never
+    read, nor the summaries past `seen`: XLA's own softmax wrote every
+    [256, L + R] tile of scores to HBM and read it back three times,
+    37 % of a prefill (PERF.md, PR 35)."""
+    B, H, L, D = q.shape
+    R = sk.shape[2]
+    tq = min(_BLOCK_ATTEND_TQ, L)
+    ts = min(128, R)
+    if L % tq or R % ts:
+        raise ValueError(f"block_attend: a block of {L} positions and a "
+                         f"list of {R} summaries in tiles of {tq} and {ts}")
+
+    def whole(n):
+        return pl.BlockSpec((1, n, D), lambda b, i, seen_ref: (b, 0, 0))
+
+    tile = pl.BlockSpec((1, tq, D), lambda b, i, seen_ref: (b, i, 0))
+    out = pl.pallas_call(
+        functools.partial(_block_attend_kernel, scale=1.0 / (D ** 0.5),
+                          tq=tq, ts=ts,
+                          prec=_mxu_precision(q.dtype, precision)),
+        out_shape=jax.ShapeDtypeStruct((B * H, L, D), v.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B * H, L // tq),
+            in_specs=[tile, whole(L), whole(L), whole(R), whole(R)],
+            out_specs=tile),
+        name="block_attend",
+        interpret=_interpret(),
+    )(jnp.reshape(seen, (1,)).astype(jnp.int32),
+      *(t.reshape(B * H, t.shape[2], D) for t in (q, k, v, sk, sv)))
+    return out.reshape(B, H, L, D)
+
+
 # -- one query a row against the positions that row has written ---------------
-DECODE_ATTEND_BLOCK = 128   # positions a block: a lane tile, `cache_write`'s
+DECODE_ATTEND_BLOCK = 128  # positions a block: a lane tile, `cache_write`'s
 _DECODE_ATTEND_SLOTS = 4    # blocks in flight or in use at a time
 # the fewest blocks a rung must have for the kernel to be the faster
 # path (one block: nothing to skip)

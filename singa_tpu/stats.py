@@ -678,7 +678,8 @@ class _DecodeStats:
         # that hold a ring of window positions, layers that hold the
         # whole context, layers that hold a fixed-size state (these
         # three read 0 before any slab; `_note_slab_bytes` adds a
-        # further kind when a model states one)
+        # further kind when a model states one: `window` and `summary`
+        # are `ChunkedAttnLM`'s)
         self.cache_bytes = {"ring": 0, "context": 0, "state": 0}
 
     def reset(self) -> None:
@@ -709,6 +710,14 @@ class _DecodeStats:
         # over rows, layers and steps (0 and 0 on the `einsum` path)
         self.attn_blocks_read = 0
         self.attn_blocks_rung = 0
+        # `ChunkedAttnLM`'s: entries (exact keys of the query's own
+        # block and summaries of the earlier ones) the rows' queries
+        # needed, entries the slab's buffers and summary lists hold, and
+        # summaries the steps wrote (a row's chunk closed), each summed
+        # over rows, layers and steps
+        self.attn_entries_needed = 0
+        self.attn_entries_held = 0
+        self.chunk_summaries_written = 0
         # KV migration (ISSUE 17). `migrated` counts sessions exported
         # off this engine's books (each decrements `sessions` too, so
         # the 4-equation reconciliation stays exact per engine: the
@@ -739,6 +748,9 @@ class _DecodeStats:
             "moe_expert_load_max": self.moe_expert_load_max,
             "attn_blocks_read": self.attn_blocks_read,
             "attn_blocks_rung": self.attn_blocks_rung,
+            "attn_entries_needed": self.attn_entries_needed,
+            "attn_entries_held": self.attn_entries_held,
+            "chunk_summaries_written": self.chunk_summaries_written,
             "migrated": self.migrated,
             "resumed": self.resumed,
             "slots": self.slots,

@@ -19,6 +19,10 @@ here for each form alike, at toy widths on the CPU:
                       (its prefill path)
   shortconv           `ShortConvMoELM`: convolution states beside
                       contexts, every expert held (the lfm2 cell)
+  chunked             `ChunkedAttnLM`: window buffers of 8 positions
+                      beside 2-to-1 chunk summaries, which are what
+                      climbs the ladder (the evabyte cell); the rows
+                      cross a block boundary inside every test
 
 A form that cannot meet a point says so as a skipped case, with the
 model's own reason. The next decode-tier model adds one row to FORMS.
@@ -27,6 +31,7 @@ import numpy as np
 import pytest
 
 from singa_tpu import device, serve, stats, tensor
+from singa_tpu.models.chunked_attn import ChunkedAttnLM
 from singa_tpu.models.hybrid_moe import HybridWindowMoELM
 from singa_tpu.models.shortconv_moe import ShortConvMoELM
 from singa_tpu.models.transformer import TransformerLM
@@ -35,7 +40,7 @@ V, D = 64, 32
 MAXLEN = 64
 WINDOW = 4
 FORMS = ["lm-layernorm-tied", "lm-rmsnorm-untied", "lm-int8",
-         "hybrid-dense", "hybrid-sorted", "shortconv"]
+         "hybrid-dense", "hybrid-sorted", "shortconv", "chunked"]
 
 
 @pytest.fixture(autouse=True)
@@ -57,11 +62,22 @@ class Form:
         self.name = name
         # the models drawn on the device (`DrawnDecodeLM`): a slab of
         # more than one kind of entry, slots first in every leaf
-        self.hybrid = name.startswith(("hybrid", "shortconv"))
+        self.hybrid = name.startswith(("hybrid", "shortconv", "chunked"))
         self.int8 = name == "lm-int8"
-        # what does not climb the ladder beside the contexts
-        self.fixed_kind = {"hybrid": "ring", "shortc": "state"}.get(name[:6])
-        if name == "shortconv":
+        # what does not climb the ladder, what does, and how many
+        # positions an entry of what does stands for
+        self.fixed_kind = {"hybrid": "ring", "shortc": "state",
+                           "chunke": "window"}.get(name[:6])
+        self.grows, self.per = (("summary", 2) if name == "chunked"
+                                else ("context", 1))
+        if name == "chunked":
+            # no axis of its slab is a contract rung (16, 32) but the
+            # summary list's on the rung twice as long
+            m = ChunkedAttnLM(
+                V, d_model=48, num_heads=4, head_dim=12, window=2 * WINDOW,
+                chunk=2, num_layers=2, d_ff=64, pred_heads=2,
+                max_len=MAXLEN, init_std=0.3)
+        elif name == "shortconv":
             # d_model 48: the contract's rungs (16, 32) are no axis of
             # a state [slots, 2, d_model]
             m = ShortConvMoELM(
@@ -328,10 +344,10 @@ def test_slab_bytes_are_the_leaves_and_growth_keeps_what_was_written(form):
     lg, slab = step(form, slab, tok, pos)
     tok, pos = lg.argmax(-1).astype(np.int32), pos + 1
     by_kind = form.m.slab_bytes(slab)
-    assert set(by_kind) == {form.fixed_kind or "ring", "context"}
+    assert set(by_kind) == {form.fixed_kind or "ring", form.grows}
     assert sum(by_kind.values()) == sum(
         leaf.size * leaf.dtype.itemsize for leaf in leaves(slab))
-    assert by_kind["context"] > 0
+    assert by_kind[form.grows] > 0
     assert (by_kind.get(form.fixed_kind, 0) > 0) == form.hybrid
     assert form.m.slab_dims(slab) == (3, 16)
     small = host(slab)
@@ -345,8 +361,9 @@ def test_slab_bytes_are_the_leaves_and_growth_keeps_what_was_written(form):
             continue
         longer += 1
         (axis,) = [i for i in range(a.ndim) if a.shape[i] != b.shape[i]]
-        assert (a.shape[axis], b.shape[axis]) == (16, 32)
-        head, tail = np.split(b, [16], axis=axis)
+        assert (a.shape[axis], b.shape[axis]) == (16 // form.per,
+                                                  32 // form.per)
+        head, tail = np.split(b, [16 // form.per], axis=axis)
         assert np.array_equal(head, a) and not tail.any()
     assert longer and (longer < len(small)) == form.hybrid
     on_grown, _ = step(form, grown, tok, pos)
@@ -525,7 +542,8 @@ def _stream(eng, requests):
 
 @pytest.mark.parametrize("block", [1, 8])
 @pytest.mark.parametrize(
-    "form", ["lm-layernorm-tied", "hybrid-dense", "shortconv"], indirect=True)
+    "form", ["lm-layernorm-tied", "hybrid-dense", "shortconv", "chunked"],
+    indirect=True)
 def test_greedy_streams_are_the_same_by_tokens_and_by_logits(
         form, block, monkeypatch):
     """Through `ServingEngine`, `decode_block` 1 and the default.

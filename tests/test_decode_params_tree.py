@@ -196,14 +196,33 @@ def _build_shortconv(seed=3):
     return m
 
 
+def _build_chunked(seed=3):
+    """The chunk-summary LM at a toy size: its tree holds the pooling
+    parameters phi and mu beside the norms' g, and a head of its own."""
+    from singa_tpu.models.chunked_attn import ChunkedAttnLM
+
+    dev = device.get_default_device()
+    dev.SetRandSeed(seed)
+    m = ChunkedAttnLM(V, d_model=D, num_heads=4, head_dim=8, window=4,
+                      chunk=2, num_layers=2, d_ff=64, pred_heads=2,
+                      max_len=MAXLEN, init_std=0.3)
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32),
+                                 device=dev)],
+              is_train=False, use_graph=False)
+    m.eval()
+    return m
+
+
 @pytest.mark.parametrize("norm,quant", [("layer", "off"),
                                         ("layer", "int8"),
                                         ("rms", "off"),
                                         ("hybrid", "off"),
-                                        ("shortconv", "off")])
+                                        ("shortconv", "off"),
+                                        ("chunked", "off")])
 def test_warmed_engine_counts_no_host_leaf(norm, quant):
-    m = ({"hybrid": _build_hybrid, "shortconv": _build_shortconv}[norm]()
-         if norm in ("hybrid", "shortconv") else _build(norm))
+    drawn = {"hybrid": _build_hybrid, "shortconv": _build_shortconv,
+             "chunked": _build_chunked}
+    m = drawn[norm]() if norm in drawn else _build(norm)
     prompt = np.array([[3, 1, 4]], np.int32)
     device.set_inference_quant(quant)
     dst = stats.decode_stats()
@@ -217,7 +236,7 @@ def test_warmed_engine_counts_no_host_leaf(norm, quant):
         eng.stop()
         device.set_inference_quant("off")
     assert stats.cache_stats()["decode"]["host_leaves_per_call"] == 0
-    if norm in ("hybrid", "shortconv"):
+    if norm in drawn:
         import jax
 
         assert all(isinstance(leaf, jax.Array) for leaf in

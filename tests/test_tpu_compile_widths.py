@@ -223,20 +223,28 @@ def _lfm2_moves(model, slab, text):
     return moves
 
 
-def _lfm2_program(model, params, slab, sds, program, monkeypatch):
-    """The executable `ServingEngine` would get for `program`."""
+def _engine_program(model, params, slab, sds, monkeypatch, slots, program,
+                    prefill=None):
+    """The executable `ServingEngine` would get for `program`: "step",
+    "block<k>", or a prefill of `prefill` = (rows, bucket)."""
     lowered = _as_served(model, monkeypatch)
-    vec = sds((SLOTS,), np.int32)
+    vec = sds((slots,), np.int32)
     if program == "step":
         model.decode_step(params, slab, vec, vec)
     elif program.startswith("block"):
         model.decode_scan(params, slab, vec, vec, int(program[5:]))
     else:
-        rows = int(program[7:])
-        model.prefill_slab(params, slab, sds((rows, LFM2_BUCKET), np.int32),
+        rows, bucket = prefill
+        model.prefill_slab(params, slab, sds((rows, bucket), np.int32),
                            sds((rows,), np.int32), sds((rows,), np.int32))
     (compiled,) = lowered
     return compiled
+
+
+def _lfm2_program(model, params, slab, sds, program, monkeypatch):
+    return _engine_program(
+        model, params, slab, sds, monkeypatch, SLOTS, program,
+        (int(program[7:]), LFM2_BUCKET) if program[:7] == "prefill" else None)
 
 
 @pytest.mark.parametrize("program,temporaries", [
@@ -405,7 +413,8 @@ def test_gpt2_programs_move_no_whole_layer_but_the_write(gpt2, slots, rung,
 # -- the token program of a single greedy step (ISSUE 33) ----------------------
 @pytest.mark.parametrize("cell_", ["mimo-v2.5-serve-mixedlen",
                                    "lfm2-24b-a2b-serve-decode128",
-                                   "gpt2-serve-decode", "gpt2-serve-short"])
+                                   "gpt2-serve-decode", "gpt2-serve-short",
+                                   "evabyte-serve-longctx32"])
 def test_the_token_program_is_the_step_and_an_argmax(request, cell_,
                                                      monkeypatch):
     """`decode_scan(k=1)`, what `ServingEngine` dispatches for a single
@@ -429,9 +438,10 @@ def test_the_token_program_is_the_step_and_an_argmax(request, cell_,
             jax.eval_shape(lambda: model.new_slab(params, slots, rung, None)))
         del compiled[:]
     else:
-        model, params, slab, sds = request.getfixturevalue(
-            "lfm2" if cell_.startswith("lfm2") else "served")
-        slots, compiled = SLOTS, _as_served(model, monkeypatch)
+        fixture = {"lfm2": "lfm2", "evab": "evabyte"}.get(cell_[:4], "served")
+        model, params, slab, sds = request.getfixturevalue(fixture)
+        slots = EVA_SLOTS if fixture == "evabyte" else SLOTS
+        compiled = _as_served(model, monkeypatch)
     vec = sds((slots,), np.int32)
     model.decode_step(params, slab, vec, vec)
     model.decode_scan(params, slab, vec, vec, 1)
@@ -501,3 +511,44 @@ def test_the_one_chip_resnet_step_holds_what_the_memory_meter_misses(
         pallas_kernels.enable(saved[2])
     m = _fits(compiled, f"ResNet-50 training step of {B}")
     assert m.temp_size_in_bytes > 0.25 * 16 * 2**30
+
+
+# -- EvaByte's served programs at their real widths (ISSUE 35) -----------------
+EVABYTE = os.path.join(os.path.dirname(CONFIG), "evabyte.json")
+EVA_SLOTS, EVA_RUNG = 32, 16384
+
+
+@pytest.fixture(scope="module")
+def evabyte(one_chip):
+    """`evabyte-serve-longctx32`'s geometry: 32 slots on the 16,384
+    rung, one-prompt prefills up to the 16,384 bucket."""
+    yield from _served(EVABYTE, EVA_SLOTS, EVA_RUNG, one_chip)
+
+
+@pytest.mark.parametrize("program,temporaries", [
+    ("step", 0.05e9), ("block1", 0.05e9), ("block8", 0.1e9),
+    ("prefill2048", 0.6e9), ("prefill16384", 1.0e9)])
+def test_evabyte_programs_hold_the_slab_in_place(evabyte, program,
+                                                 temporaries, monkeypatch):
+    """The fused step, the token program, a run-ahead block of 8 and
+    the one-prompt prefill of the shortest and of the longest bucket,
+    over 2.45 GB of weights and the 9.66 GB slab (six layers of 32
+    window buffers and 32 summary lists): each fits 16 GB, the slab is
+    aliased whole, no operation moves a whole buffer or a whole summary
+    list of a layer, and the temporaries are what is stated (compiled
+    for a described v5e; no chip, no device metric)."""
+    model, params, slab, sds = evabyte
+    compiled = _engine_program(
+        model, params, slab, sds, monkeypatch, EVA_SLOTS, program,
+        (1, int(program[7:])) if program[:7] == "prefill" else None)
+    m = _fits(compiled, f"EvaByte {program}")
+    by_kind = model.slab_bytes(slab)
+    assert by_kind == {"window": 6 * 32 * 2 * 32 * 128 * 2048 * 2,
+                       "summary": 6 * 32 * 2 * 32 * 128 * 1024 * 2}
+    assert m.alias_size_in_bytes >= sum(by_kind.values())
+    assert m.temp_size_in_bytes < temporaries
+    text = compiled.as_text()
+    # the smaller of a layer's arrays: a summary list
+    assert not _whole_layer_moves(text, int(np.prod(slab[0]["sk"].shape)))
+    if program == "block1":
+        assert " while(" not in text
