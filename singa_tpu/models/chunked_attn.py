@@ -38,7 +38,14 @@ boundary on. A decode step writes its key and value into the buffer,
 attends, and then, for the rows whose chunk closes at this position,
 pools the buffer's last C entries and writes their summary: a second,
 conditional write a row (the rows whose chunk stays open write back
-what the entry held), in place in the donated slab. A prefill writes
+what the entry held), in place in the donated slab. What the step's
+attention READS of row b: where buffer and list are whole numbers of
+128-entry blocks (the buffer two or more), the buffer's blocks 0 ..
+(pos[b] mod W) // 128 and the list's blocks 0 .. ceil(seen / 128) - 1
+and nothing else of either (`window_summary_attend`, a kernel that
+takes the four arrays where they lie; the step counts the entries as
+`attn_entries_read`); at any other widths (the toys') every buffer and
+every list whole, through two `einsum`s. The shapes alone decide. A prefill writes
 the summaries of the chunks complete among a row's REAL positions
 (zero for the rest of its bucket) and the buffer from the row's last
 real block, whatever the bucket padded.
@@ -60,7 +67,7 @@ class ChunkedAttnLM(DrawnDecodeLM):
     # v5e, `tests/test_tpu_compile_widths.py`)
     scan_unroll = 1
     step_counter_names = ("attn_entries_needed", "attn_entries_held",
-                          "chunk_summaries_written")
+                          "chunk_summaries_written", "attn_entries_read")
     _slab_words = "window buffers and chunk summaries"
     _training_lacks = ("with no backward for attention over chunk "
                        "summaries or rotary positions, and no optimizer "
@@ -344,40 +351,56 @@ class ChunkedAttnLM(DrawnDecodeLM):
         """One fused decode step over every slot at per-row positions.
         Row b writes its key and value at pos[b] mod W of its buffer
         (`cache_write`), attends the buffer's entries 0 .. pos[b] mod W
-        and the summaries of the earlier blocks in one softmax, and,
-        where its chunk closes ((pos[b] + 1) mod C == 0), pools the
-        buffer's last C entries into summary pos[b] // C. Returns
-        (logits [B, V], new slab, counters [3])."""
+        and the first `_seen_summaries(pos[b])` entries of its list
+        (those of the earlier blocks) in one softmax, and, where its
+        chunk closes ((pos[b] + 1) mod C == 0), pools the buffer's last
+        C entries into summary pos[b] // C. The attention reads of a
+        row the 128-entry blocks that hold those entries and masks a
+        block's tail entry by entry (`window_summary_attend`) wherever
+        `window_summary_blocks` cuts the slab's buffer and list into
+        blocks; elsewhere two `einsum`s read both whole. Returns
+        (logits [B, V], new slab, counters [4]: entries needed, held,
+        summaries written, entries read)."""
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pallas_kernels import cache_write, chunk_summary
+        from ..ops.pallas_kernels import (cache_write, chunk_summary,
+                                          window_summary_attend,
+                                          window_summary_blocks,
+                                          window_summary_entries_read)
 
         prec = tensor.get_matmul_precision()
         W, C = self.window, self.chunk
+        R = slab[0]["sk"].shape[3]
         at, closes = pos % W, (pos + 1) % C == 0
         seen = self._seen_summaries(pos)
-        scale = 1.0 / float(np.sqrt(self.head_dim))
+        blocks, _ = window_summary_blocks(W, R)
         new = [None] * len(slab)
 
         def attend(li, q, k, v, phi, mu):
             c = slab[li]
-            R = c["sk"].shape[3]
             with jax.named_scope("attn_chunked"):
                 k_all = cache_write(c["k"], k[:, 0], at, axis=3)
                 v_all = cache_write(c["v"], v[:, 0], at, axis=3)
-                s = jnp.concatenate([
-                    jnp.einsum("bhd,bhdt->bht", q[:, 0], t, precision=prec,
-                               preferred_element_type=jnp.float32)
-                    for t in (k_all, c["sk"])], -1) * scale
-                mask = jnp.concatenate([
-                    jnp.arange(W)[None, :] <= at[:, None],
-                    jnp.arange(R)[None, :] < seen[:, None]], -1)
-                p = softmax_probs(s, mask[:, None, :], None).astype(v.dtype)
-                o = sum(jnp.einsum("bht,bhdt->bhd", pt, t, precision=prec,
+                if blocks:
+                    o = window_summary_attend(q[:, 0], k_all, v_all, c["sk"],
+                                              c["sv"], at, seen)
+                else:
+                    s = jnp.concatenate([
+                        jnp.einsum("bhd,bhdt->bht", q[:, 0], t,
+                                   precision=prec,
                                    preferred_element_type=jnp.float32)
-                        for pt, t in ((p[..., :W], v_all),
-                                      (p[..., W:], c["sv"])))
+                        for t in (k_all, c["sk"])], -1) / float(
+                            np.sqrt(self.head_dim))
+                    mask = jnp.concatenate([
+                        jnp.arange(W)[None, :] <= at[:, None],
+                        jnp.arange(R)[None, :] < seen[:, None]], -1)
+                    p = softmax_probs(s, mask[:, None, :],
+                                      None).astype(v.dtype)
+                    o = sum(jnp.einsum("bht,bhdt->bhd", pt, t, precision=prec,
+                                       preferred_element_type=jnp.float32)
+                            for pt, t in ((p[..., :W], v_all),
+                                          (p[..., W:], c["sv"])))
             with jax.named_scope("chunk_summary"):
                 sk, sv = chunk_summary(
                     k_all, v_all, c["sk"], c["sv"], phi, mu, at,
@@ -387,10 +410,11 @@ class ChunkedAttnLM(DrawnDecodeLM):
 
         h = self._stack(params, tok[:, None], pos[:, None], attend)
         L = self.num_layers
+        held = pos.shape[0] * (W + R)
+        read = window_summary_entries_read(at, seen, W, R) if blocks else held
         counters = jnp.stack([
-            L * jnp.sum(at + 1 + seen),
-            jnp.asarray(L * pos.shape[0] * (W + slab[0]["sk"].shape[3])),
-            L * jnp.sum(closes)]).astype(jnp.int32)
+            L * jnp.sum(at + 1 + seen), jnp.asarray(L * held),
+            L * jnp.sum(closes), L * read]).astype(jnp.int32)
         return self._head(params, h[:, 0]), new, counters
 
     def _prefill_rows(self, params, slab, ids, n_real, slots):

@@ -961,3 +961,243 @@ def decode_attend(layer, q, pos, scale):
     )(pos.astype(jnp.int32), jnp.swapaxes(q, 1, 2).astype(jnp.float32),
       layer)
     return jnp.swapaxes(out, 1, 2)
+
+
+# -- one query a row against its window buffer and the summaries it sees -------
+_WINDOW_ATTEND_SLOTS = 4    # blocks in flight or in use at a time
+_WINDOW_ATTEND_HEADS = 8    # heads a pass of the loop: a tile of score rows
+
+
+def window_summary_blocks(W, R):
+    """(blocks of a window buffer of `W` positions, blocks of a summary
+    list of `R` entries) as `window_summary_attend` cuts them; (0, 0)
+    where buffer and list are read whole instead (either is no whole
+    number of blocks, or the buffer has fewer than
+    `DECODE_ATTEND_MIN_BLOCKS`: nothing to skip)."""
+    tb = DECODE_ATTEND_BLOCK
+    if W % tb or R % tb or not R or W // tb < DECODE_ATTEND_MIN_BLOCKS:
+        return 0, 0
+    return W // tb, R // tb
+
+
+def _window_rows(at, seen, W, R):
+    """A row's lengths clamped to its arrays, and the blocks of its
+    buffer and of its list that hold them: (at, seen, buffer blocks,
+    list blocks), each [B] int32. Never past either array, whatever
+    `at` and `seen` say."""
+    tb = DECODE_ATTEND_BLOCK
+    at = jnp.clip(at, 0, W - 1).astype(jnp.int32)
+    seen = jnp.clip(seen, 0, R).astype(jnp.int32)
+    return at, seen, at // tb + 1, (seen + tb - 1) // tb
+
+
+def window_summary_entries_read(at, seen, W, R):
+    """Entries of the blocks `window_summary_attend` fetches for rows
+    at `at`, `seen` [B] (keys; as many values), summed over the rows."""
+    _, _, n, ns = _window_rows(at, seen, W, R)
+    return DECODE_ATTEND_BLOCK * jnp.sum(n + ns)
+
+
+def _window_summary_attend_kernel(at_ref, seen_ref, n_ref, ns_ref, q_ref,
+                                  k_ref, v_ref, sk_ref, sv_ref, out_ref, buf,
+                                  sem, qb, s_scr, acc, *, scale, neg, tb,
+                                  nslots):
+    """Every row in turn: its buffer's key blocks 0..n[b]-1, its list's
+    key blocks 0..ns[b]-1 (scores into `s_scr`), one exact softmax over
+    the entries 0..at[b] and the first seen[b] summaries, the same
+    blocks of the two value arrays (sum into `acc`), its output. The
+    blocks come from HBM by DMAs of the kernel's own, `nslots - 1`
+    ahead of the one in use and straight on from one row's last block
+    to the next row's first, whichever of the four arrays holds it: a
+    row costs the blocks it has and nothing a grid step. All arithmetic
+    is float32 on the vector unit, a tile of `hb` heads a pass of a
+    loop: q is spread over the lanes once a row (`qb`), a key block is
+    multiplied by it and summed over D, a value block by its row of
+    probabilities (rounded to the values' dtype first) and summed over
+    the lanes at the row's end."""
+    B, H, D = q_ref.shape
+    nblk, G, hb, _ = s_scr.shape
+    nbuf = k_ref.shape[3] // tb
+    sources = (k_ref, sk_ref, v_ref, sv_ref)
+
+    def blocks(b):
+        # of a row's buffer and of its list; the cursor that runs ahead
+        # of the last row reads nothing past it
+        b = jnp.minimum(b, B - 1)
+        return n_ref[b], ns_ref[b]
+
+    def item(b, j):
+        # item j of row b: buffer keys, list keys, buffer values, list
+        # values -> (is a value, is of the list, block of its array)
+        n, ns = blocks(b)
+        val = j >= n + ns
+        j = j - jnp.where(val, n + ns, 0)
+        lst = j >= n
+        return val, lst, j - jnp.where(lst, n, 0)
+
+    def dma(src, b, blk, slot):
+        at = pl.multiple_of(blk * tb, tb)
+        return pltpu.make_async_copy(
+            src.at[b, :, :, pl.ds(at, tb)], buf.at[slot], sem.at[slot])
+
+    def after(b, j):
+        n, ns = blocks(b)
+        last = j + 1 == 2 * (n + ns)
+        return jnp.where(last, b + 1, b), jnp.where(last, 0, j + 1)
+
+    total = jax.lax.fori_loop(
+        0, B, lambda b, n: n + 2 * sum(blocks(b)), jnp.int32(0))
+
+    def heads(body):
+        jax.lax.fori_loop(0, G, lambda g, _: body(g) or 0, 0)
+
+    def spread(b):
+        qt = q_ref[b].T                                     # [D, H]
+        for h in range(H):
+            qb[h] = jnp.broadcast_to(qt[:, h:h + 1], (D, tb))
+
+    def keys(slot, row):
+        def tile(g):
+            s_scr[row, g] = jnp.concatenate([
+                jnp.sum(buf[slot, g * hb + i].astype(jnp.float32)
+                        * qb[g * hb + i], axis=0, keepdims=True)
+                for i in range(hb)], 0)
+        heads(tile)
+
+    def softmax(b):
+        s = s_scr[...] * scale                          # [nblk, G, hb, tb]
+        blk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
+        local = blk < nbuf
+        where = jnp.where(local, blk, blk - nbuf) * tb + lane
+        s = jnp.where(
+            where <= jnp.where(local, at_ref[b], seen_ref[b] - 1), s, neg)
+        m = jnp.max(jnp.max(s, axis=3, keepdims=True), axis=0,
+                    keepdims=True)
+        e = jnp.exp(s - m)
+        p = e / jnp.sum(jnp.sum(e, axis=3, keepdims=True), axis=0,
+                        keepdims=True)
+        s_scr[...] = p.astype(buf.dtype).astype(jnp.float32)
+        acc[...] = jnp.zeros_like(acc)
+
+    def values(slot, row):
+        def tile(g):
+            p = s_scr[row, g]                               # [hb, tb]
+            for i in range(hb):
+                h = g * hb + i
+                acc[h] += buf[slot, h].astype(jnp.float32) * p[i:i + 1]
+        heads(tile)
+
+    def result(b):
+        lane = jax.lax.broadcasted_iota(jnp.int32, (D, H), 1)
+
+        def tile(g, o):
+            for i in range(hb):
+                h = g * hb + i
+                o = jnp.where(lane == h,
+                              jnp.sum(acc[h], axis=1, keepdims=True), o)
+            return o
+
+        out_ref[b] = jax.lax.fori_loop(
+            0, G, tile, jnp.zeros((D, H), jnp.float32)).T
+
+    def start(i, cur):
+        b, j = cur
+
+        @pl.when(i < total)
+        def _():
+            val, lst, blk = item(b, j)
+            kind = 2 * val.astype(jnp.int32) + lst.astype(jnp.int32)
+            for which, src in enumerate(sources):
+                @pl.when(kind == which)
+                def _():
+                    dma(src, b, blk, jax.lax.rem(i, nslots)).start()
+
+        return after(b, j)
+
+    def step(i, carry):
+        (b, j), ahead = carry
+        slot = jax.lax.rem(i, nslots)
+        dma(k_ref, 0, 0, slot).wait()      # whichever array it came from
+        half = sum(blocks(b))
+        val, lst, blk = item(b, j)
+        row = blk + jnp.where(lst, nbuf, 0)
+
+        @pl.when(j == 0)
+        def _():
+            spread(b)
+
+        @pl.when(jnp.logical_not(val))
+        def _():
+            keys(slot, row)
+
+        @pl.when(j == half - 1)
+        def _():
+            softmax(b)
+
+        @pl.when(val)
+        def _():
+            values(slot, row)
+
+        @pl.when(j == 2 * half - 1)
+        def _():
+            result(b)
+
+        # the slot just read is the one the next DMA fills
+        return after(b, j), start(i + nslots, ahead)
+
+    zero = (jnp.int32(0), jnp.int32(0))
+    ahead = zero
+    for i in range(nslots):
+        ahead = start(jnp.int32(i), ahead)
+    jax.lax.fori_loop(0, total, step, (zero, ahead))
+
+
+@jax.jit
+def window_summary_attend(q, k_buf, v_buf, sk, sv, at, seen):
+    """One softmax a row over its window buffer's entries 0..at[b] and
+    the first seen[b] entries of its summary list: softmax([q . K,
+    q . SK] / sqrt(D)) . [V, SV]. `q` [B, H, D]; `k_buf`, `v_buf` [B,
+    H, D, W] and `sk`, `sv` [B, H, D, R] as the slab stores them
+    (positions last, any float dtype); `at`, `seen` [B] int32 -> [B, H,
+    D] float32. Of row b only the buffer's blocks 0..at[b] // 128 and
+    the list's blocks 0..ceil(seen[b] / 128) - 1 leave HBM; a block's
+    tail beyond `at[b]` or `seen[b]` is masked entry by entry. Scores,
+    softmax and both weighted sums are float32 whatever the matmul
+    policy says (nothing here runs on the matrix unit); the
+    probabilities are rounded to the values' dtype before they weigh
+    the values. The two `einsum`s this stands for read every buffer and
+    every list whole: 9.66 GB a step where the rows of
+    `evabyte-serve-longctx32` need 36 % of them (PERF.md, PR 36)."""
+    B, H, D, W = k_buf.shape
+    R = sk.shape[3]
+    tb = DECODE_ATTEND_BLOCK
+    nbuf, nlist = window_summary_blocks(W, R)
+    if not nbuf:
+        raise ValueError(f"window_summary_attend: a buffer of {W} and a "
+                         f"list of {R} entries do not divide into blocks of "
+                         f"{tb}, or the buffer has fewer than "
+                         f"{DECODE_ATTEND_MIN_BLOCKS}")
+    nslots = _WINDOW_ATTEND_SLOTS
+    hb = _WINDOW_ATTEND_HEADS if H % _WINDOW_ATTEND_HEADS == 0 else H
+    return pl.pallas_call(
+        functools.partial(_window_summary_attend_kernel,
+                          scale=1.0 / (D ** 0.5),
+                          neg=float(jnp.finfo(jnp.float32).min / 2),
+                          tb=tb, nslots=nslots),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 4,
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((nslots, H, D, tb), k_buf.dtype),
+                pltpu.SemaphoreType.DMA((nslots,)),
+                pltpu.VMEM((H, D, tb), jnp.float32),
+                pltpu.VMEM((nbuf + nlist, H // hb, hb, tb), jnp.float32),
+                pltpu.VMEM((H, D, tb), jnp.float32)]),
+        name="window_summary_attend",
+        interpret=_interpret(),
+    )(*_window_rows(at, seen, W, R), q.astype(jnp.float32),
+      k_buf, v_buf, sk, sv)

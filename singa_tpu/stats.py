@@ -712,10 +712,13 @@ class _DecodeStats:
         self.attn_blocks_rung = 0
         # `ChunkedAttnLM`'s: entries (exact keys of the query's own
         # block and summaries of the earlier ones) the rows' queries
-        # needed, entries the slab's buffers and summary lists hold, and
-        # summaries the steps wrote (a row's chunk closed), each summed
-        # over rows, layers and steps
+        # needed, entries of the 128-entry blocks its attention read
+        # (all that are held on the `einsum` path), entries the slab's
+        # buffers and summary lists hold, and summaries the steps wrote
+        # (a row's chunk closed), each summed over rows, layers and
+        # steps: needed <= read <= held
         self.attn_entries_needed = 0
+        self.attn_entries_read = 0
         self.attn_entries_held = 0
         self.chunk_summaries_written = 0
         # KV migration (ISSUE 17). `migrated` counts sessions exported
@@ -749,6 +752,7 @@ class _DecodeStats:
             "attn_blocks_read": self.attn_blocks_read,
             "attn_blocks_rung": self.attn_blocks_rung,
             "attn_entries_needed": self.attn_entries_needed,
+            "attn_entries_read": self.attn_entries_read,
             "attn_entries_held": self.attn_entries_held,
             "chunk_summaries_written": self.chunk_summaries_written,
             "migrated": self.migrated,

@@ -399,6 +399,7 @@ def test_c_through_the_engine_sessions_stream_what_the_reference_picks(
     assert d["attn_entries_held"] == (LAYERS * 2 * (W + rung // C)
                                       * d["decode_steps"])
     assert 0 < d["attn_entries_needed"] < d["attn_entries_held"]
+    assert d["attn_entries_read"] == d["attn_entries_held"]   # the einsums
     assert d["chunk_summaries_written"] > 0
     for (prompt, n), got in zip(requests, in_turn):
         assert len(got) == len(prompt) + n
@@ -466,12 +467,15 @@ def test_e_a_planted_fault_fails_the_comparison(fault, monkeypatch):
 
 
 # -- (f) the counters' arithmetic ----------------------------------------------
-def test_f_the_three_counters_over_a_whole_window(model):
+def test_f_the_four_counters_over_a_whole_window(model):
     """Two rows decode a whole window of W positions each from
     different phases: a row's chunk closes every C-th step, so
     chunk_summaries_written / tokens / layers = 1 / C exactly; the
     entries needed are (p mod W + 1) + (W / C)(p // W) a row-layer, the
-    entries held rows x layers x (W + rung / C) a step."""
+    entries held rows x layers x (W + rung / C) a step; at these widths
+    (a window of 32: no whole block of 128) the two `einsum`s read
+    every buffer and every list whole, so the entries read are the
+    entries held."""
     m = model
     starts = np.array([W + 3, 5], np.int32)
     rows = [ids_of((n,), seed=n) for n in starts]
@@ -488,11 +492,13 @@ def test_f_the_three_counters_over_a_whole_window(model):
     assert total["chunk_summaries_written"] * C == 2 * W * LAYERS
     assert total["attn_entries_needed"] == need
     assert total["attn_entries_held"] == W * 2 * LAYERS * (W + 128 // C)
+    assert total["attn_entries_read"] == total["attn_entries_held"]
     # a block's counters are its steps' sums
     m.decode_scan(m._decode_params(), slab, put(np.array([1, 2], np.int32)),
                   put(starts + W), 4)
     block = m.take_step_counters()
     assert block["attn_entries_held"] == 4 * 2 * LAYERS * (W + 128 // C)
+    assert block["attn_entries_read"] == block["attn_entries_held"]
     assert block["chunk_summaries_written"] == 2 * LAYERS
 
 
@@ -564,7 +570,8 @@ def test_g_the_base_split_left_the_routed_models_as_they_were(which):
     assert m.scan_unroll is True
     assert not issubclass(ChunkedAttnLM, RoutedDrawnLM)
     assert ChunkedAttnLM.step_counter_names == (
-        "attn_entries_needed", "attn_entries_held", "chunk_summaries_written")
+        "attn_entries_needed", "attn_entries_held", "chunk_summaries_written",
+        "attn_entries_read")
 
 
 @pytest.mark.parametrize("L,R,seen", [
@@ -598,3 +605,271 @@ def test_h_block_attend_is_one_softmax_over_the_block_and_the_seen_summaries(
     want = jnp.einsum("bhqk,bhkd->bhqd", p, jnp.concatenate([v, sv], 2))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=0, atol=2e-5)
+
+
+# -- (i) the decode step's length-aware attention (ISSUE 36) -----------------
+KW, KLAYERS = 256, 2     # a window of two 128-entry blocks: the kernel's path
+
+
+def _plain_attend(q, k, v, sk, sv, at, seen, round_to=None):
+    """The formula `window_summary_attend` stands for, every buffer and
+    every list read whole: one softmax over a row's buffer entries
+    0..at and its first `seen` summaries, float32 at "highest"; the
+    probabilities rounded to `round_to` (the values' dtype) before
+    they weigh the values."""
+    import jax
+    import jax.numpy as jnp
+
+    Wk, R = k.shape[3], sk.shape[3]
+    f32 = [t.astype(jnp.float32) for t in (q, k, v, sk, sv)]
+    q, k, v, sk, sv = f32
+    s = jnp.concatenate([jnp.einsum("bhd,bhdt->bht", q, t,
+                                    precision="highest")
+                         for t in (k, sk)], -1) / np.sqrt(q.shape[-1])
+    mask = jnp.concatenate([jnp.arange(Wk)[None, :] <= at[:, None],
+                            jnp.arange(R)[None, :] < seen[:, None]], -1)
+    p = jax.nn.softmax(jnp.where(mask[:, None], s, -jnp.inf), -1)
+    if round_to is not None:
+        p = p.astype(round_to).astype(jnp.float32)
+    return jnp.einsum("bht,bhdt->bhd", p, jnp.concatenate([v, sv], -1),
+                      precision="highest")
+
+
+def _kernel_alone(R, dtype):
+    """The kernel against the plain formula over buffers and lists full
+    of random entries, so whatever lies past `at` or `seen` (a block's
+    tail; the blocks a row no longer holds: what the last block, or the
+    slot's last session, left there) would show: rows at `at` = 0, 127,
+    128 and W - 1 and one inside each block, `seen` = 0, 128, all R,
+    and two that are no multiple of 128 (what the planted
+    `summaries_seen_early` asks for)."""
+    from singa_tpu.ops.pallas_kernels import window_summary_attend
+
+    rng = np.random.default_rng(R)
+    at = np.array([0, 127, 128, KW - 1, 5, 200], np.int32)
+    seen = np.array([0, 128, R, 37, R - 1, 129 if R > 129 else 1], np.int32)
+    B, H, Dh = len(at), 4, 16
+    q = put(rng.standard_normal((B, H, Dh), np.float32)).astype(dtype)
+    k, v = (put(rng.standard_normal((B, H, Dh, KW), np.float32)).astype(dtype)
+            for _ in range(2))
+    sk, sv = (put(rng.standard_normal((B, H, Dh, R), np.float32)
+                  ).astype(dtype) for _ in range(2))
+    got = np.asarray(window_summary_attend(q, k, v, sk, sv, put(at),
+                                           put(seen)))
+    assert got.shape == (B, H, Dh) and got.dtype == np.float32
+    want = _plain_attend(q, k, v, sk, sv, put(at), put(seen), round_to=dtype)
+    # a probability on the edge of two bfloat16 values may round either way
+    np.testing.assert_allclose(got, np.asarray(want), rtol=0,
+                               atol=2e-6 if dtype == np.float32 else 2e-5)
+    if dtype != np.float32:
+        # the rounding of the probabilities is as stated: left out, the
+        # formula lies a hundred times further from the kernel
+        exact = _plain_attend(q, k, v, sk, sv, put(at), put(seen))
+        assert np.abs(got - np.asarray(exact)).max() > 2e-4
+    # lengths past the arrays are clamped to them
+    wild = np.asarray(window_summary_attend(
+        q, k, v, sk, sv, put(at + KW * (at == KW - 1)),
+        put(seen + 5 * R * (seen == R))))
+    assert np.array_equal(wild, got)
+
+
+def _einsum_twin(monkeypatch, **kw):
+    """The same weights behind the two `einsum`s: the shape rule,
+    answered "no blocks" while this model's programs are made (a test's
+    own steering: the program has no option for it)."""
+    from singa_tpu.ops import pallas_kernels
+
+    twin = build(**kw)
+    real = twin._slot_step
+
+    def slot_step(*a):
+        with monkeypatch.context() as mp:
+            mp.setattr(pallas_kernels, "window_summary_blocks",
+                       lambda W_, R_: (0, 0))
+            return real(*a)
+
+    twin._slot_step = slot_step
+    return twin
+
+
+def _stream(chunk, rung, P, fault, monkeypatch):
+    """A stream decoded through the kernel's path (window 256; the
+    list's `rung / chunk` entries are one, two or four blocks) from a
+    prompt of P positions that ends in the second half of its block,
+    across the next boundary (there `at` = 0 with the whole of the last
+    block still in the buffer behind it, and the row sees W / chunk
+    summaries more: 128, or 64 or 16, no whole block) to the
+    reference's logits and to the `einsum` path's, step by step; the
+    counters say the kernel ran (read < held). With a planted fault
+    (`summaries_seen_early`: `seen` = pos // chunk, any number;
+    `summary_unwritten`: the chunks that close in decoding stay zero)
+    the kernel serves the fault as planted: the `einsum` path's logits
+    with the same fault, far from the reference."""
+    kw = dict(window=KW, chunk=chunk, num_layers=KLAYERS, max_len=rung)
+    for name in ("_seen_summaries", "_slot_step"):   # put back afterwards
+        monkeypatch.setattr(ChunkedAttnLM, name, getattr(ChunkedAttnLM, name))
+    if fault:
+        evabyte_control.plant(fault)
+    m, twin = build(**kw), _einsum_twin(monkeypatch, **kw)
+    total = KW * (P // KW + 1) + 6
+    full = ids_of((total,), seed=chunk + rung)
+    want = ref_logits(m, full[None], window=KW, chunk=chunk,
+                      num_layers=KLAYERS)[0]
+    lg, slab = prefill(m, fresh_slab(m, seq=rung), [full[:P]], bucket_of(P))
+    _, slab2 = prefill(twin, fresh_slab(twin, seq=rung), [full[:P]],
+                       bucket_of(P))
+    np.testing.assert_allclose(lg[0], want[P - 1], **TOL)
+    m.take_step_counters()
+    worst = 0.0
+    for t in range(P, total):
+        out, slab = step(m, slab, [full[t], 0], [t, 0])
+        plain, slab2 = step(twin, slab2, [full[t], 0], [t, 0])
+        np.testing.assert_allclose(out, plain, **TOL)
+        worst = max(worst, np.abs(out[0] - want[t]).max())
+        n = m.take_step_counters()
+        assert n["attn_entries_needed"] <= n["attn_entries_read"] \
+            < n["attn_entries_held"] == KLAYERS * 2 * (KW + rung // chunk)
+        assert twin.take_step_counters()["attn_entries_read"] \
+            == n["attn_entries_held"]
+    assert (worst > 1e-2) if fault else (worst < TOL["atol"])
+    for a, b in zip(slab, slab2):
+        for name in a:
+            np.testing.assert_allclose(np.asarray(a[name]),
+                                       np.asarray(b[name]), **TOL)
+
+
+def _reused_slot(monkeypatch):
+    """Slot 0 holds a session 2 W + 130 positions in (its buffer's
+    second block and its list's second block written); a prompt of
+    W + 3 positions is then prefilled into it and decoded: the stream
+    is the reference's although the buffer's second block and the list
+    behind the new prompt's bucket still hold the old session's
+    entries, which the kernel never fetches."""
+    m = build(window=KW, chunk=2, num_layers=KLAYERS, max_len=1024)
+    over = dict(window=KW, chunk=2, num_layers=KLAYERS)
+    old = ids_of((2 * KW + 130,), seed=41)
+    _, slab = prefill(m, fresh_slab(m, seq=1024), [old[:2 * KW]], 512)
+    for t in range(2 * KW, len(old)):
+        _, slab = step(m, slab, [old[t], 0], [t, 0])
+    assert np.asarray(slab[0]["sk"])[0, ..., 2 * KW // 2].any()
+    assert np.asarray(slab[0]["k"])[0, ..., 129].any()
+    new = ids_of((KW + 12,), seed=42)
+    want = ref_logits(m, new[None], **over)[0]
+    P = KW + 3
+    lg, slab = prefill(m, slab, [new[:P]], 512)
+    np.testing.assert_allclose(lg[0], want[P - 1], **TOL)
+    for t in range(P, len(new)):
+        out, slab = step(m, slab, [new[t], 0], [t, 0])
+        np.testing.assert_allclose(out[0], want[t], **TOL)
+
+
+def _block_of_8(monkeypatch):
+    """A `slot_scan_8` block through the kernel is eight single steps,
+    over the buffer's block boundary at 128 (positions W + 124 .. W +
+    131): the same tokens, the same slab, the counters their sums."""
+    m = build(window=KW, chunk=2, num_layers=KLAYERS, max_len=512)
+    params = m._decode_params()
+    P = KW + 124
+    prompt = ids_of((P,), seed=51)
+    lg, slab = prefill(m, fresh_slab(m, seq=512), [prompt], 512)
+    _, slab2 = prefill(m, fresh_slab(m, seq=512), [prompt], 512)
+    tok = np.array([lg[0].argmax(), 0], np.int32)
+    pos = np.array([P, 0], np.int32)
+    m.take_step_counters()
+    toks, slab = m.decode_scan(params, slab, put(tok), put(pos), 8)
+    block = m.take_step_counters()
+    toks, t1 = np.asarray(toks), tok.copy()
+    total = dict.fromkeys(m.step_counter_names, 0)
+    for s in range(8):
+        out, slab2 = step(m, slab2, t1, pos + s)
+        t1 = out.argmax(-1).astype(np.int32)
+        assert t1[0] == toks[s, 0]
+        for name, n in m.take_step_counters().items():
+            total[name] += n
+    assert block == total
+    assert block["attn_entries_needed"] < block["attn_entries_read"] \
+        < block["attn_entries_held"]
+    for a, b in zip(slab, slab2):
+        for name in a:
+            np.testing.assert_allclose(np.asarray(a[name])[0],
+                                       np.asarray(b[name])[0], **TOL)
+
+
+def _read_over_a_window(monkeypatch):
+    """`attn_entries_read` over a whole window of 256 steps of two rows
+    in different phases, from the step's own program (only its counters
+    are asked for, so nothing else of it runs): 128 x (at // 128 + 1)
+    of the buffer and 128 x ceil(seen / 128) of the list a row-layer,
+    never under the need, never over what is held; with `seen` as the
+    served model has it (a multiple of 128 here: W / C = 128) the
+    list's read is exact to the entry."""
+    import jax
+
+    m = build(window=KW, chunk=2, num_layers=KLAYERS, max_len=1024)
+    params, slab = m._decode_params(), fresh_slab(m, seq=1024)
+    count = jax.jit(lambda tok, pos: m._slot_step(params, slab, tok, pos)[2])
+    starts = np.array([KW + 3, 2 * KW + 200], np.int32)
+    names = list(m.step_counter_names)
+    for s in range(KW):
+        pos = starts + s
+        n = dict(zip(names, np.asarray(count(put(pos * 0), put(pos)))))
+        at, seen = pos % KW, (KW // 2) * (pos // KW)
+        assert n["attn_entries_read"] == KLAYERS * int(
+            (128 * (at // 128 + 1) + seen).sum())
+        assert n["attn_entries_needed"] == KLAYERS * int(
+            (at + 1 + seen).sum())
+        assert n["attn_entries_held"] == KLAYERS * 2 * (KW + 1024 // 2)
+        assert n["attn_entries_needed"] <= n["attn_entries_read"] \
+            < n["attn_entries_held"]
+
+
+def _the_shapes_alone_decide(monkeypatch):
+    """The rule, and that nothing else chooses: whole 128-entry blocks
+    of buffer and list, two or more of the buffer."""
+    from singa_tpu.ops.pallas_kernels import (window_summary_attend,
+                                              window_summary_blocks)
+
+    assert window_summary_blocks(2048, 1024) == (16, 8)
+    assert window_summary_blocks(2048, 128) == (16, 1)
+    assert window_summary_blocks(256, 128) == (2, 1)
+    for W_, R_ in ((128, 128), (8, 16), (32, 32), (256, 64), (256, 1),
+                   (384, 192), (200, 128)):
+        assert window_summary_blocks(W_, R_) == (0, 0)
+    z = np.zeros((1, 2, 8, 32), np.float32)
+    with pytest.raises(ValueError, match="do not divide into blocks"):
+        window_summary_attend(z[..., 0], z, z, z, z, np.zeros(1, np.int32),
+                              np.zeros(1, np.int32))
+
+
+KERNEL_CASES = {
+    "alone_float32_list_of_128": lambda mp: _kernel_alone(128, np.float32),
+    "alone_float32_list_of_256": lambda mp: _kernel_alone(256, np.float32),
+    "alone_bfloat16_probabilities_rounded":
+        lambda mp: _kernel_alone(256, "bfloat16"),
+    "stream_chunk_2_list_of_256": lambda mp: _stream(2, 512, 140, None, mp),
+    "stream_chunk_2_list_of_512_two_boundaries_in":
+        lambda mp: _stream(2, 1024, KW + 140, None, mp),
+    "stream_chunk_4_list_of_128": lambda mp: _stream(4, 512, 140, None, mp),
+    "stream_chunk_16_list_of_128":
+        lambda mp: _stream(16, 2048, 140, None, mp),
+    "stream_summaries_seen_early_as_planted":
+        lambda mp: _stream(2, 512, 140, "summaries_seen_early", mp),
+    "stream_summary_unwritten_as_planted":
+        lambda mp: _stream(2, 512, 140, "summary_unwritten", mp),
+    "slot_that_held_a_longer_session": _reused_slot,
+    "block_of_8_equals_eight_steps": _block_of_8,
+    "entries_read_over_a_whole_window": _read_over_a_window,
+    "the_shapes_alone_decide": _the_shapes_alone_decide,
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_i_the_decode_step_reads_only_the_blocks_a_row_holds(case,
+                                                             monkeypatch):
+    """`window_summary_attend` (interpreted here; compiled by Mosaic at
+    the cell's widths in `tests/test_tpu_compile_widths.py`) alone
+    against the plain formula, and behind `ChunkedAttnLM._slot_step` at
+    widths that take it against the `einsum` path and against
+    `perfbench/reference/evabyte_ref`, float32 at "highest" to TOL:
+    each case's own words say what it holds."""
+    KERNEL_CASES[case](monkeypatch)

@@ -535,8 +535,10 @@ def test_evabyte_programs_hold_the_slab_in_place(evabyte, program,
     over 2.45 GB of weights and the 9.66 GB slab (six layers of 32
     window buffers and 32 summary lists): each fits 16 GB, the slab is
     aliased whole, no operation moves a whole buffer or a whole summary
-    list of a layer, and the temporaries are what is stated (compiled
-    for a described v5e; no chip, no device metric)."""
+    list of a layer, and the temporaries are what is stated; a decode
+    program holds the length-aware attention kernel, one call a layer,
+    and no joint score tensor (compiled for a described v5e; no chip,
+    no device metric)."""
     model, params, slab, sds = evabyte
     compiled = _engine_program(
         model, params, slab, sds, monkeypatch, EVA_SLOTS, program,
@@ -552,3 +554,16 @@ def test_evabyte_programs_hold_the_slab_in_place(evabyte, program,
     assert not _whole_layer_moves(text, int(np.prod(slab[0]["sk"].shape)))
     if program == "block1":
         assert " while(" not in text
+    if program[:7] != "prefill":
+        # since ISSUE 36 a step's attention is `window_summary_attend`,
+        # lowered here by Mosaic at the cell's widths: one call a layer
+        # that takes the buffers and the lists where they lie (the
+        # shapes `step_parts` places it by are in its text), and the
+        # joint scores [32, 32, 3072] are nowhere any more
+        calls = re.findall(r"^\s*%window_summary_attend\S* = .*$", text, re.M)
+        assert len(calls) == len(slab)
+        for call in calls:
+            assert "custom-call(" in call
+            assert call.count("bf16[32,32,128,2048]") == 2
+            assert call.count("bf16[32,32,128,1024]") == 2
+        assert "[32,32,3072]" not in text
