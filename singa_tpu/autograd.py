@@ -108,6 +108,41 @@ def _to_tensor(x) -> Tensor:
     return tensor_mod.from_numpy(np.asarray(x))
 
 
+# Where a traced op is, by layer INSTANCE: the names `get_params()`
+# gives (`TransformerLM`, `blocks`, `l3`, `attn`), outermost first.
+# `Layer.__call__` pushes its own while it runs and a compiled
+# program pushes the model's (`layer_scope`); an op traced meanwhile
+# is scoped `<names joined by ".">/<Op>` (`Operator._scope`), which
+# is how a device operation finds its layer (hlo_profile.scope_map).
+_layer_path: List[str] = []
+
+
+@contextlib.contextmanager
+def layer_scope(name: str):
+    """Ops traced inside are placed under layer `name`."""
+    _layer_path.append(name)
+    try:
+        yield
+    finally:
+        _layer_path.pop()
+
+
+def _op_scope(op) -> str:
+    name = type(op).__name__
+    return f"{'.'.join(_layer_path)}/{name}" if _layer_path else name
+
+
+def _bwd_scope(op):
+    """The scope of `op`'s hand-written backward and of the cotangent
+    sums the walk makes on its behalf, spelled as jax spells a derived
+    backward (`transpose(jvp(<path>/<Op>))`) so that every backward
+    instruction of a program reads alike. A no-op context for an op
+    that was not traced."""
+    if op._scope is None:
+        return contextlib.nullcontext()
+    return jax.named_scope(f"transpose(jvp({op._scope}))")
+
+
 class Operator:
     """Base differentiable op. Reference: `autograd.Operator`.
 
@@ -120,6 +155,10 @@ class Operator:
     """
 
     _count = 0
+    # True on an op whose overridden `forward` differentiates a
+    # function it scopes itself (`_scoped`); `__call__` then enters no
+    # scope around it
+    _vjp_in_forward = False
 
     def __init__(self):
         self.name = f"{type(self).__name__}#{Operator._count}"
@@ -128,6 +167,7 @@ class Operator:
         self.requires_grad = False
         self.num_outputs = 1
         self._vjp = None
+        self._scope = None  # `<layer path>/<Op>` while traced
 
     # -- public ----------------------------------------------------------
     def __call__(self, *xs):
@@ -136,17 +176,25 @@ class Operator:
         self.requires_grad = any(t.requires_grad for t in xs)
         dev = xs[0].device if xs else None
         self.device = dev
-        # Under tracing, named_scope stamps the op's class name into
-        # XLA metadata (op_name) — how the graph-mode profiler maps
-        # fused HLO regions back to framework ops (hlo_profile.py).
+        # Under tracing, named_scope stamps `<layer path>/<Op>` into
+        # XLA metadata (op_name) — how a device operation of the
+        # compiled step finds its framework op (hlo_profile.py). A
+        # vjp-derived op enters it INSIDE the function it hands to
+        # `jax.vjp` (`_scoped`): a scope entered around the vjp stays
+        # in front of `jvp(` and the transposed operations, emitted
+        # later from `backward`, would read `transpose(jvp())` with
+        # the scope empty. An op with a hand-written backward enters
+        # it here, and the walk enters `_bwd_scope` around `backward`.
         # Eager dispatch (no tracers) skips it: the metadata is only
         # consumed when traced into a program.
         traced = any(isinstance(t.data, jax.core.Tracer) for t in xs)
         timing = dev is not None and dev._verbosity > 0
         if timing or traced:
+            self._scope = _op_scope(self) if traced else None
+            around = traced and _scoped_from_outside(self)
             with (dev.TimeOp(type(self).__name__) if timing
                   else contextlib.nullcontext()), \
-                 (jax.named_scope(type(self).__name__) if traced
+                 (jax.named_scope(self._scope) if around
                   else contextlib.nullcontext()):
                 ys = self.forward(*[t.data for t in xs])
         else:  # hot eager path: no context-manager machinery
@@ -199,8 +247,10 @@ class Operator:
                 self._cached_bwd = bwd
                 self._bwd_xs = xs
                 return fwd(*xs)
-            fn = (jax.checkpoint(self.fn)
-                  if traced and _remat_this(self) else self.fn)
+            # the scope goes around the checkpoint too, so that the
+            # remat call itself is placed, not only what it holds
+            fn = self._scoped(jax.checkpoint(self.fn)
+                              if traced and _remat_this(self) else self.fn)
             # Invalidate any residuals a PRIOR eager forward left on
             # this instance: backward() prefers _cached_bwd, and stale
             # _bwd_xs would bake that step's concrete inputs into a
@@ -209,7 +259,22 @@ class Operator:
             self._cached_bwd = self._bwd_xs = None
             ys, self._vjp = jax.vjp(fn, *xs)
             return ys
-        return self.fn(*xs)
+        return self._scoped(self.fn)(*xs)
+
+    def _scoped(self, fn):
+        """`fn` under this op's scope (`fn` itself for an op that was
+        not traced): what a traced op differentiates, so that forward
+        operations read `jvp(<path>/<Op>)/…` and backward ones
+        `transpose(jvp(<path>/<Op>))/…`."""
+        scope = self._scope
+        if scope is None:
+            return fn
+
+        def scoped(*xs):
+            with jax.named_scope(scope):
+                return fn(*xs)
+
+        return scoped
 
     def backward(self, *dys):
         cot = dys[0] if self.num_outputs == 1 else tuple(dys)
@@ -226,6 +291,15 @@ class Operator:
 
     def fn(self, *xs):  # pragma: no cover - must be overridden
         raise NotImplementedError(type(self).__name__)
+
+
+def _scoped_from_outside(op) -> bool:
+    """A hand-written backward (and its forward) is plain code, so
+    `__call__` and the walk enter the op's scope around them; a
+    vjp-derived op, and one that differentiates a function it scopes
+    itself, carry it inside."""
+    return (type(op).backward is not Operator.backward
+            and not op._vjp_in_forward)
 
 
 _EXEC_CACHE: dict = {}
@@ -350,7 +424,11 @@ def iter_backward(y: Tensor, dy=None):
     def _acc(op: Operator, idx: int, g):
         slot = pending.setdefault(id(op), [None] * op.num_outputs)
         opmap[id(op)] = op
-        slot[idx] = g if slot[idx] is None else slot[idx] + g
+        if slot[idx] is None:
+            slot[idx] = g
+        else:  # a second consumer's cotangent: part of op's backward
+            with _bwd_scope(op):
+                slot[idx] = slot[idx] + g
 
     root = y.creator
     _acc(root, getattr(y, "creator_index", 0), dy_arr)
@@ -373,6 +451,9 @@ def iter_backward(y: Tensor, dy=None):
             # use the walk instead of the one-dispatch recorded path
             with opdev.TimeOp(type(op).__name__ + ".bwd"):
                 in_grads = op.backward(*grads_out)
+        elif op._scope is not None and _scoped_from_outside(op):
+            with _bwd_scope(op):
+                in_grads = op.backward(*grads_out)
         else:
             in_grads = op.backward(*grads_out)
         if not isinstance(in_grads, (tuple, list)):
@@ -388,10 +469,10 @@ def iter_backward(y: Tensor, dy=None):
                 gt = tensor_mod.from_raw(g, x.device)
                 if id(x) in emitted:
                     prev = results[emitted[id(x)]][1]
+                    with _bwd_scope(op):  # a param's second use
+                        total = prev.data + g
                     results[emitted[id(x)]] = (
-                        x,
-                        tensor_mod.from_raw(prev.data + g, x.device),
-                    )
+                        x, tensor_mod.from_raw(total, x.device))
                 else:
                     emitted[id(x)] = len(results)
                     results.append((x, gt))
@@ -451,7 +532,7 @@ _DAG_BWD_ENABLED = "auto"
 # scanned as array state.
 _DAG_MACHINERY = frozenset((
     "inputs", "device", "name", "num_outputs", "requires_grad",
-    "_out_shapes", "_vjp", "_cached_bwd", "_bwd_xs",
+    "_out_shapes", "_vjp", "_cached_bwd", "_bwd_xs", "_scope",
 ))
 # Hand-written ops whose replay is sound; "captures" lists per-step
 # array attrs. All OTHER array attrs on these classes are
@@ -1746,6 +1827,8 @@ class _BatchNorm2d(Operator):
     them inside cuDNN instead). Inference: uses running stats.
     """
 
+    _vjp_in_forward = True
+
     def __init__(self, handle: native.BatchNormHandle, running_mean, running_var):
         super().__init__()
         self.handle = handle
@@ -1762,6 +1845,7 @@ class _BatchNorm2d(Operator):
                 )
                 return y, (nrm, nrv)
 
+            fwd = self._scoped(fwd)
             if self.requires_grad:
                 y, vjp, (nrm, nrv) = jax.vjp(fwd, x, scale, bias, has_aux=True)
                 self._vjp = vjp
@@ -1770,19 +1854,13 @@ class _BatchNorm2d(Operator):
             self.new_running_mean = nrm
             self.new_running_var = nrv
             return y
+        infer = self._scoped(
+            lambda x_, s_, b_: native.batchnorm_inference(
+                self.handle, x_, s_, b_, self.rm, self.rv))
         if self.requires_grad:
-            y, self._vjp = jax.vjp(
-                lambda x_, s_, b_: native.batchnorm_inference(
-                    self.handle, x_, s_, b_, self.rm, self.rv
-                ),
-                x,
-                scale,
-                bias,
-            )
+            y, self._vjp = jax.vjp(infer, x, scale, bias)
             return y
-        return native.batchnorm_inference(
-            self.handle, x, scale, bias, self.rm, self.rv
-        )
+        return infer(x, scale, bias)
 
     def backward(self, dy):
         return self._vjp(dy)

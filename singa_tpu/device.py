@@ -661,12 +661,14 @@ def set_tracing(flag: bool = True, ring_capacity: Optional[int] = None,
     routing, failover, the IPC boundary, and the worker dispatch —
     `trace.merge_chrome_traces` folds N processes' spans into one
     aligned timeline (see README "Fleet observability").
-    NOTE: enabling adds a device sync per graph-mode step (the
-    device_sync span needs a fence to mean anything) — leave it off
-    for peak-throughput runs. Enabled spans are also
-    `jax.profiler.TraceAnnotation`s ("singa:<name>"): any profiler
+    NOTE: enabling adds no device sync: a graph-mode step is never
+    fenced, and `device_sync` is the span around a loop's own loss
+    read (`run_resumable`), where it waits anyway. Enabled spans are
+    also `jax.profiler.TraceAnnotation`s ("singa:<name>"): any profiler
     session (`jax.profiler.start_trace`) shows them on the host's
-    threads under the device's operations. `ring_capacity` resizes the
+    threads under the device's operations; the compiled step's phases
+    (`step.call`, `step.place`, `step.enqueue`, `step.bind`:
+    `trace.phase`) are such annotations with tracing off too. `ring_capacity` resizes the
     span ring (default 16384 spans); `ship_capacity` bounds the cross-process span ship-back buffer a
     fleet WORKER drains into reply/heartbeat frames (0 = off, the
     default — overflow drops oldest, counted `ship_dropped`).
@@ -1062,6 +1064,17 @@ def use_compile_cache() -> str:
     path = compile_cache_dir()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", path)
+    # jax leaves an instruction's metadata out of the cache's key by
+    # default, so a cache that another tree warmed hands back that
+    # tree's `op_name`s: a step whose scopes the device trace is joined
+    # to (hlo_profile.scope_map) would read an older program's, or none.
+    # With the metadata in the key, a location is the traced line alone
+    # and not the ten frames of call stack above it, or the same program
+    # would be compiled again for every script that reaches it.
+    # (`jax_include_full_tracebacks_in_locations=False` would do that
+    # too, and drops most `op_name`s with the frames in this jax.)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     return path
 
 

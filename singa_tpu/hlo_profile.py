@@ -12,13 +12,24 @@ honest equivalent is:
   * a per-HLO-instruction cost breakdown of the optimized program —
     FLOPs computed analytically from dot/convolution dimension numbers,
     bytes from operand/result shapes — with each top-level instruction
-    attributed back to the framework op that produced it via the
-    `op_name` metadata that `autograd.Operator.__call__` stamps with
-    `jax.named_scope`.
+    attributed back to the layer and framework op that produced it
+    via the `op_name` metadata that `autograd.Operator` stamps with
+    `jax.named_scope` (`<layer path>/<Op>`, entered inside the
+    differentiated function so the backward carries it too).
 
 Estimated per-region time = (region FLOPs / program FLOPs) x measured
-step time; the table is explicit that these are cost-model estimates,
-not per-kernel measurements.
+step time; the table says "estimated" on every such time: they are
+cost-model shares, not per-kernel measurements. Rows are grouped by
+layer path and direction (`_group_key`).
+
+Measured per-scope DEVICE times come from a profiler trace joined to
+the same text: a trace's event names an executed instruction
+(`%fusion.786 = f32[768]{0} fusion(...)`) and carries no scope, the
+optimised text carries every instruction's `op_name`, so `scope_map`
+gives instruction -> scope for the process's step programs
+(`step_programs`) and `scope_times` reduces plain
+`(event name, t0, t1)` tuples to device self-time by scope, with the
+time it could not place and why.
 
 No TensorFlow/profiler-plugin dependency: this parses the HLO text
 that PJRT already returns (`compiled.as_text()`).
@@ -27,7 +38,8 @@ from __future__ import annotations
 
 import math
 import re
-from typing import Dict, List, Optional
+from collections import OrderedDict
+from typing import Dict, List, Optional, Tuple
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -246,15 +258,89 @@ def _op_label(ins: _Instr) -> str:
     """Framework-op attribution for one instruction: the named_scope
     op_name path (jit prefix stripped), else the HLO value name."""
     opname = _OPNAME_RE.search(ins.line)
-    label = opname.group(1) if opname else ins.name
+    # XLA joins the op_names of instructions it merges with ";": the
+    # first is the instruction's own
+    label = opname.group(1).split(";")[0] if opname else ins.name
     return re.sub(r"^jit\([^)]*\)/", "", label)
 
 
+# Segments of an op_name that say how a program is built, not where in
+# the model an operation is: transforms' and control flow's own.
+_CONTROL = r"while|body|cond|branch_\d+_fun"
+_CONTROL_RE = re.compile(rf"^(?:{_CONTROL})$")
+_STRUCTURAL_RE = re.compile(
+    rf"^(?:{_CONTROL}|checkpoint|rematted_computation|shard_map|pjit|"
+    r"closed_call|core_call|custom_[jv][vj]p_call\w*|jit\(.*\)|"
+    r"vmap\(.*\))$")
+_DIFF_RE = re.compile(r"^(transpose\()?jvp\((.*)\)$")
+
+
+def _segments(label: str) -> List[str]:
+    """`label` split on the slashes outside brackets: a differentiated
+    scope, `transpose(jvp(a.b/Op))`, is one segment."""
+    return [seg for seg in _split_top(label, "/") if seg]
+
+
+def _place(segs: List[str]) -> Tuple[str, bool]:
+    """(scope, under a transpose?) of an op_name's segments, the
+    primitive's own name already off."""
+    bwd, plain = False, []
+    for seg in segs:
+        m = _DIFF_RE.match(seg)
+        if not m:
+            plain.append(seg)
+            continue
+        bwd = bwd or bool(m.group(1))
+        inner = m.group(2)[:-1] if m.group(1) else m.group(2)
+        scope, inner_bwd = _place(_segments(inner))
+        if scope:
+            return scope, bwd or inner_bwd
+        # an empty wrapper (`transpose(jvp())` around a rematerialised
+        # region): what follows it says where
+    # what a loop's body or a branch holds is placed by the scope it
+    # entered itself; the loop's own bookkeeping by the scope around it
+    cut = max((i + 1 for i, x in enumerate(plain) if _CONTROL_RE.match(x)),
+              default=0)
+    for part in (plain[cut:], plain[:cut]):
+        scope = "/".join(x for x in part if not _STRUCTURAL_RE.match(x))
+        if scope:
+            return scope, bwd
+    return "", bwd
+
+
+def scope_of(label: str) -> Tuple[str, str]:
+    """(scope, direction) of an instruction's op_name, its `jit(..)/`
+    prefix off. The scope is what the program entered: `<layer
+    path>/<Op>` (autograd.Operator) or `opt/<class>/<parameter>`
+    (opt.Optimizer), without the primitive's name at the end and
+    without the segments transforms and control flow add; inside a
+    loop's body or a conditional's branch, the scope entered there.
+    The direction is "bwd" for `transpose(jvp(<scope>))/…` (and for
+    what a rematerialised region recomputes under an empty
+    `transpose(jvp())`), "fwd" for `jvp(<scope>)/…` and for a scope
+    that was not differentiated, "" for the optimizer's and where
+    there is no scope ("", "")."""
+    scope, bwd = _place(_segments(label)[:-1])
+    if not scope:
+        return "", ""
+    if scope.startswith("opt/"):
+        return scope, ""
+    return scope, "bwd" if bwd else "fwd"
+
+
 def _group_key(label: str, fallback: str) -> str:
-    """Group label: the first two named_scope path segments (how both
-    aggregate() and bytes_accessed() bucket per framework op)."""
-    parts = [p for p in label.split("/") if p]
-    return "/".join(parts[:2]) if parts else fallback
+    """Group label (how both aggregate() and bytes_accessed() bucket):
+    the layer path with the direction, `<layer path> fwd|bwd`, so the
+    forward and the backward of one layer are two rows side by side
+    whatever ops the layer is made of; the optimizer's instructions
+    by `opt/<class>` (or `opt/<glue>`); `fallback` where an
+    instruction has no scope."""
+    scope, direction = scope_of(label)
+    if not scope:
+        return fallback
+    if not direction:
+        return "/".join(scope.split("/")[:2])
+    return f"{scope.split('/', 1)[0]} {direction}"
 
 
 def profile_hlo(hlo_text: str) -> List[dict]:
@@ -376,16 +462,17 @@ def _instr_callees(ins: _Instr) -> List[str]:
     return out
 
 
-def _split_top(seg: str) -> List[str]:
-    """Split on commas at bracket depth 0 — operand TYPES carry
-    commas of their own (`f32[8,8]`, tuple types `(f32[], s32[])`)."""
+def _split_top(seg: str, sep: str = ",") -> List[str]:
+    """Split on `sep` at bracket depth 0 — operand TYPES carry
+    commas of their own (`f32[8,8]`, tuple types `(f32[], s32[])`),
+    and a differentiated scope its slashes (`jvp(a.b/Op)`)."""
     out, cur, depth = [], [], 0
     for ch in seg:
         if ch in "([{":
             depth += 1
         elif ch in ")]}":
             depth -= 1
-        if ch == "," and depth == 0:
+        if ch == sep and depth == 0:
             out.append("".join(cur))
             cur = []
         else:
@@ -496,7 +583,7 @@ def peak_bytes_estimate(hlo_text: str) -> float:
 
 
 def aggregate(rows: List[dict], top: int = 0) -> List[dict]:
-    """Group rows by framework op (first two named_scope segments)."""
+    """Group rows by layer path and direction (`_group_key`)."""
     groups: Dict[str, dict] = {}
     for r in rows:
         key = _group_key(r["op"], r["hlo"])
@@ -512,7 +599,9 @@ def aggregate(rows: List[dict], top: int = 0) -> List[dict]:
 def format_table(rows: List[dict], measured_step_s: Optional[float] = None,
                  top: int = 25) -> str:
     """Human-readable graph profile table (printed by
-    Device.PrintTimeProfiling when graph-mode profiles exist)."""
+    Device.PrintTimeProfiling when graph-mode profiles exist): one row
+    a layer and direction. The step time is measured; a row's time is
+    its FLOP share of it, and says "estimated"."""
     agg = aggregate(rows, top=top)
     total_flops = sum(r["flops"] for r in rows) or 1.0
     lines = ["Graph (XLA) cost profile"
@@ -521,9 +610,210 @@ def format_table(rows: List[dict], measured_step_s: Optional[float] = None,
              + f"  total ~{total_flops / 1e9:.2f} GFLOP:"]
     for g in agg:
         pct = 100.0 * g["flops"] / total_flops
-        est = (f"  est {measured_step_s * g['flops'] / total_flops * 1e3:8.3f} ms"
+        est = (f"  estimated "
+               f"{measured_step_s * g['flops'] / total_flops * 1e3:8.3f} ms"
                if measured_step_s else "")
         lines.append(
-            f"  OP = {g['op']:<40} FLOPs = {g['flops'] / 1e6:12.2f} M "
+            f"  OP = {g['op']:<48} FLOPs = {g['flops'] / 1e6:12.2f} M "
             f"({pct:5.1f}%) x {g['count']:<4d}{est}")
     return "\n".join(lines)
+
+
+# ---------------------------------------------------------------------------
+# The join: a device trace's events -> the scopes of the program that
+# ran them, through the program's own optimised HLO.
+# ---------------------------------------------------------------------------
+# Instructions the device runs no operation of its own for.
+_NO_EVENT_OPS = ("parameter", "constant", "tuple", "get-tuple-element",
+                 "bitcast")
+_EVENT_CALLERS = ("while", "call", "conditional", "async-start")
+_MODULE_RE = re.compile(r"^HloModule\s+([\w.\-]+)")
+
+
+def _shape_str(ins: _Instr) -> Optional[str]:
+    if ins.dims is None:
+        return None
+    return f"{ins.dtype}[{','.join(str(d) for d in ins.dims)}]"
+
+
+def _fused_label(ins: _Instr, comps) -> str:
+    """A fusion without metadata of its own (the compiler made it:
+    `wrapped_reduce-window`) takes the op_name most of its fused
+    computation's instructions carry."""
+    cm = _CALLS_RE.search(ins.line)
+    votes: Dict[str, int] = {}
+    for inner in comps.get(cm.group(1), []) if cm else []:
+        if _OPNAME_RE.search(inner.line):
+            lab = _op_label(inner)
+            votes[lab] = votes.get(lab, 0) + 1
+    return max(votes, key=votes.get) if votes else ""
+
+
+def scope_map(hlo_text: str) -> dict:
+    """instruction -> scope for every computation of an optimised HLO
+    text whose instructions the device runs as operations of their
+    own: the entry, `while` bodies and conditions, called
+    computations, a conditional's branches (a fusion's computation is
+    ONE operation: the fusion, under its own metadata).
+
+    {"module": the HLO module's name (a trace's "XLA Modules" event is
+    named `<module>(<id>)`), "instructions": {name: {"shape":
+    "f32[8,4]" or None for a tuple, "opcode", "scope", "dir"}} as
+    `scope_of` gives them, "scoped" / "unscoped": how many carry a
+    scope — the map's own coverage, which a program handed back by a
+    compile cache that an older tree warmed brings down (jax's default
+    key leaves metadata out; `device.use_compile_cache` puts it in)}."""
+    comps = _parse_computations(hlo_text)
+    m = _MODULE_RE.match(hlo_text.lstrip())
+    out = {"module": m.group(1) if m else "", "instructions": {},
+           "scoped": 0, "unscoped": 0}
+    if not comps:
+        return out
+    todo, seen = [_entry_name(comps)], set()
+    while todo:
+        cname = todo.pop()
+        if cname in seen or cname not in comps:
+            continue
+        seen.add(cname)
+        for ins in comps[cname]:
+            if ins.opcode in _EVENT_CALLERS:
+                todo.extend(_instr_callees(ins))
+            if ins.opcode in _NO_EVENT_OPS:
+                continue
+            has = _OPNAME_RE.search(ins.line)
+            label = (_op_label(ins) if has else
+                     _fused_label(ins, comps) if ins.opcode == "fusion"
+                     else "")
+            scope, direction = scope_of(label) if label else ("", "")
+            out["instructions"][ins.name] = {
+                "shape": _shape_str(ins), "opcode": ins.opcode,
+                "scope": scope, "dir": direction}
+            out["scoped" if scope else "unscoped"] += 1
+    return out
+
+
+def _event_instr(name: str):
+    """(instruction name, result shape or None, opcode) of a trace
+    event, which is named by its instruction's whole text without
+    `metadata=`; a bare name ("fusion.3") is its own."""
+    m = _INSTR_RE.match(name)
+    if m:
+        return (m.group("name"), f"{m.group('dtype')}[{m.group('shape')}]",
+                m.group("opcode"))
+    m = _TUPLE_INSTR_RE.match(name)
+    if m:
+        return m.group("name"), None, m.group("opcode")
+    bare = name.strip().lstrip("%")
+    return bare, None, bare.split(".", 1)[0]
+
+
+def _self_times(events) -> List[int]:
+    """Per event, its duration less what events nested inside it cover
+    (a `while` holds its body's operations), so a sum over scopes
+    counts no nanosecond twice. `events` sorted by start."""
+    out = [0] * len(events)
+    stack: List[int] = []
+    for i, (_, t0, t1) in enumerate(events):
+        while stack and events[stack[-1]][2] <= t0:
+            stack.pop()
+        if stack:
+            out[stack[-1]] -= min(t1, events[stack[-1]][2]) - t0
+        out[i] += t1 - t0
+        stack.append(i)
+    return out
+
+
+def scope_times(events, smap: dict, modules=None) -> dict:
+    """Device self-time by scope, from one chip's `(event name, t0,
+    t1)` tuples (any one time unit) and a `scope_map`. An event is
+    matched by its instruction's name AND result shape and, where
+    `modules` gives the chip's "XLA Modules" events, only inside an
+    event of the map's module.
+
+    {"total": self-time of the events considered, "rows": [{"scope",
+    "dir", "time", "events"}] heaviest first, "unplaced": {"not in
+    map": …, "no scope": …} (an event of no instruction of the map, or
+    of one with another shape: another program's, or a stale text; an
+    instruction the program entered no scope for: the compiler's
+    copies, an unscoped piece of glue), "unplaced_by_opcode": the same
+    time by HLO opcode, "matched" / "unmatched": events, "elsewhere":
+    self-time inside other modules' events, not in "total"}. The rows
+    and the unplaced parts add up to "total"."""
+    events = sorted(events, key=lambda e: (e[1], -e[2]))
+    selfs = _self_times(events)
+    spans = None
+    if modules is not None:
+        spans = sorted((t0, t1) for name, t0, t1 in modules
+                       if name.split("(")[0] == smap["module"])
+    instrs = smap["instructions"]
+    rows: Dict[tuple, list] = {}
+    unplaced = {"not in map": 0, "no scope": 0}
+    by_opcode: Dict[str, int] = {}
+    total = elsewhere = matched = unmatched = 0
+    i = 0
+    for (name, t0, _), dt in zip(events, selfs):
+        if spans is not None:
+            while i < len(spans) and spans[i][1] <= t0:
+                i += 1
+            if i == len(spans) or t0 < spans[i][0]:
+                elsewhere += dt
+                continue
+        total += dt
+        iname, shape, opcode = _event_instr(name)
+        ent = instrs.get(iname)
+        if ent is None or (shape is not None and ent["shape"] is not None
+                           and shape != ent["shape"]):
+            why = "not in map"
+            unmatched += 1
+        else:
+            matched += 1
+            if ent["scope"]:
+                row = rows.setdefault((ent["scope"], ent["dir"]), [0, 0])
+                row[0] += dt
+                row[1] += 1
+                continue
+            why = "no scope"
+        unplaced[why] += dt
+        by_opcode[opcode] = by_opcode.get(opcode, 0) + dt
+    return {
+        "total": total,
+        "rows": [{"scope": k[0], "dir": k[1], "time": v[0], "events": v[1]}
+                 for k, v in sorted(rows.items(), key=lambda kv: -kv[1][0])],
+        "unplaced": unplaced,
+        "unplaced_by_opcode": dict(sorted(by_opcode.items(),
+                                          key=lambda kv: -kv[1])),
+        "matched": matched, "unmatched": unmatched,
+        "elsewhere": elsewhere}
+
+
+# The process's most recent step programs: `model._JitStep` (and its
+# sharded subclass) hands over the `jax.stages.Lowered` of each step it
+# is about to call. A `Lowered` holds a module and its compile
+# arguments, no array and nothing of the model, so a program can be
+# read after its model is gone (a benchmark's readers run when the
+# training loop has returned); the newest `_KEEP_PROGRAMS` stay.
+_KEEP_PROGRAMS = 8
+_STEP_PROGRAMS: "OrderedDict[int, object]" = OrderedDict()
+
+
+def note_step_program(step, lowered) -> None:
+    """`step`'s program is now `lowered` (one program a step: the one
+    it called last)."""
+    _STEP_PROGRAMS[id(step)] = lowered
+    _STEP_PROGRAMS.move_to_end(id(step))
+    while len(_STEP_PROGRAMS) > _KEEP_PROGRAMS:
+        _STEP_PROGRAMS.popitem(last=False)
+
+
+def step_programs() -> List[Tuple[str, str]]:
+    """(module name, optimised HLO text) of the process's most recent
+    compiled training steps, newest last, each as last called: what
+    `scope_map` wants for a trace of this process. Compiled here, on
+    demand (jax's in-memory or persistent cache has the executable):
+    nothing is compiled until this is called."""
+    out = []
+    for lowered in list(_STEP_PROGRAMS.values()):
+        text = lowered.compile().as_text()
+        m = _MODULE_RE.match(text.lstrip())
+        out.append((m.group(1) if m else "", text))
+    return out

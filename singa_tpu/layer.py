@@ -34,12 +34,13 @@ class Layer:
     def __init__(self, name: Optional[str] = None):
         self.name = name or type(self).__name__
         self._initialized = False
-        self._parent = None
 
     # -- attribute registration -------------------------------------------
     def __setattr__(self, key, value):
         if isinstance(value, Layer):
             self.__dict__.setdefault("_sublayers", OrderedDict())[key] = value
+            # the name `get_params()` gives it, for `__call__`'s path
+            value.__dict__["_attr"] = key
         elif isinstance(value, Tensor) and getattr(value, "stores_grad", False):
             self.__dict__.setdefault("_params", OrderedDict())[key] = value
         object.__setattr__(self, key, value)
@@ -63,7 +64,15 @@ class Layer:
         if not self._initialized:
             self.initialize(*xs)
             self._initialized = True
-        return self.forward(*xs)
+        # this instance's name joins the path that scopes the ops it
+        # traces (autograd.layer_scope, inlined: every eager call of
+        # every layer passes here)
+        path = autograd._layer_path
+        path.append(self.__dict__.get("_attr") or self.name)
+        try:
+            return self.forward(*xs)
+        finally:
+            path.pop()
 
     def register_param(self, attr: str, t: Tensor):
         t.requires_grad = True

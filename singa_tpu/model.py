@@ -33,8 +33,8 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from . import autograd, stats as stats_mod, tensor as tensor_mod, \
-    trace as trace_mod
+from . import autograd, hlo_profile, stats as stats_mod, \
+    tensor as tensor_mod, trace as trace_mod
 from .layer import Layer
 from .tensor import Tensor
 
@@ -860,7 +860,8 @@ class _JitForward:
                 for i in range(nargs):
                     if args[i] is None:
                         args[i] = next(it)
-                out_arrays = _unwrap_out(model.forward(*args))
+                with autograd.layer_scope(model.name):
+                    out_arrays = _unwrap_out(model.forward(*args))
                 new_s = [s.data for s in states]
                 return out_arrays, new_s, dev._rng_key
 
@@ -1077,6 +1078,7 @@ class _JitStep:
         from . import resilience
 
         self.model = model
+        model.get_params()  # names every param: the update's scopes
         self.params: List[Tensor] = model.param_tensors()
         self.states: List[Tensor] = model.state_tensors()
         self.opt = model._optimizer
@@ -1101,6 +1103,10 @@ class _JitStep:
         # requires re-compile().
         self._guard_n = (len(resilience.state_arrays())
                          if resilience.guard_active() else 0)
+        # batch signature -> the `jax.stages.Lowered` of the step at
+        # that signature (`_note_program`)
+        self._lowerings: Dict = {}
+        self._noted_sig = None
 
     # ---- optimizer state flattening -------------------------------------
     def _opt_arrays(self):
@@ -1142,7 +1148,9 @@ class _JitStep:
                     if opt is not None:
                         opt.step_counter = step_counter
                     batch_t = [tensor_mod.from_raw(b, dev) for b in batch]
-                    out_arrays = _unwrap_out(model.train_one_batch(*batch_t))
+                    with autograd.layer_scope(model.name):
+                        out_arrays = _unwrap_out(
+                            model.train_one_batch(*batch_t))
                     new_p = [p.data for p in params]
                     new_s = [s.data for s in states]
                     new_o = self._opt_arrays()
@@ -1256,8 +1264,9 @@ class _JitStep:
         dev._rng_key = key_c
         opt._accum_begin(skip_backward=skip_backward)
         try:
-            out = model.train_one_batch(
-                *[tensor_mod.from_raw(b, dev) for b in mb])
+            with autograd.layer_scope(model.name):
+                out = model.train_one_batch(
+                    *[tensor_mod.from_raw(b, dev) for b in mb])
         finally:
             cap = opt._accum_end()
         if len(cap) != 1:
@@ -1362,7 +1371,8 @@ class _JitStep:
         stacked_outs)."""
         import jax.numpy as jnp
 
-        acc0 = [jnp.zeros(p.data.shape, jnp.float32) for p in order]
+        with jax.named_scope("opt/accum"):
+            acc0 = [jnp.zeros(p.data.shape, jnp.float32) for p in order]
         ids = [id(p) for p in order]
         remat_pol = stats_mod.remat_policy()
 
@@ -1388,24 +1398,30 @@ class _JitStep:
             # same sequential fp32 sum as the eager adder
             # (_accum_add_fn) — the two modes accumulate
             # bit-identically
-            acc = [a + g.astype(jnp.float32)
-                   for a, g in zip(acc, gl)]
-            loss_acc = loss_acc + jnp.mean(loss_arr).astype(
-                jnp.float32)
+            with jax.named_scope("opt/accum"):
+                acc = [a + g.astype(jnp.float32)
+                       for a, g in zip(acc, gl)]
+                loss_acc = loss_acc + jnp.mean(loss_arr).astype(
+                    jnp.float32)
             return (tuple(new_s), new_key, acc, loss_acc), outs
 
-        carry0 = (tuple(svals_init), key_init, acc0,
-                  jnp.zeros((), jnp.float32))
+        with jax.named_scope("opt/accum"):
+            carry0 = (tuple(svals_init), key_init, acc0,
+                      jnp.zeros((), jnp.float32))
         if micro and int(micro[0].shape[0]) == 1:
             # Length-1 "scan" (the remat-policy reroute of a
             # non-accumulated step): run the body once inline — no
             # while loop in the HLO, so the entry-level byte/peak
             # meters stay sighted on the step's real internals.
             carry, outs = body(carry0, [m[0] for m in micro])
-            outs = jax.tree_util.tree_map(
-                lambda a: jnp.asarray(a)[None], outs)
+            with jax.named_scope("opt/accum"):
+                outs = jax.tree_util.tree_map(
+                    lambda a: jnp.asarray(a)[None], outs)
             return carry, outs
-        return jax.lax.scan(body, carry0, micro)
+        # the loop's own counter and stacking under `opt/accum`; what
+        # the body traces keeps the scopes it enters itself
+        with jax.named_scope("opt/accum"):
+            return jax.lax.scan(body, carry0, micro)
 
     def _accum_step(self, n, pvals, svals, ovals, key, step_counter,
                     batch):
@@ -1430,7 +1446,8 @@ class _JitStep:
             try:
                 self._bind_opt_arrays(ovals)
                 opt.step_counter = step_counter
-                micro = self._microbatch_stack(n, batch)
+                with jax.named_scope("opt/accum"):
+                    micro = self._microbatch_stack(n, batch)
                 mb = micro[0].shape[1]
                 mb_specs = [jax.ShapeDtypeStruct(m.shape[1:], m.dtype)
                             for m in micro]
@@ -1445,7 +1462,8 @@ class _JitStep:
                 dev._rng_key = key_f
                 opt.apply_accumulated(loss_sum,
                                       list(zip(order, acc)), n)
-                out_arrays = _merge_accum_out(outs, mb)
+                with jax.named_scope("opt/accum"):
+                    out_arrays = _merge_accum_out(outs, mb)
                 new_p = [p.data for p in params]
                 new_s = [s.data for s in states]
                 new_o = self._opt_arrays()
@@ -1539,6 +1557,21 @@ class _JitStep:
             return lowered.as_text(dialect="hlo")
         return lowered.compile().as_text()
 
+    def _note_program(self, args):
+        """Hand `hlo_profile.step_programs` the step's `Lowered` at
+        the current batch signature, taken once a signature just
+        before its first call: the trace and the lowering are the ones
+        that call needs anyway (it finds them in jax's caches), so
+        this adds no work of its own. A `Lowered` holds the module and
+        its compile arguments: no array, nothing of the model, so the
+        program can still be read when the model is gone."""
+        low = self._lowerings.get(self._batch_sig)
+        if low is None:
+            low = self._lowerings[self._batch_sig] = \
+                self._compiled.lower(*args)
+        hlo_profile.note_step_program(self, low)
+        self._noted_sig = self._batch_sig
+
     # ---- AOT export cache (ISSUE 6) --------------------------------------
     def _export_kind(self) -> str:
         return "step"
@@ -1621,6 +1654,17 @@ class _JitStep:
         return fn
 
     def __call__(self, *batch: Tensor):
+        """One step, in four host phases (`trace.phase`: always on a
+        profiler session's host thread, in the ring while the tracer
+        is on): `step.call` the whole call, `step.place` the inputs onto
+        their layout, `step.enqueue` the compiled call (first call:
+        trace + compile), `step.bind` the results back onto params,
+        states, slots and key. Nothing here waits for the device: a
+        loop waits where it reads the loss."""
+        with trace_mod.phase("step.call"):
+            return self._call(batch)
+
+    def _call(self, batch):
         from . import export_cache
 
         batch_arrays = tuple(
@@ -1653,40 +1697,32 @@ class _JitStep:
         svals = [s.data for s in self.states]
         ovals = self._opt_arrays()
         step = 0 if opt is None else opt.step_counter
-        pvals, svals, ovals, key, batch_arrays = self._prepare_inputs(
-            pvals, svals, ovals, dev._rng_key, batch_arrays
-        )
+        with trace_mod.phase("step.place"):
+            pvals, svals, ovals, key, batch_arrays = self._prepare_inputs(
+                pvals, svals, ovals, dev._rng_key, batch_arrays
+            )
         if exporting:
             self._compiled = self._obtain_export(
                 (pvals, svals, ovals, key, step, batch_arrays),
                 batch_arrays, prior_sig=prior_sig)
             self._from_export = True
+        if self._noted_sig != self._batch_sig:
+            self._note_program(
+                (pvals, svals, ovals, key, step, batch_arrays))
         profiling = dev._verbosity > 0
         if profiling and getattr(self, "_hlo_rows", None) is None:
-            # One extra lower+compile (shapes only — safe before the
-            # donating call below) yields the optimized HLO for the
-            # per-op cost table (hlo_profile.py).
+            # The step's lowering, compiled once more, yields the
+            # optimized HLO for the per-op cost table (hlo_profile.py).
             try:
-                from . import hlo_profile
-
-                text = self._compiled.lower(
-                    pvals, svals, ovals, key, step, batch_arrays
-                ).compile().as_text()
-                self._hlo_rows = hlo_profile.profile_hlo(text)
+                self._hlo_rows = hlo_profile.profile_hlo(
+                    self._lowerings[self._batch_sig].compile().as_text())
             except Exception:
                 self._hlo_rows = []
         t0 = time.perf_counter() if profiling else 0.0
-        # dispatch: host time to enqueue the compiled program (first
-        # call: trace+compile). device_sync below only exists while
-        # tracing — an unconditional fence would break the pipelined
-        # steady state this step is designed for.
-        with trace_mod.span("dispatch"):
+        with trace_mod.phase("step.enqueue"):
             out, new_p, new_s, new_o, new_key = self._compiled(
                 pvals, svals, ovals, key, step, batch_arrays
             )
-        if trace_mod.enabled():
-            with trace_mod.span("device_sync"):
-                jax.block_until_ready(new_key)
         # Accumulated replays count their n microbatch invocations so
         # train_steps agrees between eager and graph accumulation;
         # accum_steps counts the one executed apply (the in-trace
@@ -1707,14 +1743,15 @@ class _JitStep:
                 label, {"rows": self._hlo_rows or [], "step_s": dt})
             prof["step_s"] = min(prof["step_s"], dt)
             prof["rows"] = self._hlo_rows or []
-        for p, v in zip(self.params, new_p):
-            p.data = v
-        for s, v in zip(self.states, new_s):
-            s.data = v
-        self._bind_opt_arrays(new_o)
-        dev._rng_key = self._restore_key(new_key, dev)
-        if opt is not None:
-            opt.step_counter = step + 1
-        return jax.tree_util.tree_map(
-            lambda a: tensor_mod.from_raw(a, dev), out
-        )
+        with trace_mod.phase("step.bind"):
+            for p, v in zip(self.params, new_p):
+                p.data = v
+            for s, v in zip(self.states, new_s):
+                s.data = v
+            self._bind_opt_arrays(new_o)
+            dev._rng_key = self._restore_key(new_key, dev)
+            if opt is not None:
+                opt.step_counter = step + 1
+            return jax.tree_util.tree_map(
+                lambda a: tensor_mod.from_raw(a, dev), out
+            )

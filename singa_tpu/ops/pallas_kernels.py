@@ -175,6 +175,7 @@ def _softmax_xent_fwd(logits, labels):
                   pl.BlockSpec((tile, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile, 1), lambda i: (i, 0)),
         interpret=_interpret(),
+        name="softmax_xent_fwd",
     )(xp, lp)
     return loss[:b0, 0], (logits, labels)
 
@@ -198,6 +199,7 @@ def _softmax_xent_bwd(res, g):
                   pl.BlockSpec((tile, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile, c), lambda i: (i, 0)),
         interpret=_interpret(),
+        name="softmax_xent_bwd",
     )(xp, lp, gp)
     return dx[:b0], None
 
@@ -245,6 +247,7 @@ def dropout(x, ratio: float, seed) -> tuple:
         out_specs=(pl.BlockSpec((tile, lane), lambda i: (i, 0)),
                    pl.BlockSpec((tile, lane), lambda i: (i, 0))),
         interpret=_interpret(),
+        name="dropout",
     )(seed_arr, x2)
     y = y2.reshape(-1)[:n].reshape(orig_shape)
     m = m2.reshape(-1)[:n].reshape(orig_shape)
@@ -319,6 +322,7 @@ def topk_threshold(flat, k: int):
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((nrows, _HIST_CHUNK), lambda i: (0, 0)),
         interpret=_interpret(),
+        name="topk_hist",
     )(x2, jnp.asarray([1.0], jnp.float32) * gmax)
     # padding contributed zeros into bin 0; remove them
     hist = hist.reshape(_BINS).at[0].add(-(pad + (x2.size - x.size)))
@@ -352,6 +356,7 @@ def threshold_mask(x, thr):
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((tile, lane), lambda i: (i, 0)),
         interpret=_interpret(),
+        name="threshold_mask",
     )(x2, jnp.asarray(thr, jnp.float32).reshape(1))
     return y2.reshape(-1)[:n].reshape(orig)
 
@@ -513,6 +518,7 @@ def _flash_fwd(q, k, v, causal, scale, precision):
         out_specs=(pl.BlockSpec((1, tq, d), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, tq, 1), lambda i, j: (i, j, 0))),
         interpret=_interpret(),
+        name="flash_fwd",
     )(qp, kp, vp)
     o = o.reshape(b, h, spad, d)[:, :, :s]
     return o, (q, k, v, o, lse)
@@ -546,6 +552,7 @@ def _flash_bwd(causal, scale, precision, res, g):
                   pl.BlockSpec((1, tq, 1), blk)],
         out_specs=pl.BlockSpec((1, tq, d), blk),
         interpret=_interpret(),
+        name="flash_dq",
     )(qp, kp, vp, gp, lse, dpad)
     dk, dv = pl.pallas_call(
         functools.partial(_attn_dkv_kernel, scale=sc, causal=causal,
@@ -562,6 +569,7 @@ def _flash_bwd(causal, scale, precision, res, g):
         out_specs=(pl.BlockSpec((1, tq, d), blk),
                    pl.BlockSpec((1, tq, d), blk)),
         interpret=_interpret(),
+        name="flash_dkv",
     )(kp, vp, qp, gp, lse, dpad)
     unpad = lambda x: x.reshape(b, h, spad, d)[:, :, :s]  # noqa: E731
     return unpad(dq), unpad(dk), unpad(dv)
@@ -626,6 +634,7 @@ def cache_write(cache, new, at, axis):
             out_specs=pl.BlockSpec(tuple(block), cache_map)),
         input_output_aliases={1: 0},
         interpret=_interpret(),
+        name="cache_write",
     )(at.astype(jnp.int32), cache, new)
 
 

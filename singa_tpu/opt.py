@@ -13,6 +13,7 @@ whole-step `jax.jit` program built by `Model.compile(use_graph=True)`
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Dict, List, Optional
 
@@ -266,16 +267,33 @@ class Optimizer:
     def update(self, param: Tensor, grad: Tensor) -> None:
         """Apply one update to `param` in place (rebinds `.data`)."""
         g = grad.data if isinstance(grad, Tensor) else grad
-        if g.dtype != param.data.dtype:
-            # fp16/bf16 grads (half allreduce path) apply to fp32 master.
-            g = g.astype(param.data.dtype)
-        if isinstance(param.data, jax.core.Tracer) or isinstance(
-                g, jax.core.Tracer):
-            # graph mode: the whole step is one traced program; the
-            # plain expressions fuse there anyway
-            param.data = self._apply_masterized(param, param.data, g)
-        else:
-            self._fused_eager_update_all([(param, g)])
+        traced = isinstance(param.data, jax.core.Tracer) or isinstance(
+            g, jax.core.Tracer)
+        with (jax.named_scope(self._update_scope(param)) if traced
+              else contextlib.nullcontext()):
+            if g.dtype != param.data.dtype:
+                # fp16/bf16 grads (half allreduce path) apply to fp32
+                # master.
+                g = g.astype(param.data.dtype)
+            if traced:
+                # graph mode: the whole step is one traced program;
+                # the plain expressions fuse there anyway
+                param.data = self._apply_masterized(param, param.data, g)
+            else:
+                self._fused_eager_update_all([(param, g)])
+
+    def _update_scope(self, param) -> str:
+        """The `jax.named_scope` of one parameter's update inside a
+        traced step program, by the name `get_params()` gave it: how a
+        device operation of the update is placed
+        (hlo_profile.scope_map). The glue around the updates has a
+        scope of its own each: `opt/guard` (the step guard's finite
+        check, select and counters), `opt/loss_scale` (the scaled seed
+        and the unscale), `opt/clip` (the global-norm clip),
+        `opt/accum` (accumulation's sums and mean), `opt/allreduce`
+        (DistOpt's reductions)."""
+        return (f"opt/{type(self).__name__}/"
+                f"{getattr(param, 'name', None) or 'unnamed'}")
 
     def _hyper_key(self):
         """Scalar hyperparameter snapshot for the fused-update cache:
@@ -657,10 +675,11 @@ class Optimizer:
             # traced (graph step): the same expressions inline — the
             # division/cast are elementwise, so both branches are
             # bit-identical
-            gs = [(a / nf).astype(p.data.dtype)
-                  for p, a in acc_pairs]
-            loss_mean = jnp.asarray(loss_sum).astype(
-                jnp.float32) / nf
+            with jax.named_scope("opt/accum"):
+                gs = [(a / nf).astype(p.data.dtype)
+                      for p, a in acc_pairs]
+                loss_mean = jnp.asarray(loss_sum).astype(
+                    jnp.float32) / nf
         pairs = []
         for (p, _), g in zip(acc_pairs, gs):
             gt = tensor_mod.from_raw(g, p.device)
@@ -705,7 +724,8 @@ class Optimizer:
         guard = resilience.guard_active()
         dy = None
         if guard and resilience.scaler_active():
-            dy = resilience.scaled_seed(loss.data)
+            with jax.named_scope("opt/loss_scale"):
+                dy = resilience.scaled_seed(loss.data)
         pairs = list(autograd.iter_backward(loss, dy))
         if self._accum_capture is not None:
             self._accum_capture.append((loss, pairs))
@@ -750,10 +770,13 @@ class Optimizer:
             return loss
         raw = [(p, g.data if isinstance(g, Tensor) else g)
                for p, g in pairs]
-        scale = _global_clip_scale(self.clip_norm,
-                                   [g for _, g in raw])
+        with jax.named_scope("opt/clip"):
+            scale = _global_clip_scale(self.clip_norm,
+                                       [g for _, g in raw])
+            raw = [(p, (g.astype(jnp.float32) * scale).astype(g.dtype))
+                   for p, g in raw]
         for p, g in raw:
-            self.update(p, (g.astype(jnp.float32) * scale).astype(g.dtype))
+            self.update(p, g)
         self.step()
         return loss
 
@@ -781,12 +804,14 @@ class Optimizer:
         for p, g in pairs:
             g = g.data if isinstance(g, Tensor) else g
             if g.dtype != p.data.dtype:
-                g = g.astype(p.data.dtype)
+                with jax.named_scope(self._update_scope(p)):
+                    g = g.astype(p.data.dtype)
             prepared.append((p, g))
         scale, counters = resilience.state_arrays()
         scaler = resilience.scaler_active()
         gs_raw = [g for _, g in prepared]
-        finite = resilience.all_finite([loss.data] + gs_raw)
+        with jax.named_scope("opt/guard"):
+            finite = resilience.all_finite([loss.data] + gs_raw)
         pids = [id(p) for p, _ in prepared]
         names = [tuple(sorted(self.states.get(pid, ())))
                  for pid in pids]
@@ -798,12 +823,14 @@ class Optimizer:
             if scaler:
                 # finite(g) == finite(g/s): checked on raw grads, only
                 # the apply path pays the unscale
-                inv = 1.0 / scale
-                gs = [g * inv.astype(g.dtype) for g in gs]
+                with jax.named_scope("opt/loss_scale"):
+                    inv = 1.0 / scale
+                    gs = [g * inv.astype(g.dtype) for g in gs]
             if self.clip_norm is not None:
-                cs = _global_clip_scale(self.clip_norm, gs)
-                gs = [(g.astype(jnp.float32) * cs).astype(g.dtype)
-                      for g in gs]
+                with jax.named_scope("opt/clip"):
+                    cs = _global_clip_scale(self.clip_norm, gs)
+                    gs = [(g.astype(jnp.float32) * cs).astype(g.dtype)
+                          for g in gs]
             return gs
 
         def apply_branch(op):
@@ -815,7 +842,8 @@ class Optimizer:
                 for (p, _), pid, nm, v, g, sl in zip(
                         prepared, pids, names, vals, gs, slots):
                     self.states[pid] = dict(zip(nm, sl))
-                    new_vals.append(self._apply_masterized(p, v, g))
+                    with jax.named_scope(self._update_scope(p)):
+                        new_vals.append(self._apply_masterized(p, v, g))
                     st = self.states[pid]
                     new_slots.append([st[n] for n in sorted(st)])
                 return new_vals, new_slots
@@ -831,9 +859,10 @@ class Optimizer:
             return list(vals), [list(sl) for sl in slots]
 
         try:
-            new_vals, new_slots = jax.lax.cond(
-                finite, apply_branch, skip_branch,
-                (vals_in, gs_raw, slots_in))
+            with jax.named_scope("opt/guard"):
+                new_vals, new_slots = jax.lax.cond(
+                    finite, apply_branch, skip_branch,
+                    (vals_in, gs_raw, slots_in))
         except (TypeError, ValueError):
             # apply created/renamed slots mid-trace: branch structures
             # can't match — run the update and select outputs instead
@@ -841,18 +870,20 @@ class Optimizer:
             old_slots = {pid: dict(self.states.get(pid, ()))
                          for pid in pids}
             for (p, _), g in zip(prepared, gs):
-                p.data = self._apply_masterized(p, p.data, g)
-            for (p, _), old in zip(prepared, vals_in):
-                p.data = jnp.where(finite, p.data, old)
-            for pid in pids:
-                st = self.states.get(pid)
-                if not st:
-                    continue
-                old = old_slots[pid]
-                for name in list(st):
-                    st[name] = jnp.where(
-                        finite, st[name],
-                        old.get(name, jnp.zeros_like(st[name])))
+                with jax.named_scope(self._update_scope(p)):
+                    p.data = self._apply_masterized(p, p.data, g)
+            with jax.named_scope("opt/guard"):
+                for (p, _), old in zip(prepared, vals_in):
+                    p.data = jnp.where(finite, p.data, old)
+                for pid in pids:
+                    st = self.states.get(pid)
+                    if not st:
+                        continue
+                    old = old_slots[pid]
+                    for name in list(st):
+                        st[name] = jnp.where(
+                            finite, st[name],
+                            old.get(name, jnp.zeros_like(st[name])))
         else:
             for (p, _), v in zip(prepared, new_vals):
                 p.data = v
@@ -867,8 +898,9 @@ class Optimizer:
                 and not isinstance(scale, jax.core.Tracer)):
             resilience.warn_frozen_guard_state()
             return
-        resilience.bind_state_arrays(
-            resilience.advance_state(finite, scale, counters))
+        with jax.named_scope("opt/guard"):
+            resilience.bind_state_arrays(
+                resilience.advance_state(finite, scale, counters))
 
     # -- state I/O for checkpointing ---------------------------------------
     def state_arrays(self) -> List:
@@ -1047,13 +1079,14 @@ class DistOpt(Optimizer):
     def update(self, param, grad):
         """Reference: `DistOpt.update` — allreduce then average then
         apply (same grad scaling as every backward_and_* path)."""
-        self.all_reduce(grad)
-        self.wait()
-        inv = self.communicator.grad_scale
-        if isinstance(grad, Tensor):
-            grad.data = grad.data * inv
-        else:
-            grad = grad * inv
+        with jax.named_scope("opt/allreduce"):
+            self.all_reduce(grad)
+            self.wait()
+            inv = self.communicator.grad_scale
+            if isinstance(grad, Tensor):
+                grad.data = grad.data * inv
+            else:
+                grad = grad * inv
         self.opt.update(param, grad)
 
     def apply(self, param, value, grad):
@@ -1111,16 +1144,18 @@ class DistOpt(Optimizer):
         pairs = list(autograd.iter_backward(loss))
         small = [(p, g) for p, g in pairs if g.size() <= threshold]
         large = [(p, g) for p, g in pairs if g.size() > threshold]
-        if small:
-            reduced = self.communicator.fused_synch([g.data for _, g in small])
-            for (p, g), r in zip(small, reduced):
-                g.data = r
-        for _, g in large:
-            g.data = self.communicator.synch(g.data)
-        self.communicator.wait()
-        inv = self.communicator.grad_scale
-        for p, g in pairs:
-            g.data = g.data * inv
+        with jax.named_scope("opt/allreduce"):
+            if small:
+                reduced = self.communicator.fused_synch(
+                    [g.data for _, g in small])
+                for (p, g), r in zip(small, reduced):
+                    g.data = r
+            for _, g in large:
+                g.data = self.communicator.synch(g.data)
+            self.communicator.wait()
+            inv = self.communicator.grad_scale
+            for p, g in pairs:
+                g.data = g.data * inv
         self._clip_pairs(pairs)
         if self._guard_skip(loss, pairs):
             self.opt.step()
@@ -1164,21 +1199,23 @@ class DistOpt(Optimizer):
               else self.clip_norm)  # honor the wrapper's public API too
         if cn is None or not pairs:
             return
-        scale = _global_clip_scale(cn, [g.data for _, g in pairs])
-        for _, g in pairs:
-            g.data = (g.data.astype(jnp.float32)
-                      * scale).astype(g.data.dtype)
+        with jax.named_scope("opt/clip"):
+            scale = _global_clip_scale(cn, [g.data for _, g in pairs])
+            for _, g in pairs:
+                g.data = (g.data.astype(jnp.float32)
+                          * scale).astype(g.data.dtype)
 
     def backward_and_update_half(self, loss: Tensor, threshold: int = 2097152):
         """Reference: `backward_and_update_half` — fp16 compression
         around the allreduce; here bf16 (the TPU-native half)."""
         pairs = list(autograd.iter_backward(loss))
-        reduced = self.communicator.fused_synch_half(
-            [g.data for _, g in pairs]
-        )
-        inv = self.communicator.grad_scale
-        for (p, g), r in zip(pairs, reduced):
-            g.data = r.astype(p.data.dtype) * inv
+        with jax.named_scope("opt/allreduce"):
+            reduced = self.communicator.fused_synch_half(
+                [g.data for _, g in pairs]
+            )
+            inv = self.communicator.grad_scale
+            for (p, g), r in zip(pairs, reduced):
+                g.data = r.astype(p.data.dtype) * inv
         self._clip_pairs(pairs)
         if self._guard_skip(loss, pairs):
             self.opt.step()
@@ -1196,7 +1233,9 @@ class DistOpt(Optimizer):
         k = self.opt.step_counter % max(len(pairs), 1)
         for i, (p, g) in enumerate(pairs):
             if i == k:
-                g.data = self.communicator.synch(g.data) * self.communicator.grad_scale
+                with jax.named_scope("opt/allreduce"):
+                    g.data = (self.communicator.synch(g.data)
+                              * self.communicator.grad_scale)
             self.opt.update(p, g)
         self.opt.step()
         return loss
@@ -1208,9 +1247,10 @@ class DistOpt(Optimizer):
         pairs = list(autograd.iter_backward(loss))
         inv = self.communicator.grad_scale
         for p, g in pairs:
-            g.data = self.communicator.sparsification(
-                g.data, spars=spars, topK=topK
-            ) * inv
+            with jax.named_scope("opt/allreduce"):
+                g.data = self.communicator.sparsification(
+                    g.data, spars=spars, topK=topK
+                ) * inv
             self.opt.update(p, g)
         self.opt.step()
         return loss

@@ -20,7 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import trace as trace_mod
 from ..model import _JitStep, _merge_accum_out
 from .sharding import ShardingRules, batch_sharding, replicated
 
@@ -133,22 +132,21 @@ class ShardedJitStep(_JitStep):
     def _prepare_inputs(self, pvals, svals, ovals, key, batch_arrays):
         """device_put everything to its mesh layout (no-op for arrays
         already placed — users may rebind p.data to host arrays).
-        Traced as a "shard_place" span: re-placement cost here means
-        something upstream keeps handing the step host/off-mesh
+        The step's `step.place` phase (`_JitStep.__call__`): time here
+        means something upstream keeps handing the step host/off-mesh
         arrays every step."""
-        with trace_mod.span("shard_place"):
-            rep = replicated(self.mesh)
-            pvals = [self._gput(v, s)
-                     for v, s in zip(pvals, self._param_shardings())]
-            svals = [self._gput(v, rep) for v in svals]
-            ovals = [self._gput(v, s)
-                     for v, s in zip(ovals, self._opt_shardings())]
-            key = self._gput(key, rep)
-            batch_arrays = tuple(
-                self._gput(b, s)
-                for b, s in zip(batch_arrays,
-                                self._batch_shardings(batch_arrays))
-            )
+        rep = replicated(self.mesh)
+        pvals = [self._gput(v, s)
+                 for v, s in zip(pvals, self._param_shardings())]
+        svals = [self._gput(v, rep) for v in svals]
+        ovals = [self._gput(v, s)
+                 for v, s in zip(ovals, self._opt_shardings())]
+        key = self._gput(key, rep)
+        batch_arrays = tuple(
+            self._gput(b, s)
+            for b, s in zip(batch_arrays,
+                            self._batch_shardings(batch_arrays))
+        )
         return pvals, svals, ovals, key, batch_arrays
 
     def _restore_key(self, new_key, dev):
@@ -335,40 +333,41 @@ class ShardedJitStep(_JitStep):
                         and not (getattr(a, "ndim", 0) >= 1
                                  and a.shape[0] == n * mbl)
                     ]
-                    parts = ([a.reshape(-1) for a in acc]
-                             + [loss_sum.reshape(1)]
-                             + [jnp.asarray(states[i].data)
-                                .astype(jnp.float32).reshape(-1)
-                                for i in fstate_ix]
-                             + [jnp.asarray(mleaves[i])
-                                .astype(jnp.float32).reshape(-1)
-                                for i in fout_ix])
-                    sizes = [int(np.prod(p.shape)) for p in parts]
-                    flat = (jnp.concatenate(parts)
-                            if len(parts) > 1 else parts[0])
-                    red = jax.lax.psum(flat, ax)
-                    pieces, off = [], 0
-                    for sz in sizes:
-                        pieces.append(red[off:off + sz])
-                        off += sz
-                    k = len(acc)
-                    acc = [pc.reshape(p.data.shape)
-                           for pc, p in zip(pieces[:k], order)]
-                    loss_sum = pieces[k].reshape(())
-                    k += 1
-                    for j, i in enumerate(fstate_ix):
-                        orig = states[i].data
-                        states[i].data = (
-                            (pieces[k + j] / ndev)
-                            .astype(orig.dtype).reshape(orig.shape))
-                    k += len(fstate_ix)
-                    for j, i in enumerate(fout_ix):
-                        orig = mleaves[i]
-                        mleaves[i] = (
-                            (pieces[k + j] / ndev)
-                            .astype(orig.dtype).reshape(orig.shape))
-                    merged = jax.tree_util.tree_unflatten(mtree,
-                                                          mleaves)
+                    with jax.named_scope("opt/accum"):
+                        parts = ([a.reshape(-1) for a in acc]
+                                 + [loss_sum.reshape(1)]
+                                 + [jnp.asarray(states[i].data)
+                                    .astype(jnp.float32).reshape(-1)
+                                    for i in fstate_ix]
+                                 + [jnp.asarray(mleaves[i])
+                                    .astype(jnp.float32).reshape(-1)
+                                    for i in fout_ix])
+                        sizes = [int(np.prod(p.shape)) for p in parts]
+                        flat = (jnp.concatenate(parts)
+                                if len(parts) > 1 else parts[0])
+                        red = jax.lax.psum(flat, ax)
+                        pieces, off = [], 0
+                        for sz in sizes:
+                            pieces.append(red[off:off + sz])
+                            off += sz
+                        k = len(acc)
+                        acc = [pc.reshape(p.data.shape)
+                               for pc, p in zip(pieces[:k], order)]
+                        loss_sum = pieces[k].reshape(())
+                        k += 1
+                        for j, i in enumerate(fstate_ix):
+                            orig = states[i].data
+                            states[i].data = (
+                                (pieces[k + j] / ndev)
+                                .astype(orig.dtype).reshape(orig.shape))
+                        k += len(fstate_ix)
+                        for j, i in enumerate(fout_ix):
+                            orig = mleaves[i]
+                            mleaves[i] = (
+                                (pieces[k + j] / ndev)
+                                .astype(orig.dtype).reshape(orig.shape))
+                        merged = jax.tree_util.tree_unflatten(mtree,
+                                                              mleaves)
                     # one apply on the global mean (n * ndev
                     # microbatches contributed to the sums)
                     opt.apply_accumulated(

@@ -17,8 +17,8 @@ what justifies decomposition choices). Three pieces:
     context, nothing is recorded, nothing allocates. Spans are
     pre-wired through the whole step path (`data.BatchIter`
     data-wait, eager `train_one_batch` + the fused optimizer apply,
-    `_JitStep` dispatch vs `block_until_ready` device-sync,
-    `ShardedJitStep` shard placement, `run_resumable`
+    the compiled step's phases (below), `run_resumable`'s loss read
+    (`device_sync`: the one place a loop waits for the device) and
     checkpoint save/restore). Enable: `device.set_tracing(True)`.
     Export: `export_chrome_trace(path)` (Chrome trace-event /
     Perfetto JSON) or the per-step `format_summary()` table.
@@ -28,6 +28,13 @@ what justifies decomposition choices). Three pieces:
     lands in the trace's `/host:CPU` plane on the device trace's
     clock, on the thread that did the work, under the device's
     operations. No session running, it costs under a microsecond.
+  - **Phases** — `phase(name)`: what a job does once a step, the
+    compiled step's `step.call` / `step.place` / `step.enqueue` /
+    `step.bind` (`model._JitStep.__call__`; `step` alone stays the
+    loop's own `step_span`, one a step). A phase is such an
+    annotation ALWAYS, so a profiler session over a live job shows
+    them with no switch thrown, and a span in the ring while the
+    tracer is on. None of them waits for the device.
   - **MetricsLogger** — one schema-stable JSONL record per training
     step (step, loss, examples/sec, data-wait / dispatch /
     device-sync seconds, `cache_stats` counter deltas,
@@ -61,6 +68,7 @@ __all__ = [
     "get_config",
     "enabled",
     "span",
+    "phase",
     "record_span",
     "step_span",
     "records",
@@ -79,7 +87,6 @@ __all__ = [
     "OffsetEstimator",
     "MetricsLogger",
     "read_metrics",
-    "default_metrics_path",
 ]
 
 # v2 (ISSUE 15): records additionally carry the writer `pid` and a
@@ -347,13 +354,8 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        global _ANNOTATION
-        if _ANNOTATION is None:
-            from jax.profiler import TraceAnnotation
-
-            _ANNOTATION = TraceAnnotation
         # the span's args stay out of the name: readers match on it
-        self._ann = _ANNOTATION("singa:" + self.name)
+        self._ann = _annotation()("singa:" + self.name)
         self._ann.__enter__()
         st = _stack()
         self.depth = len(st)
@@ -428,6 +430,28 @@ def span(name: str, **args):
     if not _ENABLED:
         return _NULL
     return _Span(name, args or None)
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def phase(name: str):
+    """Context manager for a once-a-step host phase. Tracer on or
+    off it is a `jax.profiler.TraceAnnotation("singa:" + name)`: any
+    profiler session, a benchmark's traced window or an operator's
+    capture of a live job, shows it on the host thread under the
+    device's operations. While the tracer is on it is a `span` (ring,
+    step frame) as well. For the few phases of a step, not for hot
+    paths: unlike a disabled `span()` it allocates."""
+    if _ENABLED:
+        return _Span(name, None)
+    return _annotation()("singa:" + name)
 
 
 def record_span(name: str, t0: float, t1: float, trace=None,
@@ -509,7 +533,9 @@ class _StepCtx:
             "step": self.step,
             "step_s": wall,
             "data_wait_s": acc.get("data_wait", 0.0),
-            "dispatch_s": acc.get("dispatch", 0.0),
+            # the compiled step's enqueue phase; the loop's own wait
+            # where it reads the loss (`resilience.run_resumable`)
+            "dispatch_s": acc.get("step.enqueue", 0.0),
             "device_sync_s": acc.get("device_sync", 0.0),
         }
         with _LOCK:
@@ -520,10 +546,11 @@ class _StepCtx:
 
 
 def step_span(step=None):
-    """Context manager for ONE training step. While tracing is enabled
-    it opens a "step" span whose children (data_wait / dispatch /
-    device_sync, emitted by the wired step path) become the per-step
-    decomposition. A strict no-op when tracing is off."""
+    """Context manager for ONE training step of a loop. While tracing
+    is enabled it opens a "step" span whose children (data_wait /
+    step.enqueue / device_sync, emitted by the wired step path) become
+    the per-step decomposition; the compiled step's own `step.call`
+    phase nests inside it. A strict no-op when tracing is off."""
     if not _ENABLED:
         return _NULL
     return _StepCtx(step)
@@ -739,16 +766,6 @@ def format_summary() -> str:
 # ---------------------------------------------------------------------------
 # Structured metrics log (JSONL, one record per train step).
 # ---------------------------------------------------------------------------
-def default_metrics_path(tag: str) -> str:
-    """`$SINGA_TPU_METRICS_DIR/<tag>.jsonl` (default dir: ./metrics),
-    created on demand — the directory `tools/fleet_top.py` and
-    `tools/metrics_lint.py --dir` read by default."""
-    d = os.environ.get("SINGA_TPU_METRICS_DIR") or os.path.join(
-        os.getcwd(), "metrics")
-    os.makedirs(d, exist_ok=True)
-    return os.path.join(d, f"{tag}.jsonl")
-
-
 def _json_default(v):
     if isinstance(v, (np.floating, np.integer)):
         return v.item()

@@ -205,14 +205,27 @@ def test_chrome_export_is_spec_conformant(tmp_path):
 
 def test_step_decomposes_into_data_wait_dispatch_device_sync(tmp_path):
     """The acceptance shape: a graph-mode train step's chrome span
-    nests data_wait + dispatch + device_sync children."""
+    nests data_wait, the compiled step's phases and the loop's own
+    device_sync (its loss read, as `fit_resumable` spans it) — and the
+    traced step itself never fences the device."""
+    import jax
+
     device.set_tracing(True)
     m, tx, ty = _build(use_graph=True)
-    for k in range(3):
-        with trace.step_span(k):
-            with trace.span("data_wait"):
-                pass  # batch already device-resident
-            m(tx, ty)
+    fences = []
+    real = jax.block_until_ready
+    jax.block_until_ready = lambda x: (fences.append(1), real(x))[1]
+    try:
+        for k in range(3):
+            with trace.step_span(k):
+                with trace.span("data_wait"):
+                    pass  # batch already device-resident
+                _, loss = m(tx, ty)
+                assert not fences, "a traced graph step fenced"
+                with trace.span("device_sync"):
+                    float(loss.to_numpy())
+    finally:
+        jax.block_until_ready = real
     path = trace.export_chrome_trace(str(tmp_path / "steps.json"))
     with open(path) as f:
         evs = json.load(f)["traceEvents"]
@@ -223,13 +236,19 @@ def test_step_decomposes_into_data_wait_dispatch_device_sync(tmp_path):
     kids = {e["name"] for e in evs
             if e is not last and last["ts"] <= e["ts"]
             and e["ts"] + e["dur"] <= last["ts"] + last["dur"] + 1e-3}
-    assert {"data_wait", "dispatch", "device_sync"} <= kids, kids
+    assert {"data_wait", "step.call", "step.place", "step.enqueue",
+            "step.bind", "device_sync"} <= kids, kids
+    call = [e for e in evs if e["name"] == "step.call"][-1]
+    for name in ("step.place", "step.enqueue", "step.bind"):
+        e = [e for e in evs if e["name"] == name][-1]
+        assert call["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= call["ts"] + call["dur"] + 1e-3, name
     t = trace.last_step_timings()
     assert t["step"] == 2 and t["step_s"] > 0
     assert t["dispatch_s"] > 0 and t["device_sync_s"] > 0
     # the summary table renders every wired span
     s = trace.format_summary()
-    for name in ("step", "dispatch", "device_sync", "data_wait"):
+    for name in ("step", "step.enqueue", "device_sync", "data_wait"):
         assert name in s
 
 
@@ -393,6 +412,60 @@ def test_spans_of_a_worker_thread_reach_the_profilers_host_plane(
     assert [r["name"] for r in trace.records()] == ["inner", "outer"] * 3
 
 
+@pytest.mark.parametrize("tracer", [False, True])
+def test_step_phases_reach_a_profiler_session_with_the_tracer_off(
+        tmp_path, tracer):
+    """Live, on the CPU backend: a compiled step's four phases are in
+    a running profiler session's /host:CPU plane whether or not the
+    program's tracer is on, nested as `_JitStep.__call__` runs them,
+    and in the ring only while it is on."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    device.set_tracing(tracer)
+    m, tx, ty = _build(use_graph=True)
+    m(tx, ty)                           # compile outside the session
+    trace.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            _, loss = m(tx, ty)
+        float(loss.to_numpy())
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    mine = sorted(
+        ((e.name, e.start_ns, e.start_ns + e.duration_ns)
+         for plane in ProfileData.from_file(path).planes
+         if plane.name == "/host:CPU" for line in plane.lines
+         for e in line.events if e.name.startswith("singa:step.")),
+        key=lambda e: (e[1], -e[2]))
+    assert [e[0] for e in mine] == [
+        "singa:step.call", "singa:step.place", "singa:step.enqueue",
+        "singa:step.bind"] * 3
+    for i in range(0, 12, 4):
+        call, rest = mine[i], mine[i + 1:i + 4]
+        assert all(call[1] <= e[1] and e[2] <= call[2] for e in rest)
+        assert all(a[2] <= b[1] for a, b in zip(rest, rest[1:]))
+    ring = [r["name"] for r in trace.records()]
+    assert ring == (["step.place", "step.enqueue", "step.bind",
+                     "step.call"] * 3 if tracer else [])
+
+
+def test_phase_is_a_span_only_while_the_tracer_is_on():
+    with trace.phase("step.call"):
+        pass
+    assert trace.records() == []
+    assert trace.span("x") is trace.span("y")      # the shared null
+    device.set_tracing(True)
+    with trace.phase("step.call"):
+        pass
+    assert [r["name"] for r in trace.records()] == ["step.call"]
+
+
 # ---------------------------------------------------------------------------
 # Metrics JSONL
 # ---------------------------------------------------------------------------
@@ -426,7 +499,7 @@ def test_metrics_one_schema_stable_record_per_step(tmp_path, mode):
     names = {r["name"] for r in trace.records()}
     assert "checkpoint_restore" in names and "checkpoint_save" in names
     if mode == "mesh":
-        assert "shard_place" in names
+        assert "step.place" in names
     # step spans: one per executed step
     assert sum(1 for r in trace.records() if r["name"] == "step") == 4
 
