@@ -1613,8 +1613,11 @@ class SoftMaxCrossEntropy(Operator):
         # Loss math always in fp32 (bf16 logsumexp loses ~2 decimal
         # digits); under AMP the incoming logits are bf16. backward
         # returns dx in the original dtype so the vjp chain stays bf16.
+        # Where the float32 lives: on the jnp path in HBM (the cast
+        # below); on the Pallas path only in the kernels' VMEM, a block
+        # of rows at a time (the logits, the residual and dx cross HBM
+        # in the dtype they came in, PERF.md PR 38).
         self._in_dtype = x.dtype
-        x = x.astype(jnp.float32) if x.dtype != jnp.float32 else x
         int_labels = t.ndim == x.ndim - 1 or (
             t.ndim == x.ndim and t.shape[-1] == 1)
         n = x.shape[0] if x.ndim > 1 else 1
@@ -1628,6 +1631,7 @@ class SoftMaxCrossEntropy(Operator):
             lab = jnp.reshape(t, (x.shape[0],)).astype(jnp.int32)
             self._pallas_res = (x, lab)
             return jnp.sum(_pk.softmax_xent(x, lab)) / n
+        x = x.astype(jnp.float32) if x.dtype != jnp.float32 else x
         self._pallas_res = None
         self._valid = None
         traced = isinstance(x, jax.core.Tracer)
@@ -1658,7 +1662,7 @@ class SoftMaxCrossEntropy(Operator):
             x, lab = self._pallas_res
             g = jnp.full((x.shape[0],), dy / self._n, jnp.float32)
             dx, _ = _pk._softmax_xent_bwd((x, lab), g)
-            return dx.astype(self._in_dtype)
+            return dx
         if not isinstance(dy, jax.core.Tracer) and not isinstance(
                 self._p, jax.core.Tracer):
             dyf = jnp.asarray(dy, jnp.float32)

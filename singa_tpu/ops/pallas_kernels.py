@@ -11,7 +11,9 @@ those kernels:
     backward (KernelSoftmaxCrossEntropy / KernelSoftmaxCrossEntropyBwd
     equivalents). One HBM round-trip for the whole loss instead of
     separate softmax / gather / reduce programs; the backward
-    recomputes probs in-VMEM (no softmax residual in HBM).
+    recomputes probs in-VMEM (no softmax residual in HBM). Logits,
+    residual and gradient cross HBM in the logits' own dtype
+    (bfloat16 under AMP); the arithmetic is float32 in VMEM.
   * `dropout` — mask generation with the TPU's on-core PRNG
     (pltpu.prng_random_bits) fused with the scale-and-mask multiply
     (KernelDropout equivalent).
@@ -40,15 +42,22 @@ from jax.experimental.pallas import tpu as pltpu
 _ENABLED = os.environ.get("SINGA_TPU_PALLAS", "0") == "1"
 
 # Per-kernel policy (VERDICT r4 next #3: "make every Pallas kernel pay
-# or cut it").  Measured on the v5e (benchmarks/PALLAS_BENCH.md):
-# fused softmax-xent wins at every tested shape (1.07-1.80x) and flash
-# attention wins from seq >= ~1024 (1.14-1.27x; 0.98x at 512), so the
-# default tier routes ONLY those, with the attention crossover
-# enforced by `attn_supported`.  The on-core-PRNG dropout (0.94x) and
-# the histogram top-K sparsifier (0.89-1.03x) sit at parity with
-# XLA's own fusion — they remain correct, tested, and available, but
-# engage only with SINGA_TPU_PALLAS_ALL=1 (or `enable_all`) so the
-# default tier never trades a measured win for a measured loss.
+# or cut it").  The default tier routes ONLY the fused softmax-xent
+# and flash attention, the latter from seq >= ~1024 (`attn_supported`).
+# What says the xent kernels pay is the trace of the one cell that runs
+# them, `gpt2-train-seq1024` (PERF.md section 5): over bfloat16
+# [8192, 50257] logits they move them once a pass in that dtype, at
+# the rate the HBM gives a streaming kernel, where the cast-first
+# operator before PR 38 also paid a float32 copy of the logits and a
+# convert of the d-logits back.  (benchmarks/PALLAS_BENCH.md's
+# 1.07-1.80x for this kernel and 1.14-1.27x for flash attention were
+# measured on float32 operands before PR 1, on another installation,
+# and stand on no ledger line; ROADMAP A2 re-measures attention.)  The
+# on-core-PRNG dropout (0.94x there) and the histogram top-K
+# sparsifier (0.89-1.03x) sat at parity with XLA's own fusion — they
+# remain correct, tested, and available, but engage only with
+# SINGA_TPU_PALLAS_ALL=1 (or `enable_all`) so the default tier never
+# trades a measured win for a measured loss.
 _ALL = os.environ.get("SINGA_TPU_PALLAS_ALL", "0") == "1"
 # ALL implies the tier itself: opting into the parity kernels with
 # only SINGA_TPU_PALLAS_ALL=1 must not be a silent no-op.
@@ -99,14 +108,24 @@ _ROW_BUDGET = int(os.environ.get("SINGA_TPU_ROW_BUDGET", str(1 << 19)))
 _HIST_BUDGET = int(os.environ.get("SINGA_TPU_HIST_BUDGET", str(1 << 13)))
 
 
-def _row_tile(batch: int, ncol: int, budget: int = 0) -> int:
-    """Rows per block: keep a block under ~budget elements, multiple
-    of 8 (f32 sublane)."""
+def _sublane(dtype) -> int:
+    """Rows of a dtype's smallest tile: 8 of float32, 16 of a 2-byte
+    dtype (two rows share a sublane), 32 of a 1-byte one."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _row_tile(batch: int, ncol: int, budget: int = 0,
+              dtype=jnp.float32) -> int:
+    """Rows per block: keep a block under ~budget elements, a multiple
+    of the dtype's sublane tile and never under one (a row wider than
+    the budget still gets a block Mosaic can lay out), or the whole
+    batch where that is smaller."""
     budget = budget or _ROW_BUDGET
-    rows = max(1, budget // max(ncol, 1))
+    sub = _sublane(dtype)
+    rows = max(sub, budget // max(ncol, 1))
     rows = min(batch, rows)
-    if rows >= 8:
-        rows -= rows % 8
+    if rows >= sub:
+        rows -= rows % sub
     return max(rows, 1)
 
 
@@ -152,56 +171,71 @@ def _pad_rows(a, tile):
     return a, b
 
 
+_VMEM_DEFAULT = 16 << 20   # what Mosaic scopes a kernel to, unasked
+# float32 arrays of a block's size the limit leaves room for: Mosaic
+# holds two (compiled for a described v5e, the backward is refused
+# with room for one); the third is to spare
+_XENT_TEMPORARIES = 3
+
+
+def _xent_call(kernel, name, logits, per_row, wide):
+    """One of the two kernels over whole rows of `logits` [B, C], in
+    the dtype they come in: a block is as many rows as the element
+    budget gives in tiles of that dtype (at 50257 classes one tile: 8
+    rows of float32, 16 of bfloat16), and float32 exists only in the
+    kernel's VMEM. `per_row`: [B, 1] arrays; the result is `wide`
+    ([B, C] in the logits' dtype) or a float32 [B, 1]. The limit asked
+    of Mosaic is the block's own arithmetic: the logits' block and a
+    wide result's, each twice (the pipeline's), and the float32
+    temporaries; under 16 MiB, what a kernel gets unasked, it asks for
+    that."""
+    b, c = logits.shape
+    tile = _row_tile(b, c, dtype=logits.dtype)
+    sub = _sublane(logits.dtype)
+    block = -(-tile // sub) * sub * -(-c // 128) * 128   # as VMEM pads it
+    held = block * ((4 if wide else 2) * logits.dtype.itemsize
+                    + 4 * _XENT_TEMPORARIES)
+    row = pl.BlockSpec((tile, 1), lambda i: (i, 0))
+    whole = pl.BlockSpec((tile, c), lambda i: (i, 0))
+    xp, _ = _pad_rows(logits, tile)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(
+            (xp.shape[0], c if wide else 1),
+            logits.dtype if wide else jnp.float32),
+        grid=(xp.shape[0] // tile,),
+        in_specs=[whole] + [row] * len(per_row),
+        out_specs=whole if wide else row,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(_VMEM_DEFAULT, held + (2 << 20))),
+        interpret=_interpret(), name=name,
+    )(xp, *(_pad_rows(a, tile)[0] for a in per_row))[:b]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=())
 def softmax_xent(logits, labels):
     """Per-row cross-entropy loss, fused. logits (B, C) float,
-    labels (B,) int -> (B,) float32. Mean/scale is the caller's."""
+    labels (B,) int -> (B,) float32. Mean/scale is the caller's. The
+    logits cross HBM once a pass in the dtype they have; the gradient
+    comes back in it."""
     loss, _ = _softmax_xent_fwd(logits, labels)
     return loss
 
 
 def _softmax_xent_fwd(logits, labels):
-    b, c = logits.shape
-    tile = _row_tile(b, c)
-    lab2 = labels.reshape(b, 1).astype(jnp.int32)
-    xp, b0 = _pad_rows(logits, tile)
-    lp, _ = _pad_rows(lab2, tile)
-    grid = (xp.shape[0] // tile,)
-    loss = pl.pallas_call(
-        _xent_fwd_kernel,
-        out_shape=jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32),
-        grid=grid,
-        in_specs=[pl.BlockSpec((tile, c), lambda i: (i, 0)),
-                  pl.BlockSpec((tile, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-        interpret=_interpret(),
-        name="softmax_xent_fwd",
-    )(xp, lp)
-    return loss[:b0, 0], (logits, labels)
+    lab2 = labels.reshape(-1, 1).astype(jnp.int32)
+    loss = _xent_call(_xent_fwd_kernel, "softmax_xent_fwd", logits,
+                      (lab2,), wide=False)
+    return loss[:, 0], (logits, labels)
 
 
 def _softmax_xent_bwd(res, g):
     logits, labels = res
-    b, c = logits.shape
-    tile = _row_tile(b, c)
-    lab2 = labels.reshape(b, 1).astype(jnp.int32)
-    g2 = g.reshape(b, 1).astype(jnp.float32)
-    xp, b0 = _pad_rows(logits, tile)
-    lp, _ = _pad_rows(lab2, tile)
-    gp, _ = _pad_rows(g2, tile)
-    grid = (xp.shape[0] // tile,)
-    dx = pl.pallas_call(
-        _xent_bwd_kernel,
-        out_shape=jax.ShapeDtypeStruct(xp.shape, logits.dtype),
-        grid=grid,
-        in_specs=[pl.BlockSpec((tile, c), lambda i: (i, 0)),
-                  pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((tile, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tile, c), lambda i: (i, 0)),
-        interpret=_interpret(),
-        name="softmax_xent_bwd",
-    )(xp, lp, gp)
-    return dx[:b0], None
+    lab2 = labels.reshape(-1, 1).astype(jnp.int32)
+    g2 = g.reshape(-1, 1).astype(jnp.float32)
+    dx = _xent_call(_xent_bwd_kernel, "softmax_xent_bwd", logits,
+                    (lab2, g2), wide=True)
+    return dx, None
 
 
 softmax_xent.defvjp(_softmax_xent_fwd, _softmax_xent_bwd)

@@ -88,6 +88,101 @@ class TestSoftmaxXent:
         want = -jax.nn.log_softmax(x, -1)[jnp.arange(b), lab]
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("rows", [8, 24, 64])
+    @pytest.mark.parametrize("classes", [10, 1000, 50257])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bfloat16", "float32"])
+    def test_logits_in_their_own_dtype_give_the_cast_first_result(
+            self, dtype, classes, rows):
+        """The kernels take the logits in the dtype they come in (the
+        block's rows in tiles of it: 16 of bfloat16, 8 of float32; 24
+        rows are padded, 8 are one short block) and upcast in VMEM:
+        the loss is bit-equal to the one from logits cast to float32
+        first, as the operator did before ISSUE 38, and dx is that
+        path's dx rounded to the logits' dtype, written once. Padding
+        rows (label -1, label == classes) keep zero loss and zero
+        gradient; `jax.grad` through the `custom_vjp` is the direct
+        call."""
+        rs = np.random.RandomState(classes + rows)
+        x = jnp.asarray(3 * rs.randn(rows, classes), jnp.float32
+                        ).astype(dtype)
+        lab = rs.randint(0, classes, rows).astype(np.int32)
+        lab[1], lab[3] = -1, classes
+        lab = jnp.asarray(lab)
+        g = jnp.asarray(rs.rand(rows).astype(np.float32))
+
+        cast = x.astype(jnp.float32)
+        loss = pk.softmax_xent(x, lab)
+        dx, _ = pk._softmax_xent_bwd((x, lab), g)
+        want_dx, _ = pk._softmax_xent_bwd((cast, lab), g)
+        assert loss.dtype == jnp.float32 and dx.dtype == dtype
+        np.testing.assert_array_equal(loss, pk.softmax_xent(cast, lab))
+        np.testing.assert_array_equal(
+            np.asarray(dx, np.float32),
+            np.asarray(want_dx.astype(dtype), np.float32))
+        # the mathematics, not only the agreement of two calls
+        ref = -jax.nn.log_softmax(cast, -1)[
+            jnp.arange(rows), jnp.clip(lab, 0, classes - 1)]
+        pad = np.array([1, 3])
+        np.testing.assert_allclose(np.delete(loss, pad),
+                                   np.delete(ref, pad), rtol=1e-5,
+                                   atol=1e-5)
+        assert not np.asarray(loss)[pad].any()
+        assert not np.asarray(dx, np.float32)[pad].any()
+        via_vjp = jax.grad(
+            lambda a: jnp.sum(pk.softmax_xent(a, lab) * g))(x)
+        np.testing.assert_array_equal(np.asarray(via_vjp, np.float32),
+                                      np.asarray(dx, np.float32))
+
+    def test_operator_keeps_bfloat16_logits_out_of_float32(self):
+        """With the tier on, loss-and-gradient of `SoftMaxCrossEntropy`
+        over bfloat16 logits holds no float32 array of the logits'
+        shape: they go to the kernels, stay the residual and come back
+        as dx in bfloat16 (the mechanism engaged, in test form). With
+        the tier off the jnp branch casts first, as it always did."""
+        rs = np.random.RandomState(9)
+        x = jnp.asarray(rs.randn(32, 40), jnp.float32).astype(jnp.bfloat16)
+        t = jnp.asarray(rs.randint(0, 40, 32).astype(np.int32))
+
+        def loss_and_grad():
+            # a function of its own a trace: jax keeps a function's
+            # jaxpr, and the tier's switch is not among its arguments
+            def f(x):
+                op = autograd.SoftMaxCrossEntropy(t)
+                loss = op.forward(x)
+                return loss, op.backward(jnp.float32(1.0))
+            return f
+
+        def float32_logits(jaxpr):
+            # everything that lives in HBM: the program's own values
+            # and those of what it calls, but not a kernel's body
+            found = []
+            for eqn in jaxpr.eqns:
+                found += [v.aval for v in eqn.outvars
+                          if v.aval.shape == x.shape
+                          and v.aval.dtype == jnp.float32]
+                if eqn.primitive.name != "pallas_call":
+                    for sub in jax.core.jaxprs_in_params(eqn.params):
+                        found += float32_logits(sub)
+            return found
+
+        on = jax.make_jaxpr(loss_and_grad())(x)
+        assert "pallas_call" in str(on)
+        assert not float32_logits(on.jaxpr), on
+        assert [v.aval.dtype for v in on.jaxpr.outvars] == [
+            jnp.float32, jnp.bfloat16]
+        l_on, g_on = loss_and_grad()(x)
+        pk.enable(False)
+        off = jax.make_jaxpr(loss_and_grad())(x)
+        assert float32_logits(off.jaxpr)
+        assert "pallas_call" not in str(off)
+        l_off, g_off = loss_and_grad()(x)
+        assert g_on.dtype == g_off.dtype == jnp.bfloat16
+        assert abs(float(l_on) - float(l_off)) <= 1e-5
+        np.testing.assert_allclose(np.asarray(g_on, np.float32),
+                                   np.asarray(g_off, np.float32),
+                                   rtol=1e-2, atol=1e-6)
+
 
 class TestTopKSparsify:
     def test_threshold_keeps_at_least_k(self):

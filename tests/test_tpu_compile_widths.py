@@ -464,6 +464,47 @@ def test_the_token_program_is_the_step_and_an_argmax(request, cell_,
     assert " while(" not in token.as_text()
 
 
+def _training_step(config, builder, example, batch, one_chip):
+    """A configuration's training step at its `train` policy, compiled
+    for the described chip over `batch` (shapes): the program the first
+    `model(x, y)` of `perfbench/drivers/train.py` makes, kernels
+    lowered by Mosaic. `example`: a tiny host batch to build the
+    model's parameters from."""
+    import jax
+
+    from perfbench.harness import cell
+    from singa_tpu import device, tensor
+    from singa_tpu.model import _JitStep
+    from singa_tpu.ops import pallas_kernels
+
+    saved = (tensor.get_matmul_precision(), tensor.get_compute_dtype(),
+             pallas_kernels.enabled())
+    monkey = pytest.MonkeyPatch()
+    monkey.setattr(pallas_kernels, "_interpret", lambda: False)
+    try:
+        cell.set_policies(config["train"])
+        dev = device.get_default_device()
+        model = cell.build(builder)
+        model.set_optimizer(cell.build(config["train"]["optimizer"]))
+        model.compile([tensor.from_numpy(example, device=dev)],
+                      is_train=True, use_graph=True)
+        step = _JitStep(model)     # as the first `model(x, y)` makes it
+
+        def sds(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+        return step._build(*batch).lower(
+            [sds(p.data) for p in step.params],
+            [sds(s.data) for s in step.states],
+            [sds(o) for o in step._opt_arrays()], sds(dev._rng_key), 0,
+            tuple(sds(a) for a in batch)).compile()
+    finally:
+        monkey.undo()
+        tensor.set_matmul_precision(saved[0])
+        tensor.set_compute_dtype(saved[1])
+        pallas_kernels.enable(saved[2])
+
+
 def test_the_one_chip_resnet_step_holds_what_the_memory_meter_misses(
         one_chip):
     """The one-chip ResNet-50 step at batch 256 (ISSUE 27's second
@@ -474,43 +515,82 @@ def test_the_one_chip_resnet_step_holds_what_the_memory_meter_misses(
     there is this compile's)."""
     import jax
 
-    from perfbench.harness import cell
-    from singa_tpu import device, tensor
-    from singa_tpu.model import _JitStep
-    from singa_tpu.ops import pallas_kernels
-
     with open(os.path.join(os.path.dirname(CONFIG), "resnet50.json")) as f:
         config = json.load(f)
-    saved = (tensor.get_matmul_precision(), tensor.get_compute_dtype(),
-             pallas_kernels.enabled())
-    try:
-        cell.set_policies(config["train"])
-        dev = device.get_default_device()
-        model = cell.build(config["builder"])
-        model.set_optimizer(cell.build(config["train"]["optimizer"]))
-        model.compile([tensor.from_numpy(
-            np.zeros((2, 3, 224, 224), np.float32), device=dev)],
-            is_train=True, use_graph=True)
-        step = _JitStep(model)     # as the first `model(x, y)` makes it
-
-        def sds(a):
-            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-
-        B = 256
-        batch = (jax.ShapeDtypeStruct((B, 3, 224, 224), np.float32,
-                                      sharding=one_chip),
-                 jax.ShapeDtypeStruct((B,), np.int32, sharding=one_chip))
-        compiled = step._build(*batch).lower(
-            [sds(p.data) for p in step.params],
-            [sds(s.data) for s in step.states],
-            [sds(o) for o in step._opt_arrays()], sds(dev._rng_key), 0,
-            batch).compile()
-    finally:
-        tensor.set_matmul_precision(saved[0])
-        tensor.set_compute_dtype(saved[1])
-        pallas_kernels.enable(saved[2])
+    B = 256
+    compiled = _training_step(
+        config, config["builder"], np.zeros((2, 3, 224, 224), np.float32),
+        (jax.ShapeDtypeStruct((B, 3, 224, 224), np.float32),
+         jax.ShapeDtypeStruct((B,), np.int32)), one_chip)
     m = _fits(compiled, f"ResNet-50 training step of {B}")
     assert m.temp_size_in_bytes > 0.25 * 16 * 2**30
+
+
+def test_the_gpt2_training_step_moves_its_logits_in_bfloat16(one_chip):
+    """`gpt2-train-seq1024`'s step (ISSUE 38): the cell's policy
+    (`perfbench/configs/gpt2.json` `train`: bfloat16 AMP, the Pallas
+    tier on, Adam), 8 x 1,024 tokens, the published widths, two layers
+    (depth does not touch the head). Both fused softmax-xent kernels
+    lower under Mosaic at bfloat16 [8192, 50257], 16 whole rows a
+    block, and no float32 array of the logits' size is left: the cast
+    in front of the forward kernel, the float32 d-logits behind the
+    backward one and the convert back are gone. What stays of that
+    size, all bfloat16: the tied head's product, which XLA writes
+    vocabulary-major; its two relayout copies, one into the row-major
+    operand a kernel must have and one into the layout the compiler
+    gives the step's result (`out, loss = model(x, y)` returns the
+    logits); the backward kernel's d-logits (PERF.md, PR 38)."""
+    import jax
+
+    with open(GPT2) as f:
+        config = json.load(f)
+    B, S, V = 8, 1024, config["vocab_size"]
+    tokens = jax.ShapeDtypeStruct((B, S), np.int32)
+    compiled = _training_step(
+        config, dict(config["builder"], kwargs=dict(
+            config["builder"]["kwargs"], num_layers=2)),
+        np.zeros((1, 8), np.int32), (tokens, tokens), one_chip)
+    m = _fits(compiled, "GPT-2 training step of 8 x 1024, two layers")
+    # 3.91 GB with the float32 logits and d-logits live together
+    assert m.temp_size_in_bytes < 2.6e9
+    text = compiled.as_text()
+    logits = rf"\[(?:{B * S}|{B},{S}),{V}\]"
+    assert not re.search("f32" + logits, text)
+    entry = text[text.index("\nENTRY "):]
+    for kernel in ("softmax_xent_fwd", "softmax_xent_bwd"):
+        (call,) = re.findall(rf"%{kernel}\S* = .*custom-call\((.*)", entry)
+        assert f"bf16[{B * S},{V}]" in call, call
+    held = re.findall(rf"= bf16{logits}\S* ([\w-]+)\(", entry)
+    assert sorted(set(held) - {"bitcast"}) == ["copy", "custom-call",
+                                               "fusion"], held
+    assert held.count("copy") == 2, held
+
+
+@pytest.mark.parametrize("rows,classes", [(8, 10), (24, 1000), (128, 1000),
+                                          (64, 50257)])
+@pytest.mark.parametrize("dtype,hlo", [("bfloat16", "bf16"),
+                                       ("float32", "f32")])
+def test_xent_kernels_lower_for_a_classifier_under_amp(
+        one_chip, monkeypatch, dtype, hlo, rows, classes):
+    """Who else runs the xent kernels (ISSUE 38): a classifier's
+    [B, 10] or [B, 1000] logits, bfloat16 under AMP. Mosaic takes the
+    block the dtype's tile gives: 8 rows of bfloat16 are the whole
+    (short) batch, 24 are padded to two blocks of 16, 128 are one
+    block; float32 keeps the tile it had."""
+    import jax
+
+    from singa_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((rows, classes), dtype, sharding=one_chip)
+    lab = jax.ShapeDtypeStruct((rows,), np.int32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((rows,), np.float32, sharding=one_chip)
+    fwd = jax.jit(pk.softmax_xent).lower(x, lab).compile()
+    bwd = jax.jit(lambda x, lab, g: pk._softmax_xent_bwd(
+        (x, lab), g)[0]).lower(x, lab, g).compile()
+    assert "softmax_xent_fwd" in fwd.as_text()
+    assert re.search(rf"%softmax_xent_bwd\S* = {hlo}\[\d+,{classes}\]",
+                     bwd.as_text())
 
 
 # -- EvaByte's served programs at their real widths (ISSUE 35) -----------------
