@@ -34,6 +34,16 @@ class DecodeLM(model.Model):
     # `lax.scan`'s unroll of a run-ahead block's steps (1: a loop)
     scan_unroll = 1
 
+    def __init_subclass__(cls, **kwargs):
+        """A model's counter names read 0 in `cache_stats()["decode"]`
+        from its class's definition on, so a snapshot taken before its
+        first step already holds them."""
+        super().__init_subclass__(**kwargs)
+        from .. import stats
+
+        for name in cls.step_counter_names:
+            stats.decode_stats().step_counters.setdefault(name, 0)
+
     def _trace_key(self):
         """What a decode program closes over besides its arguments'
         shapes: the precision policy and the norms' `eps`."""

@@ -13,7 +13,7 @@ the slab holds, for the messages of what is not implemented.
 
 `DrawnDecodeLM` is what EVERY such model shares (the draw, the norm,
 `forward`, the not-implemented messages); `RoutedDrawnLM` adds what
-the two routed models share (the held range of experts, the one routed
+the routed models share (the held range of experts, the one routed
 layer with its constants, the three `moe_*` step counters). A dense
 model (`chunked_attn.py`) carries none of that.
 """
@@ -286,8 +286,9 @@ class DrawnDecodeLM(DecodeLM):
 
 
 class RoutedDrawnLM(DrawnDecodeLM):
-    """Base of `HybridWindowMoELM` and `ShortConvMoELM`: a drawn model
-    with routed layers, of which this chip holds a range of experts."""
+    """Base of `HybridWindowMoELM`, `ShortConvMoELM` and
+    `BlockSparseMoELM`: a drawn model with routed layers, of which this
+    chip holds a range of experts."""
 
     # a run-ahead block is its steps in a row, not a loop: around a
     # loop XLA re-lays every held expert's gate and up matrices out
@@ -298,9 +299,13 @@ class RoutedDrawnLM(DrawnDecodeLM):
     step_counter_names = ("moe_assignments_local", "moe_experts_touched",
                           "moe_expert_load_max")
     # `routed_experts`'s: one constant for every model that calls it,
-    # and the number in its normalising sum an architecture may set
+    # and what an architecture may set: the number in its normalising
+    # sum, its experts' activation (None: silu(g) * u) and the scale
+    # of their part (`routed_scaling_factor`)
     dense_rows = DENSE_ROWS
     router_sum_eps = 0.0
+    expert_act = None
+    routed_scale = 1.0
     _one_chip_holds = "its share of the experts"
 
     def _init_drawn(self, vocab_size, max_len, norm_eps, param_dtype,
@@ -325,4 +330,5 @@ class RoutedDrawnLM(DrawnDecodeLM):
         return routed_experts(ffn, x, prec, held=self.held,
                               experts_per_token=self.experts_per_token,
                               dense_rows=self.dense_rows,
-                              sum_eps=self.router_sum_eps)
+                              sum_eps=self.router_sum_eps,
+                              act=self.expert_act, scale=self.routed_scale)

@@ -5,14 +5,18 @@ products of the experts this chip HOLDS.
 
     sig = sigmoid(x W_r) in float32;  S = top_k(sig + b)
     w_e = sig_e / (sum_S sig + sum_eps)
-    y   = sum_{e in S and held} w_e (silu(x G_e) * x U_e) D_e
+    y   = scale * sum_{e in S and held} w_e act(x G_e, x U_e) D_e
+
+`act(g, u)` is silu(g) * u unless an architecture names another
+(`swigluoai`), and `scale` its `routed_scaling_factor` (1: none).
 
 `held = (first, count)` names the held experts: `ffn["W_g"]`, `W_u`
 ([count, d, f]) and `W_d` ([count, f, d]) are theirs alone, while
 `W_r` [d, n_experts] and `b` [n_experts] keep the router's width. The
 layer drops no token at any imbalance. What differs between
 architectures is numbers, not code: how many experts and how many a
-token (the router's shapes) and `sum_eps` in the normalising sum.
+token (the router's shapes), `sum_eps` in the normalising sum, the
+activation and the scale.
 
 Two product paths, chosen by the row count (static): up to
 `dense_rows` rows every held expert runs over every row (one dense
@@ -29,11 +33,24 @@ import functools
 DENSE_ROWS = 256
 
 
+def swigluoai(g, u, alpha=1.702, limit=7.0):
+    """gpt-oss's clamped gate: min(g, limit) * sigmoid(alpha * min(g,
+    limit)) * (clip(u, -limit, limit) + 1), in float32, returned in
+    g's dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    gf = jnp.minimum(g.astype(jnp.float32), limit)
+    uf = jnp.clip(u.astype(jnp.float32), -limit, limit)
+    return (gf * jax.nn.sigmoid(alpha * gf) * (uf + 1.0)).astype(g.dtype)
+
+
 def routed_experts(ffn, x, prec, *, held, experts_per_token,
-                   dense_rows=DENSE_ROWS, sum_eps=0.0):
+                   dense_rows=DENSE_ROWS, sum_eps=0.0, act=None, scale=1.0):
     """The held experts' part of a routed layer for x [N, d], and the
     held experts' assignment counts [count] (from which a step's three
-    counters come: their sum, how many are not zero, their maximum)."""
+    counters come: their sum, how many are not zero, their maximum).
+    `act` None is silu(g) * u; `scale` multiplies the held part."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -65,9 +82,10 @@ def routed_experts(ffn, x, prec, *, held, experts_per_token,
                          [..., :E] * w[..., None], 1)      # [N,E]
             g = jnp.einsum("nd,edf->enf", x, ffn["W_g"], precision=prec)
             u = jnp.einsum("nd,edf->enf", x, ffn["W_u"], precision=prec)
-            a = jax.nn.silu(g) * u * cw.T[:, :, None].astype(x.dtype)
+            a = (jax.nn.silu(g) * u if act is None else act(g, u)) \
+                * cw.T[:, :, None].astype(x.dtype)
             y = jnp.einsum("enf,efd->nd", a, ffn["W_d"], precision=prec)
-            return y, counts
+            return _scaled(y, scale), counts
         # assignments sorted by expert (those routed elsewhere
         # last), each group through its expert
         flat = local.reshape(-1)
@@ -84,7 +102,10 @@ def routed_experts(ffn, x, prec, *, held, experts_per_token,
             to their tokens."""
             o = order[:rows]
             xs = x[o // K]
-            a = jax.nn.silu(rd(xs, ffn["W_g"])) * rd(xs, ffn["W_u"])
+            if act is None:
+                a = jax.nn.silu(rd(xs, ffn["W_g"])) * rd(xs, ffn["W_u"])
+            else:
+                a = act(rd(xs, ffn["W_g"]), rd(xs, ffn["W_u"]))
             y = rd(a, ffn["W_d"])                          # [rows,d]
             # rows past the held groups were multiplied by no
             # expert: whatever they hold is replaced, never weighted
@@ -96,7 +117,7 @@ def routed_experts(ffn, x, prec, *, held, experts_per_token,
 
         if all_held:
             # every assignment is local: straight through all of them
-            return through(N * K), counts
+            return _scaled(through(N * K), scale), counts
         # where the held experts are a small share of all and get
         # their share of the assignments, a quarter of the rows
         # carries the local ones at a quarter of the gathers; N*K
@@ -104,4 +125,8 @@ def routed_experts(ffn, x, prec, *, held, experts_per_token,
         few = N * K // 4
         y = lax.cond(counts.sum() <= few, lambda: through(few),
                      lambda: through(N * K))
-        return y, counts
+        return _scaled(y, scale), counts
+
+
+def _scaled(y, scale):
+    return y if scale == 1.0 else y * scale

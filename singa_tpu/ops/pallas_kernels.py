@@ -1244,3 +1244,109 @@ def window_summary_attend(q, k_buf, v_buf, sk, sv, at, seen):
         interpret=_interpret(),
     )(*_window_rows(at, seen, W, R), q.astype(jnp.float32),
       k_buf, v_buf, sk, sv)
+
+
+# -- one query a row and group against the blocks an indexer selected --------
+def _selected_blocks_kernel(sel_ref, n_ref, pos_ref, q_ref, *refs, scale, tb,
+                            groups, nsel):
+    """Grid step (b, j): of every group g of row b, the j-th selected
+    block of keys and values (the pipeline fetched it by the block id
+    in `sel`), folded into the group's running softmax; a block past
+    the group's count is its last one again (no fetch) and skipped.
+    The block that holds pos[b] is masked past it."""
+    k_refs, v_refs = refs[:groups], refs[groups:2 * groups]
+    o_ref, m_scr, l_scr, acc = refs[2 * groups:]
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, -1e30, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+    for g in range(groups):
+        @pl.when(j < n_ref[b * groups + g])
+        def _(g=g):
+            s = jax.lax.dot_general(
+                q_ref[0, g], k_refs[g][0, 0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [Hg, tb]
+            at = (sel_ref[(b * groups + g) * nsel + j] * tb
+                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            s = jnp.where(at <= pos_ref[b], s, -1e30)
+            m_old = jnp.max(m_scr[g], axis=1, keepdims=True)     # [Hg, 1]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            a = jnp.exp(m_old - m_new)
+            l_scr[g] = a * l_scr[g] + jnp.sum(p, axis=1, keepdims=True)
+            acc[g] = a * acc[g] + jax.lax.dot_general(
+                p.astype(v_refs[g].dtype), v_refs[g][0, 0],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_scr[g] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+
+    @pl.when(j == nsel - 1)
+    def _():
+        o_ref[0] = acc[...] / jnp.max(l_scr[...], axis=2, keepdims=True)
+
+
+def selected_blocks_lower(block, T):
+    """Whether `selected_blocks_attend` lowers for blocks of `block`
+    positions on a rung of `T`: a block is a whole number of lane tiles
+    and the rung a whole number of blocks."""
+    return block % DECODE_ATTEND_BLOCK == 0 and T % block == 0
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def selected_blocks_attend(q, k, v, sel, n_sel, pos, block):
+    """softmax(q . K[blocks] / sqrt(D)) . V[blocks] for each row b and
+    key/value group g over the blocks `sel[b, g, :n_sel[b, g]]` (ids of
+    `block`-position blocks) of its keys and values, positions past
+    pos[b] masked: `q` [B, G, Hg, D] (the group's query heads), `k` [B,
+    G, D, T] and `v` [B, G, T, D] as the slab stores them, `sel` [B, G,
+    S] int32, `n_sel` [B, G] (at least 1), `pos` [B] -> [B, G, Hg, D]
+    float32. Of each row and group only the selected blocks leave HBM,
+    a grid step a selection rank with every group's block at once; one
+    running softmax in float32, the probabilities rounded to the
+    values' dtype before they weigh them. Interpreted, any block that
+    divides the rung; lowered by Mosaic, `selected_blocks_lower`."""
+    B, G, Hg, D = q.shape
+    T = k.shape[3]
+    S = sel.shape[2]
+    tb = int(block)
+    if T % tb:
+        raise ValueError(f"selected_blocks_attend: {T} positions do not "
+                         f"divide into blocks of {tb}")
+
+    def key_map(g):
+        def index(b, j, sel_ref, n_ref, pos_ref):
+            r = b * G + g
+            return b, g, 0, sel_ref[r * S + jnp.minimum(j, n_ref[r] - 1)]
+        return index
+
+    def value_map(g):
+        def index(b, j, sel_ref, n_ref, pos_ref):
+            r = b * G + g
+            return b, g, sel_ref[r * S + jnp.minimum(j, n_ref[r] - 1)], 0
+        return index
+
+    def row(b, j, *_):
+        return b, 0, 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_selected_blocks_kernel, scale=1.0 / (D ** 0.5),
+                          tb=tb, groups=G, nsel=S),
+        out_shape=jax.ShapeDtypeStruct((B, G, Hg, D), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, S),
+            in_specs=[pl.BlockSpec((1, G, Hg, D), row)]
+            + [pl.BlockSpec((1, 1, D, tb), key_map(g)) for g in range(G)]
+            + [pl.BlockSpec((1, 1, tb, D), value_map(g)) for g in range(G)],
+            out_specs=pl.BlockSpec((1, G, Hg, D), row),
+            scratch_shapes=[pltpu.VMEM((G, Hg, 128), jnp.float32),
+                            pltpu.VMEM((G, Hg, 128), jnp.float32),
+                            pltpu.VMEM((G, Hg, D), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="selected_blocks_attend",
+        interpret=_interpret(),
+    )(sel.reshape(-1).astype(jnp.int32), n_sel.reshape(-1).astype(jnp.int32),
+      pos.astype(jnp.int32), q, *([k] * G), *([v] * G))

@@ -1086,7 +1086,7 @@ class ServingEngine:
         """Tear down the decode tier: stop the decode dispatcher, then
         fail every waiting AND live session with `ServeClosedError`
         (counted `failed` — the 4-equation reconciliation stays exact
-        through shutdown) and release their slots. Mid-stream sessions
+        through shutdown) and release their slots and the slab. Mid-stream sessions
         keep the tokens already streamed; only the continuation is
         lost, and loudly."""
         with self._decode_lock:
@@ -1102,9 +1102,10 @@ class ServingEngine:
             self._dqueue.clear()
             live = list(self._decode_live.values())
             self._decode_live.clear()
-            if self._slab is not None:
-                self._slab_free = list(range(
-                    self._slab_dims()[0]))
+            # every session is failed below, so the slab holds nothing
+            # a query will read: give its memory back (the next
+            # admission builds it again, `_build_slab`)
+            self._slab, self._slab_free = None, []
             self._decode_reserved = 0
         dst = stats_mod.decode_stats()
         for s in waiting + live:
@@ -1738,9 +1739,10 @@ class ServingEngine:
         """Allocate the pooled cache: the model states its layout
         (`new_slab`: which layers hold the context and climb the
         sequence ladder, which a ring that does not), the engine says
-        how many slots and which rung. Batch slots ride the PR 6
-        bucket ladder (`policy.bucket_batch(max_sessions)`); the
-        sequence dim starts at the smallest ladder rung covering
+        how many slots and which rung. One slot a session
+        (`max_sessions`: a slot past it would never be admitted, and
+        a long-context slab padded to the next power of two leaves no
+        room for the weights); the sequence dim starts at the smallest ladder rung covering
         `need_t` and grows via `_grow_slab`. Returns
         (params, slots, sequence rung)."""
         import jax
@@ -1762,9 +1764,7 @@ class ServingEngine:
         dst.host_leaves_per_call = sum(
             not isinstance(leaf, jax.Array)
             for leaf in jax.tree_util.tree_leaves(params))
-        Sb = (self.policy.bucket_batch(self.max_sessions)
-              if self.max_sessions <= self.policy.max_batch
-              else _pow2_ceil(self.max_sessions))
+        Sb = self.max_sessions
         Tslab = self._slab_seq_bucket(need_t)
         # born ON the engine's device, and every step input placed
         # beside it (`_slab_put`): an uncommitted jnp.zeros/asarray
@@ -2366,7 +2366,7 @@ class ServingEngine:
         if not sampled:
             dst.decode_steps_tokens += k
         for name, n in counted.items():
-            setattr(dst, name, getattr(dst, name) + n)
+            dst.step_counters[name] += n
         trace_mod.record_span("decode_step", t0, t0 + block_s,
                               rows=len(live), slots=Sb, steps=k)
         with trace_mod.span("decode.step.scatter"):

@@ -679,7 +679,7 @@ class _DecodeStats:
         # whole context, layers that hold a fixed-size state (these
         # three read 0 before any slab; `_note_slab_bytes` adds a
         # further kind when a model states one: `window` and `summary`
-        # are `ChunkedAttnLM`'s)
+        # are `ChunkedAttnLM`'s, `blockkey` `BlockSparseMoELM`'s)
         self.cache_bytes = {"ring": 0, "context": 0, "state": 0}
 
     def reset(self) -> None:
@@ -698,29 +698,12 @@ class _DecodeStats:
         # int32 (a greedy single step or a block's steps), not logits
         self.decode_steps_tokens = 0
         self.prefills = 0       # prefill dispatches
-        # what a model's fused step counts itself (`DecodeLM.
-        # step_counter_names`), summed over decode steps and expert
-        # layers: assignments routed to an expert held here, held
-        # experts with at least one, and the fullest held expert's
-        self.moe_assignments_local = 0
-        self.moe_experts_touched = 0
-        self.moe_expert_load_max = 0
-        # `TransformerLM`'s: 128-position blocks of the slab its
-        # length-aware attention read, and those the rung holds, summed
-        # over rows, layers and steps (0 and 0 on the `einsum` path)
-        self.attn_blocks_read = 0
-        self.attn_blocks_rung = 0
-        # `ChunkedAttnLM`'s: entries (exact keys of the query's own
-        # block and summaries of the earlier ones) the rows' queries
-        # needed, entries of the 128-entry blocks its attention read
-        # (all that are held on the `einsum` path), entries the slab's
-        # buffers and summary lists hold, and summaries the steps wrote
-        # (a row's chunk closed), each summed over rows, layers and
-        # steps: needed <= read <= held
-        self.attn_entries_needed = 0
-        self.attn_entries_read = 0
-        self.attn_entries_held = 0
-        self.chunk_summaries_written = 0
+        # what a model's fused step counts itself, by its
+        # `DecodeLM.step_counter_names`, summed over decode steps (each
+        # name 0 from its model class's definition on); the names stay
+        # across a reset
+        self.step_counters = dict.fromkeys(
+            getattr(self, "step_counters", ()), 0)
         # KV migration (ISSUE 17). `migrated` counts sessions exported
         # off this engine's books (each decrements `sessions` too, so
         # the 4-equation reconciliation stays exact per engine: the
@@ -746,15 +729,7 @@ class _DecodeStats:
             "decode_steps": self.decode_steps,
             "decode_steps_tokens": self.decode_steps_tokens,
             "prefills": self.prefills,
-            "moe_assignments_local": self.moe_assignments_local,
-            "moe_experts_touched": self.moe_experts_touched,
-            "moe_expert_load_max": self.moe_expert_load_max,
-            "attn_blocks_read": self.attn_blocks_read,
-            "attn_blocks_rung": self.attn_blocks_rung,
-            "attn_entries_needed": self.attn_entries_needed,
-            "attn_entries_read": self.attn_entries_read,
-            "attn_entries_held": self.attn_entries_held,
-            "chunk_summaries_written": self.chunk_summaries_written,
+            **self.step_counters,
             "migrated": self.migrated,
             "resumed": self.resumed,
             "slots": self.slots,

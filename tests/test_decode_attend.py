@@ -181,14 +181,14 @@ def test_a_served_stream_on_a_two_block_rung_counts_its_blocks(lm):
                               decode_block=4).start()
     try:
         got = np.asarray(eng.submit_decode(prompt, 24).result(timeout=600))
-        assert eng._slab_dims()[1] == 256
+        slots, rung_t = eng._slab_dims()
+        assert rung_t == 256
     finally:
         eng.stop()
     after = stats.cache_stats()["decode"]
     steps = after["decode_steps"] - before["decode_steps"]
     read = after["attn_blocks_read"] - before["attn_blocks_read"]
     rung = after["attn_blocks_rung"] - before["attn_blocks_rung"]
-    slots = eng._slab_dims()[0]
     assert steps >= 23 and rung == steps * 3 * slots * 2
     # the session's row crosses into its second block at position 128;
     # the other rows are empty and read one block each

@@ -23,6 +23,12 @@ here for each form alike, at toy widths on the CPU:
                       beside 2-to-1 chunk summaries, which are what
                       climbs the ladder (the evabyte cell); the rows
                       cross a block boundary inside every test
+  blocksparse         `BlockSparseMoELM`: contexts beside a pooled key
+                      a block of 4 positions, BOTH climbing the ladder,
+                      the pooled keys starting at the dtype's lowest
+                      value, not zero (the minimax cell); a query reads
+                      block 0, its own and the best-scoring one, so the
+                      rows past 12 positions drop blocks
 
 A form that cannot meet a point says so as a skipped case, with the
 model's own reason. The next decode-tier model adds one row to FORMS.
@@ -31,6 +37,7 @@ import numpy as np
 import pytest
 
 from singa_tpu import device, serve, stats, tensor
+from singa_tpu.models.block_sparse_moe import BlockSparseMoELM
 from singa_tpu.models.chunked_attn import ChunkedAttnLM
 from singa_tpu.models.hybrid_moe import HybridWindowMoELM
 from singa_tpu.models.shortconv_moe import ShortConvMoELM
@@ -40,7 +47,8 @@ V, D = 64, 32
 MAXLEN = 64
 WINDOW = 4
 FORMS = ["lm-layernorm-tied", "lm-rmsnorm-untied", "lm-int8",
-         "hybrid-dense", "hybrid-sorted", "shortconv", "chunked"]
+         "hybrid-dense", "hybrid-sorted", "shortconv", "chunked",
+         "blocksparse"]
 
 
 @pytest.fixture(autouse=True)
@@ -62,7 +70,8 @@ class Form:
         self.name = name
         # the models drawn on the device (`DrawnDecodeLM`): a slab of
         # more than one kind of entry, slots first in every leaf
-        self.hybrid = name.startswith(("hybrid", "shortconv", "chunked"))
+        self.hybrid = name.startswith(("hybrid", "shortconv", "chunked",
+                                       "blocksparse"))
         self.int8 = name == "lm-int8"
         # what does not climb the ladder, what does, and how many
         # positions an entry of what does stands for
@@ -70,7 +79,21 @@ class Form:
                            "chunke": "window"}.get(name[:6])
         self.grows, self.per = (("summary", 2) if name == "chunked"
                                 else ("context", 1))
-        if name == "chunked":
+        # every kind the slab states, and the positions an entry of
+        # each kind that climbs the ladder stands for
+        self.kinds = {self.fixed_kind or "ring", self.grows}
+        self.pers = {self.per}
+        if name == "blocksparse":
+            self.kinds, self.pers = {"context", "blockkey"}, {1, 4}
+            # d_model 48, heads of 12: no axis but a rung's is 16 or 32
+            m = BlockSparseMoELM(
+                V, d_model=48, num_heads=4, kv_heads=2, head_dim=12,
+                rotary_dim=4, index_heads=2, index_dim=12, block=4,
+                top_blocks=1, local_blocks=1, moe_layers=(0, 1), d_ff=64,
+                d_ff_expert=16, d_ff_shared=16, n_experts=8,
+                experts_per_token=2, held=(2, 4), max_len=MAXLEN,
+                prefill_block=8, prefill_tile=4, init_std=0.3)
+        elif name == "chunked":
             # no axis of its slab is a contract rung (16, 32) but the
             # summary list's on the rung twice as long
             m = ChunkedAttnLM(
@@ -279,16 +302,20 @@ def test_a_row_whose_slot_is_out_of_bounds_is_dropped(form):
     cohort = [ids_of(6, 4), ids_of(4, 5)]
     lg, slab = prefill(form, slab, cohort, slots=[1, 3])
     after = host(slab)
-    for a, b in zip(before, after):
+    # what a slot holds before anything is written: zeros, or the
+    # lowest value where a kind keeps a running max
+    fresh = host(new_slab(form, 3, rung))
+    for a, b, f in zip(before, after, fresh):
         for kept in (0, 2):
             assert np.array_equal(slot_of(form, a, kept),
                                   slot_of(form, b, kept))
-        assert not slot_of(form, a, 1).any()
-        assert slot_of(form, b, 1).any()
+        assert np.array_equal(slot_of(form, a, 1), slot_of(form, f, 1))
+        assert not np.array_equal(slot_of(form, b, 1), slot_of(form, f, 1))
         if rung in b.shape:                 # a context: no ring, no state
-            past = np.take(b, np.arange(bucket, rung),
-                           axis=b.shape.index(rung))
-            assert not past.any()
+            axis = b.shape.index(rung)
+            past = np.arange(bucket, rung)
+            assert np.array_equal(np.take(b, past, axis=axis),
+                                  np.take(f, past, axis=axis))
     lg_in, _ = prefill(form, new_slab(form, 3, rung), cohort, slots=[1, 2])
     assert np.array_equal(lg, lg_in)
 
@@ -344,28 +371,31 @@ def test_slab_bytes_are_the_leaves_and_growth_keeps_what_was_written(form):
     lg, slab = step(form, slab, tok, pos)
     tok, pos = lg.argmax(-1).astype(np.int32), pos + 1
     by_kind = form.m.slab_bytes(slab)
-    assert set(by_kind) == {form.fixed_kind or "ring", form.grows}
+    assert set(by_kind) == form.kinds
     assert sum(by_kind.values()) == sum(
         leaf.size * leaf.dtype.itemsize for leaf in leaves(slab))
     assert by_kind[form.grows] > 0
-    assert (by_kind.get(form.fixed_kind, 0) > 0) == form.hybrid
+    fixed = form.fixed_kind is not None
+    assert (by_kind.get(form.fixed_kind, 0) > 0) == fixed
     assert form.m.slab_dims(slab) == (3, 16)
     small = host(slab)
     grown = form.m.grow_slab(slab, 32)
     assert form.m.slab_dims(grown) == (3, 32)
+    fresh = host(new_slab(form, 3, 32))     # what a new entry holds
     longer = 0
-    for a, b in zip(small, host(grown)):
+    for a, b, f in zip(small, host(grown), fresh):
         assert a.dtype == b.dtype
         if a.shape == b.shape:              # a ring or a state
             assert np.array_equal(a, b)
             continue
         longer += 1
         (axis,) = [i for i in range(a.ndim) if a.shape[i] != b.shape[i]]
-        assert (a.shape[axis], b.shape[axis]) == (16 // form.per,
-                                                  32 // form.per)
-        head, tail = np.split(b, [16 // form.per], axis=axis)
-        assert np.array_equal(head, a) and not tail.any()
-    assert longer and (longer < len(small)) == form.hybrid
+        per = 16 // a.shape[axis]
+        assert per in form.pers and b.shape[axis] == 32 // per
+        head, tail = np.split(b, [16 // per], axis=axis)
+        assert np.array_equal(head, a)
+        assert np.array_equal(tail, np.split(f, [16 // per], axis=axis)[1])
+    assert longer and (longer < len(small)) == fixed
     on_grown, _ = step(form, grown, tok, pos)
     tok, pos, slab = started(form)
     lg, slab = step(form, slab, tok, pos)

@@ -213,15 +213,37 @@ def _build_chunked(seed=3):
     return m
 
 
+def _build_blocksparse(seed=3):
+    """The block-sparse mixture-of-experts LM at a toy size: its tree
+    holds the indexer's projection, the per-head norms' gains, the
+    router's bias, the held and the shared experts' matrices."""
+    from singa_tpu.models.block_sparse_moe import BlockSparseMoELM
+
+    dev = device.get_default_device()
+    dev.SetRandSeed(seed)
+    m = BlockSparseMoELM(
+        V, d_model=D, num_heads=4, kv_heads=2, head_dim=8, rotary_dim=4,
+        index_heads=2, index_dim=8, block=4, top_blocks=1, local_blocks=1,
+        moe_layers=(0, 1), d_ff=64, d_ff_expert=16, d_ff_shared=16,
+        n_experts=8, experts_per_token=2, held=(2, 4), max_len=MAXLEN,
+        prefill_block=8, prefill_tile=4, init_std=0.3)
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32),
+                                 device=dev)],
+              is_train=False, use_graph=False)
+    m.eval()
+    return m
+
+
 @pytest.mark.parametrize("norm,quant", [("layer", "off"),
                                         ("layer", "int8"),
                                         ("rms", "off"),
                                         ("hybrid", "off"),
                                         ("shortconv", "off"),
-                                        ("chunked", "off")])
+                                        ("chunked", "off"),
+                                        ("blocksparse", "off")])
 def test_warmed_engine_counts_no_host_leaf(norm, quant):
     drawn = {"hybrid": _build_hybrid, "shortconv": _build_shortconv,
-             "chunked": _build_chunked}
+             "chunked": _build_chunked, "blocksparse": _build_blocksparse}
     m = drawn[norm]() if norm in drawn else _build(norm)
     prompt = np.array([[3, 1, 4]], np.int32)
     device.set_inference_quant(quant)

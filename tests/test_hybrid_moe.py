@@ -464,11 +464,12 @@ def test_h_gpt2_slab_and_streams_as_before_the_model_stated_them(gpt2,
     try:
         eng.warm_decode(prompt_lens=(5,), max_new_tokens=8)
         slab = eng._slab
-        assert len(slab) == 3 and eng._slab_dims() == (4, 16)
-        assert eng._decode_geom()[1:] == (4, 16)
+        # one slot a session: 3, not the next power of two
+        assert len(slab) == 3 and eng._slab_dims() == (3, 16)
+        assert eng._decode_geom()[1:] == (3, 16)
         for layer_ in slab:
             pay = layer_[0] if quant == "int8" else layer_
-            assert pay.shape == (2, 4, 2, 16, 16)     # [2, B, H, D, T]
+            assert pay.shape == (2, 3, 2, 16, 16)     # [2, B, H, D, T]
             assert not pay.is_deleted()
             # warm-up's longest block wrote positions 0..3 of every
             # row (stale state no query attends), and a pad row's
@@ -476,22 +477,23 @@ def test_h_gpt2_slab_and_streams_as_before_the_model_stated_them(gpt2,
             assert not np.asarray(pay)[..., 4:].any()
             if quant == "int8":
                 assert pay.dtype == jnp.int8
-                assert layer_[1].shape == (2, 4, 16)
+                assert layer_[1].shape == (2, 3, 16)
                 assert layer_[1].dtype == jnp.float32
             else:
                 assert pay.dtype == jnp.float32
         d = stats.cache_stats()["decode"]
         assert d["cache_bytes_ring"] == 0
-        assert d["cache_bytes_context"] == 3 * 2 * 4 * 2 * 16 * 16 * (
+        assert d["cache_bytes_context"] == 3 * 2 * 3 * 2 * 16 * 16 * (
             1 if quant == "int8" else 4) + (
-                3 * 2 * 4 * 16 * 4 if quant == "int8" else 0)
+                3 * 2 * 3 * 16 * 4 if quant == "int8" else 0)
         short = eng.submit_decode(ids_of((5,), 21), 8)
         long = eng.submit_decode(ids_of((9,), 22), 40)    # grows to 64
         got = [np.asarray(r.result(timeout=300)) for r in (short, long)]
-        assert eng._slab_dims() == (4, 64)
+        assert eng._slab_dims() == (3, 64)
     finally:
         eng.stop()
         device.set_inference_quant("off")
+    assert eng._slab is None        # a stopped engine holds no slab
     if quant == "off":
         assert np.array_equal(got[0], m.generate(ids_of((5,), 21)[None], 8))
         assert np.array_equal(got[1], m.generate(ids_of((9,), 22)[None], 40))

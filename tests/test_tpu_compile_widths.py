@@ -647,3 +647,91 @@ def test_evabyte_programs_hold_the_slab_in_place(evabyte, program,
             assert call.count("bf16[32,32,128,2048]") == 2
             assert call.count("bf16[32,32,128,1024]") == 2
         assert "[32,32,3072]" not in text
+
+
+# -- MiniMax-M3's served programs at their real widths (ISSUE 39) --------------
+MINIMAX = os.path.join(os.path.dirname(CONFIG), "minimax-m3.json")
+MM_SLOTS, MM_RUNG = 20, 32768
+
+
+@pytest.fixture(scope="module")
+def minimax(one_chip):
+    """`minimax-m3-serve-longctx20`'s geometry: 20 slots on the 32,768
+    rung, one-prompt prefills up to the 32,768 bucket."""
+    yield from _served(MINIMAX, MM_SLOTS, MM_RUNG, one_chip)
+
+
+# what may hold a whole key or value array of a layer: the slab passed
+# along, and the two kernels that take it where it lies (the write of
+# one position a row, in place, and the attention that fetches the
+# selected blocks alone)
+_PASSED = ("parameter", "get-tuple-element", "tuple", "bitcast")
+_KERNELS = ("cache_write", "selected_blocks_attend")
+
+
+def _reads_a_whole_context(text, slots, rung):
+    """(name, opcode) of the instructions that take or make a whole
+    [slots, 4, 128, rung] key or [slots, 4, rung, 128] value array and
+    are neither passing the slab along nor one of `_KERNELS`."""
+    whole = re.compile(rf"\[{slots},4,(?:128,{rung}|{rung},128)\]")
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\(", line)
+        if not m or not whole.search(line):
+            continue
+        name, opcode = m.groups()
+        if opcode in _PASSED or (opcode == "custom-call"
+                                 and name.startswith(_KERNELS)):
+            continue
+        out.append((name, opcode))
+    return out
+
+
+@pytest.mark.parametrize("program,temporaries", [
+    ("step", 0.2e9), ("block1", 0.2e9), ("prefill4096", 1.5e9),
+    ("prefill32768", 1.5e9)])
+def test_minimax_programs_hold_the_slab_in_place(minimax, program,
+                                                 temporaries, monkeypatch):
+    """The fused step, the token program and the one-prompt prefill of
+    the shortest bucket and of the one a 24,576-position prompt takes,
+    over 6.39 GB of weights and the 6.76 GB slab (five layers of 20
+    slots' keys and values on the 32,768 rung, and their pooled block
+    keys in float32): each fits 16 GB, the slab is aliased whole, and the
+    temporaries are what is stated. A decode program holds the
+    selected-blocks kernel, one call a layer, and no other operation
+    reads a whole key or value array of a layer; a prefill holds no
+    score array over all the keys of its block of queries (compiled
+    for a described v5e; no chip, no device metric)."""
+    model, params, slab, sds = minimax
+    compiled = _engine_program(
+        model, params, slab, sds, monkeypatch, MM_SLOTS, program,
+        (1, int(program[7:])) if program[:7] == "prefill" else None)
+    m = _fits(compiled, f"MiniMax-M3 {program}")
+    by_kind = model.slab_bytes(slab)
+    assert by_kind == {"context": 5 * 20 * 2 * 4 * 128 * 32768 * 2,
+                       "blockkey": 5 * 20 * 4 * 128 * 256 * 4}
+    assert m.alias_size_in_bytes >= sum(by_kind.values())
+    assert m.temp_size_in_bytes < temporaries, m.temp_size_in_bytes / 1e9
+    text = compiled.as_text()
+    if os.environ.get("SINGA_DUMP_HLO"):
+        with open(os.path.join(os.environ["SINGA_DUMP_HLO"],
+                               f"minimax_{program}.txt"), "w") as f:
+            f.write(text)
+    if program[:7] != "prefill":
+        assert not _reads_a_whole_context(text, MM_SLOTS, MM_RUNG)
+        calls = re.findall(r"^\s*%selected_blocks_attend\S* = .*$", text,
+                           re.M)
+        assert len(calls) == len(slab)
+        assert " while(" not in text
+        # the step's scopes reach the program's metadata, where a trace
+        # joined to the program's own HLO finds them
+        for scope in ("msa_indexer", "attn_sparse", "moe_shared",
+                      "moe_router", "moe_experts"):
+            assert re.search(rf'op_name="jit\(slot_\w+\)/{scope}/', text)
+    else:
+        # the prompt's rows written into the slab in place, and nothing
+        # of a layer's size moved; a tile of 512 queries over a key tile
+        # of 512 is the largest score array (over all 32,768 keys it
+        # would be 64 times that)
+        assert not _whole_layer_moves(text, int(np.prod(slab[0]["k"].shape)))
+        assert not re.search(r"f32\[1,4,512,16,(?:4096|32768)\]", text)
