@@ -134,16 +134,33 @@ class DecodeLM(model.Model):
             self._step_counters = out[2]
         return out[0], out[1]
 
-    def take_step_counters(self):
-        """The last dispatched step's (or block's) counters as host
-        ints by name, once; {} for a model whose step counts nothing."""
+    def detach_step_counters(self):
+        """The last dispatched program's counters vector, still on the
+        device, taken off the model (None where its step counts
+        nothing). A caller that dispatches a block behind one it has
+        not read back keeps each block's vector beside that block and
+        names it after the block's readback: `take_step_counters(vec)`."""
+        return self.__dict__.pop("_step_counters", None)
+
+    def take_step_counters(self, vec=None):
+        """A program's counters as host ints by name: those of `vec`
+        (as `detach_step_counters` gave it), else the last dispatched
+        step's (or block's), once; {} for a model whose step counts
+        nothing."""
         import numpy as np
 
-        vec = self.__dict__.pop("_step_counters", None)
+        if vec is None:
+            vec = self.detach_step_counters()
         if vec is None:
             return {}
         return dict(zip(self.step_counter_names,
                         (int(v) for v in np.asarray(vec))))
+
+    def take_next_tokens(self):
+        """The token row the last `decode_scan`'s steps ended on, [B]
+        int32 left on the device, once: the `tok` of a block dispatched
+        behind that one before its tokens come to the host."""
+        return self.__dict__.pop("_next_tokens", None)
 
     def decode_step(self, params, cache, tok, pos):
         """ONE fused decode step for the serving tier: advance every
@@ -177,8 +194,11 @@ class DecodeLM(model.Model):
         identical logits bits — both first-max-wins, NaN the largest),
         so a block decodes bit-identically to k single steps. Returns
         (toks [k, B] — one sampled token per step per row, new
-        cache). The caller only dispatches a block when no session
-        joins, leaves, expires, or samples within it."""
+        cache); the carry's last token row, `toks[k - 1]` as an output
+        of its own, waits on the device for `take_next_tokens`, so a
+        block can follow this one with no program in between. The
+        caller only dispatches a block when no session joins, leaves,
+        expires, or samples within it."""
         import jax
         import jax.numpy as jnp
 
@@ -189,24 +209,25 @@ class DecodeLM(model.Model):
         def scan_k(p, c, t, po):
             if int(k) == 1:
                 t2, c, counters = greedy_step(p, c, t, po)
-                return (t2[None], c, *counters)
+                return (t2[None], c, t2, *counters)
 
             def body(carry, _):
                 c, t, po = carry
                 t2, c, counters = greedy_step(p, c, t, po)
                 return (c, t2, po + 1), (t2, *counters)
 
-            (c, _t, _po), (toks, *counters) = jax.lax.scan(
+            (c, t, _po), (toks, *counters) = jax.lax.scan(
                 body, (c, t, po), None, length=int(k),
                 unroll=self.scan_unroll)
-            return (toks, c, *(v.sum(0) for v in counters))
+            return (toks, c, t, *(v.sum(0) for v in counters))
 
         # a trace names the module by this: the block's steps with it
         scan_k.__name__ = f"slot_scan_{int(k)}"
         args = (params, list(cache), tok, pos)
         fn = self._slab_program("decode_scan", ("slot_scan", int(k)),
                                 scan_k, args, {"block": int(k)})
-        return self._keep_counters(fn(*args))
+        toks, slab, self._next_tokens, *counters = fn(*args)
+        return self._keep_counters((toks, slab, *counters))
 
     def prefill_slab(self, params, slab, ids, n_real, slots):
         """Prefill a COHORT of bucket-padded prompts and scatter their

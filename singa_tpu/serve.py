@@ -784,6 +784,26 @@ class _DecodeSession:
         self.resumed = False
 
 
+class _DecodeBlock:
+    """One fused step or block in flight: what it was given, and what
+    the host needs to read it back and to dispatch the next one behind
+    it."""
+
+    __slots__ = ("idx", "k", "sampled", "pos", "out", "tok", "counters",
+                 "t0")
+
+    def __init__(self, idx: int, k: int, sampled: bool, pos: np.ndarray,
+                 out, tok, counters, t0: float):
+        self.idx = idx                  # dispatch ordinal (chaos key)
+        self.k = k                      # steps
+        self.sampled = sampled          # `decode_step`: logits come back
+        self.pos = pos                  # [Sb] int32 positions, host
+        self.out = out                  # tokens [k, Sb] or logits, device
+        self.tok = tok                  # [Sb] token row it ends on, device
+        self.counters = counters        # the model's vector, device
+        self.t0 = t0                    # dispatch start, perf_counter
+
+
 def _pow2_ceil(n: int) -> int:
     b = 1
     while b < n:
@@ -1811,10 +1831,12 @@ class ServingEngine:
         return any(leaf.is_deleted()
                    for leaf in jax.tree_util.tree_leaves(self._slab))
 
-    def _rebuild_lost_slab(self) -> None:
+    def _rebuild_lost_slab(self, lost: bool = False) -> None:
         """Fresh buffers at the lost slab's geometry (its sessions
-        have been failed by the caller), so queued work can go on."""
-        if self._slab_lost():
+        have been failed by the caller), so queued work can go on:
+        where a failed program took it, or where `lost` says that a
+        program that failed after its dispatch wrote it."""
+        if lost or self._slab_lost():
             slots, seq = self._slab_dims()
             self._slab = self.model.new_slab(
                 self._decode_params, slots, seq,
@@ -1932,9 +1954,11 @@ class ServingEngine:
         through ONE fused cohort prefill dispatch (bounded, so a burst
         of prompts never stalls the decode batch for long), then
         advances EVERY live session one token with ONE fused
-        `decode_step` over the pooled slab — sequences join and leave
-        the fused batch between steps, and a freed slot re-admits
-        queued work mid-stream."""
+        `decode_step` over the pooled slab (or a run-ahead block, and
+        the blocks chained behind it while nothing can join or leave:
+        `_decode_fused_step`) — sequences join and leave the fused
+        batch between steps, and a freed slot re-admits queued work
+        mid-stream."""
         dst = stats_mod.decode_stats()
         dst.slots = self.max_sessions
         geom = None
@@ -2245,21 +2269,22 @@ class ServingEngine:
                         self._decode_live[slot] = sess
                         dst.slots_in_use = len(self._decode_live)
 
-    def _decode_run_ahead(self, live) -> int:
+    def _decode_run_ahead(self, live, ahead: int = 0) -> int:
         """How many fused steps may dispatch as ONE scanned block
         (`decode_scan`) without delaying a join, leave, expiry, or
         sampled token: capped by `decode_block` and every session's
-        remaining budget, collapsed to 1 whenever a session samples
-        (host-side key splits), carries a deadline (expiry is checked
-        between dispatches), or queued work could take a free slot.
-        The result is floored to a power of two so `decode_scan`
-        compiles one program per LADDER RUNG, not one per distinct
-        remaining-token count (the same churn-bounding argument as the
-        PR 6 shape buckets)."""
+        remaining budget (less the `ahead` steps of a block in flight
+        whose tokens the host has not handed out yet), collapsed to 1
+        whenever a session samples (host-side key splits), carries a
+        deadline (expiry is checked between dispatches), or queued work
+        could take a free slot. The result is floored to a power of two
+        so `decode_scan` compiles one program per LADDER RUNG, not one
+        per distinct remaining-token count (the same churn-bounding
+        argument as the prefill's shape buckets)."""
         k = self.decode_block
         for _, sess in live:
-            if sess.left < k:
-                k = sess.left
+            if sess.left - ahead < k:
+                k = sess.left - ahead
             if sess.temperature != 0.0 or sess.deadline is not None:
                 return 1
         if k > 1:
@@ -2272,28 +2297,53 @@ class ServingEngine:
             return k  # the configured block is its own ladder rung
         return 1 << (int(k).bit_length() - 1)
 
+    def _decode_chains(self, live, ahead: int) -> bool:
+        """Whether a block may follow, before the host reads it back,
+        one that takes every live session `ahead` steps on: nobody
+        leaves at its end, everybody is greedy (a sampled step's
+        logits come to the host) with no deadline (expiry is checked
+        between dispatches), and no slot is free. A session queues only
+        while a slot is free (`submit_decode` sheds once every slot is
+        reserved), so nothing can join before the block after it; the
+        next join waits for a leave, which the host sees coming."""
+        for _, sess in live:
+            if (sess.left <= ahead or sess.temperature != 0.0
+                    or sess.deadline is not None):
+                return False
+        with self._decode_lock:
+            return not self._slab_free
+
+    def _decode_fault_due(self) -> bool:
+        """Whether the fault injector holds a failure or a hang for the
+        next dispatch: that one is not chained, so it meets the
+        unchained dispatch's retry."""
+        inj, idx = self.fault_injector, self._decode_step_idx + 1
+        return inj is not None and (inj.should("decode_fail", idx)
+                                    or inj.should("decode_hang", idx))
+
     def _decode_fused_step(self, live, geom, dst) -> None:
-        """ONE warm dispatch advancing every live slot — a single
-        step, or a `decode_scan` block of up to
-        `decode_block` steps when `_decode_run_ahead` proves nothing
-        joins/leaves inside it — with the forward tier's
-        retry/backoff discipline. While every live session is greedy
-        the token is chosen in the program that computed the logits
-        (`decode_scan`, k = 1 for a single step) and [k, Sb] int32
-        comes back; only a step with a sampled session among the live
-        ones is `decode_step`, whose logits [Sb, V] cross to the host
-        for `sample_fn` and its host-side key splits (the greedy rows
-        beside it take the host's argmax). Tokens are streamed only AFTER the
-        dispatch completes and only from its output — a retried
-        dispatch recomputes from the UNCHANGED slab, so a delivered
-        stream is never torn or duplicated."""
-        import jax
-
-        from . import resilience
-
-        model = self.model
+        """Advance every live slot by ONE warm dispatch — a single
+        step, or a `decode_scan` block of up to `decode_block` steps
+        when `_decode_run_ahead` proves nothing joins/leaves inside it —
+        with the forward tier's retry/backoff discipline; and while
+        `_decode_chains` proves the live set cannot change at a block's
+        end, dispatch the next block behind it (on the slab it returns,
+        from the token row its steps end on, left on the device, at
+        positions `pos + k`) before reading it back, so the device runs
+        block n + 1 while the host reads back and hands out block n.
+        While every live session is greedy the token is chosen in the
+        program that computed the logits (`decode_scan`, k = 1 for a
+        single step) and [k, Sb] int32 comes back; only a step with a
+        sampled session among the live ones is `decode_step`, whose
+        logits [Sb, V] cross to the host for `sample_fn` and its
+        host-side key splits (the greedy rows beside it take the host's
+        argmax), and nothing follows it unread. Tokens are streamed
+        only AFTER their own block's readback, block after block, and
+        only from its output — a retried dispatch recomputes from the
+        UNCHANGED slab, so a delivered stream is never torn or
+        duplicated. Returns with no block in flight, so the slab and
+        every session's ledger agree."""
         params = geom[0]
-        put = self._slab_put
         with trace_mod.span("decode.step.assemble"):
             Sb = self._slab_dims()[0]
             tokv = np.zeros(Sb, np.int32)
@@ -2304,6 +2354,41 @@ class ServingEngine:
                 posv[slot] = sess.pos
                 sampled = sampled or sess.temperature != 0.0
             k = self._decode_run_ahead(live)
+            chain = self._decode_chains(live, k)
+        blk = self._decode_dispatch(live, params, tokv, posv, k, sampled,
+                                    dst)
+        behind = None       # when the block before `blk` was read back
+        while blk is not None:
+            nxt = err = None
+            if chain and self._decode_running and not self._decode_fault_due():
+                with trace_mod.span("decode.step.assemble"):
+                    k = self._decode_run_ahead(live, blk.k)
+                    chain = self._decode_chains(live, blk.k + k)
+                self._decode_step_idx += 1
+                try:
+                    nxt = self._decode_enqueue(params, blk.tok,
+                                               blk.pos + blk.k, k, False,
+                                               time.perf_counter())
+                except Exception as e:  # `blk` is handed out first
+                    err = e
+            behind = self._decode_deliver(blk, live, dst, behind)
+            if behind is None:
+                return  # its sessions failed, and what was behind it
+            if err is not None:
+                # the next dispatch is an unchained one with its retry;
+                # a failure that took the slab took the sessions' state
+                self._fail_live_if_slab_lost(dst, "chained decode step",
+                                             err)
+            blk = nxt
+
+    def _decode_dispatch(self, live, params, tokv, posv, k, sampled,
+                         dst) -> Optional[_DecodeBlock]:
+        """The unchained dispatch of one step or block, retried with
+        backoff while the slab is untouched. Returns it in flight, or
+        None once its sessions have failed (retries exhausted, or the
+        donated slab gone with a failed attempt)."""
+        from . import resilience
+
         inj = self.fault_injector
         t0 = time.perf_counter()
         attempt = 0
@@ -2316,44 +2401,81 @@ class ServingEngine:
                 if inj is not None and inj.should("decode_fail", idx):
                     raise RuntimeError(
                         f"injected decode step failure (step {idx})")
-                with trace_mod.span("decode.step.dispatch", steps=k):
-                    if sampled:     # k == 1: its keys split on the host
-                        out, new_slab = model.decode_step(
-                            params, self._slab, put(tokv), put(posv))
-                    else:
-                        out, new_slab = model.decode_scan(
-                            params, self._slab, put(tokv), put(posv),
-                            k)
-                with trace_mod.span("decode.step.readback", steps=k):
-                    out = np.asarray(out)  # completes the dispatch
-                    counted = model.take_step_counters()
-                # tokens [k, Sb], chosen where the logits were
-                # computed; the logits [Sb, V] of a single step only
-                # when a live session samples
-                lg, toks = (out, None) if sampled else (None, out)
-                break
+                return self._decode_enqueue(params, tokv, posv, k,
+                                            sampled, t0)
             except BaseException as e:  # noqa: BLE001 — retry below
                 if attempt >= self.max_retries or self._slab_lost():
                     # retries exhausted (or nothing left to retry
                     # from): the fused step is the only way forward
                     # for these sessions — fail them loudly, free
                     # every slot for queued work
-                    self._rebuild_lost_slab()
-                    for _, sess in live:
-                        self._decode_fail_session(sess, dst,
-                                                  ServeDispatchError(
-                            f"fused decode step failed after "
-                            f"{attempt} retries: {e!r}"))
-                    with self._decode_lock:
-                        dst.slots_in_use = len(self._decode_live)
-                    return
+                    self._decode_fail_live(live, dst, (
+                        f"fused decode step failed after {attempt} "
+                        f"retries: {e!r}"))
+                    return None
                 attempt += 1
                 time.sleep(resilience.backoff_delay_s(
                     attempt, self.backoff_s,
                     jitter=self.backoff_jitter,
                     seed=self._jitter_seed))
-        self._slab = new_slab
-        block_s = time.perf_counter() - t0
+
+    def _decode_enqueue(self, params, tok, pos, k, sampled,
+                        t0) -> _DecodeBlock:
+        """Enqueue ONE step or block on the slab, its inputs put beside
+        it (`tok` a host vector, or the device row a block in flight
+        ends on). The slab becomes the one the program returns: the one
+        it took is donated. Returns the block in flight, with the
+        counters the model kept for it."""
+        model, put = self.model, self._slab_put
+        with trace_mod.span("decode.step.dispatch", steps=k):
+            if sampled:     # k == 1: its keys split on the host
+                out, self._slab = model.decode_step(
+                    params, self._slab, put(tok), put(pos))
+            else:
+                out, self._slab = model.decode_scan(
+                    params, self._slab, put(tok), put(pos), k)
+        return _DecodeBlock(
+            self._decode_step_idx, k, sampled, pos, out,
+            None if sampled else model.take_next_tokens(),
+            model.detach_step_counters(), t0)
+
+    def _decode_fail_live(self, live, dst, msg: str,
+                          lost: bool = False) -> None:
+        """Fail a dispatch's sessions loudly and free their slots; give
+        queued work a slab again where a failed program took it (or
+        `lost`: a block that failed after its dispatch wrote it)."""
+        self._rebuild_lost_slab(lost)
+        for _, sess in live:
+            self._decode_fail_session(sess, dst, ServeDispatchError(msg))
+        with self._decode_lock:
+            dst.slots_in_use = len(self._decode_live)
+
+    def _decode_deliver(self, blk: _DecodeBlock, live, dst,
+                        behind: Optional[float]) -> Optional[float]:
+        """Read a block back with its own counters and hand its tokens
+        to their sessions. Its `decode_step` record starts at its
+        dispatch or, for a block dispatched `behind` another, where
+        that one's readback ended: the time the block alone held the
+        host. Returns when its readback ended, or None once its
+        sessions have failed: the program had taken the slab, so there
+        is nothing to retry from, and a block behind it read what it
+        never wrote."""
+        import jax
+
+        model = self.model
+        put = self._slab_put
+        try:
+            with trace_mod.span("decode.step.readback", steps=blk.k):
+                out = np.asarray(blk.out)  # completes the dispatch
+                counted = model.take_step_counters(blk.counters)
+        except Exception as e:  # the program took the slab: no retry
+            self._decode_fail_live(live, dst, (
+                f"fused decode step failed: {e!r}"), lost=True)
+            return None
+        t_read = time.perf_counter()
+        k = blk.k
+        t0 = blk.t0 if behind is None else max(blk.t0, behind)
+        block_s = t_read - t0
         step_s = block_s / k
         self._ema_decode_step_s = (
             step_s if not self._ema_decode_step_s
@@ -2363,12 +2485,19 @@ class ServingEngine:
             rate if not self._decode_tokens_ema
             else 0.8 * self._decode_tokens_ema + 0.2 * rate)
         dst.decode_steps += k
-        if not sampled:
+        if not blk.sampled:
             dst.decode_steps_tokens += k
+        if behind is not None:
+            dst.decode_steps_chained += k
         for name, n in counted.items():
             dst.step_counters[name] += n
-        trace_mod.record_span("decode_step", t0, t0 + block_s,
+        Sb = self._slab_dims()[0]
+        trace_mod.record_span("decode_step", t0, t_read,
                               rows=len(live), slots=Sb, steps=k)
+        # tokens [k, Sb], chosen where the logits were computed; the
+        # logits [Sb, V] of a single step only when a live session
+        # samples
+        lg, toks = (out, None) if blk.sampled else (None, out)
         with trace_mod.span("decode.step.scatter"):
             now = time.perf_counter()
             for slot, sess in live:
@@ -2404,7 +2533,7 @@ class ServingEngine:
                     extra = ({"quant": self._decode_quant}
                              if self._decode_quant != "off" else {})
                     self.metrics.log_step(
-                        self._decode_step_idx,
+                        blk.idx,
                         examples=len(live) * k,
                         step_s=block_s, tier="decode",
                         sessions=len(live), slots=Sb, block=k,
@@ -2416,6 +2545,7 @@ class ServingEngine:
                         shed=dst.shed, failed=dst.failed, **extra)
                 except Exception:
                     _STATS.errors += 1  # metrics stream closed mid-serve
+        return t_read
 
     # -- dispatcher -------------------------------------------------------
     def _fail_request(self, req: _Request, err: BaseException,

@@ -697,6 +697,9 @@ class _DecodeStats:
         # those of them whose result came to the host as tokens [k, B]
         # int32 (a greedy single step or a block's steps), not logits
         self.decode_steps_tokens = 0
+        # those of them dispatched behind a block whose result the host
+        # had not read back yet (`ServingEngine._decode_fused_step`)
+        self.decode_steps_chained = 0
         self.prefills = 0       # prefill dispatches
         # what a model's fused step counts itself, by its
         # `DecodeLM.step_counter_names`, summed over decode steps (each
@@ -728,6 +731,7 @@ class _DecodeStats:
             "tokens_streamed": self.tokens_streamed,
             "decode_steps": self.decode_steps,
             "decode_steps_tokens": self.decode_steps_tokens,
+            "decode_steps_chained": self.decode_steps_chained,
             "prefills": self.prefills,
             **self.step_counters,
             "migrated": self.migrated,
