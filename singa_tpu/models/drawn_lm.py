@@ -4,7 +4,8 @@ their stored dtype (billions of them cannot be drawn leaf by leaf on
 the host in float32), one functional stack outside autograd (no
 backward, so `train_one_batch` raises), RMSNorm, rotary positions, a
 softmax with an optional sink column, causal attention over a prompt
-and over a cached context, and rows scattered into slab slots.
+and over a cached context, a short causal convolution over a prompt
+and a step of it from a state, and rows scattered into slab slots.
 
 A subclass gives `_param_table()` (every parameter by dotted name),
 `_tree(leaf)` (the tree its programs receive), `_eval_logits(params,
@@ -139,6 +140,70 @@ def dense_mlp(ffn, x, prec):
         return jnp.matmul(jax.nn.silu(g) * u, ffn["W_d"], precision=prec)
 
 
+def conv_prompt(u, w):
+    """z_t = sum_j w[j] u_{t-(L-1)+j} over a prompt's positions 0..S-1
+    of u [B, S, C] with the depthwise taps w [L, C] (float32): causal,
+    nothing before position 0; float32."""
+    import jax.numpy as jnp
+
+    L, S = w.shape[0], u.shape[1]
+    up = jnp.pad(u.astype(jnp.float32), ((0, 0), (L - 1, 0), (0, 0)))
+    return sum(w[j] * up[:, j:j + S] for j in range(L))
+
+
+def conv_columns(pos, L):
+    """[B, L-1, 1]: the tap each column of a row's convolution state
+    meets at position pos [B]. The state holds a row's last L-1 inputs,
+    u_p in column p mod (L-1); column c holds u_{p-(L-1)+j} for the one
+    j with (p + j) mod (L-1) = c, and the oldest's, j = 0, is where
+    u_p goes."""
+    import jax.numpy as jnp
+
+    col = jnp.arange(L - 1)[None, :]
+    return ((col - pos[:, None]) % (L - 1))[:, :, None]
+
+
+def conv_step(state, u, w, tap):
+    """One position of `conv_prompt` from a row's state [B, L-1, C],
+    its new input u [B, 1, C] and the taps w [L, C] (float32), the
+    columns' taps `tap` (`conv_columns`): (z [B, 1, C] float32, the
+    state with u over the oldest column). The taps are dealt to the
+    columns by selects, so the state is read where it lies and written
+    by one more (in place in a donated slab; shifting the columns
+    makes XLA build the state anew and copy it back)."""
+    import jax.numpy as jnp
+
+    new = jnp.where(tap == 0, u, state)
+    w_col = sum(jnp.where(tap == j, w[j], 0.0)
+                for j in range(w.shape[0] - 1))
+    z = (w_col * state.astype(jnp.float32)).sum(1) \
+        + w[-1] * u[:, 0].astype(jnp.float32)
+    return z[:, None], new
+
+
+def conv_tail_positions(n_real, L):
+    """[Bp, L-1]: the position column r of a row's state takes after a
+    prompt of n_real [Bp] real positions: the one p in the last L-1
+    with p mod (L-1) = r (below 0 where the prompt is shorter)."""
+    import jax.numpy as jnp
+
+    r = jnp.arange(L - 1)[None, :]
+    last = n_real[:, None] - 1
+    return last - (last - r) % (L - 1)
+
+
+def conv_tail(u, back):
+    """The state a prompt leaves, u [Bp, S, C] at the positions back
+    [Bp, L-1] (`conv_tail_positions`), zero where there is no such
+    position: the pad tail never lands there, nor anything of the
+    slot's last session."""
+    import jax.numpy as jnp
+
+    held = jnp.take_along_axis(
+        u, jnp.clip(back, 0, u.shape[1] - 1)[:, :, None], 1)
+    return jnp.where((back >= 0)[:, :, None], held, 0)
+
+
 def put_rows(cache, rows, slots):
     """rows [Bp, ...] over the head of cache rows `slots`, a row at a
     time and in place; an out-of-bounds slot keeps what the (clamped)
@@ -156,7 +221,7 @@ def put_rows(cache, rows, slots):
 
 class DrawnDecodeLM(DecodeLM):
     """Base of every decode-tier model whose parameters are drawn on
-    the device: `RoutedDrawnLM`'s two and `ChunkedAttnLM`."""
+    the device: `RoutedDrawnLM`'s and `ChunkedAttnLM`."""
 
     # the subclass's: what its slab holds, what a training path lacks
     # ("with no backward for ..., and no optimizer state for ..."), and
@@ -286,9 +351,9 @@ class DrawnDecodeLM(DecodeLM):
 
 
 class RoutedDrawnLM(DrawnDecodeLM):
-    """Base of `HybridWindowMoELM`, `ShortConvMoELM` and
-    `BlockSparseMoELM`: a drawn model with routed layers, of which this
-    chip holds a range of experts."""
+    """Base of `HybridWindowMoELM`, `ShortConvMoELM`, `BlockSparseMoELM`
+    and `GatedDeltaMoELM`: a drawn model with routed layers, of which
+    this chip holds a range of experts."""
 
     # a run-ahead block is its steps in a row, not a loop: around a
     # loop XLA re-lays every held expert's gate and up matrices out
@@ -299,10 +364,11 @@ class RoutedDrawnLM(DrawnDecodeLM):
     step_counter_names = ("moe_assignments_local", "moe_experts_touched",
                           "moe_expert_load_max")
     # `routed_experts`'s: one constant for every model that calls it,
-    # and what an architecture may set: the number in its normalising
-    # sum, its experts' activation (None: silu(g) * u) and the scale
-    # of their part (`routed_scaling_factor`)
+    # and what an architecture may set: the router's scoring rule, the
+    # number in its normalising sum, its experts' activation (None:
+    # silu(g) * u) and the scale of their part (`routed_scaling_factor`)
     dense_rows = DENSE_ROWS
+    router_scoring = "sigmoid"
     router_sum_eps = 0.0
     expert_act = None
     routed_scale = 1.0
@@ -331,4 +397,5 @@ class RoutedDrawnLM(DrawnDecodeLM):
                               experts_per_token=self.experts_per_token,
                               dense_rows=self.dense_rows,
                               sum_eps=self.router_sum_eps,
-                              act=self.expert_act, scale=self.routed_scale)
+                              act=self.expert_act, scale=self.routed_scale,
+                              scoring=self.router_scoring)

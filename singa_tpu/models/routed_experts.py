@@ -1,9 +1,11 @@
 """The routed expert layer every mixture-of-experts model of the decode
-tier calls: a sigmoid router over all `n_experts`, top-k chosen by
-score + bias, weighted by the unbiased scores and normalised, and the
+tier calls: a router over all `n_experts` that scores by one of two
+rules, top-k chosen, weighted by the chosen scores normalised, and the
 products of the experts this chip HOLDS.
 
-    sig = sigmoid(x W_r) in float32;  S = top_k(sig + b)
+    "sigmoid":  sig = sigmoid(x W_r) in float32;  S = top_k(sig + b)
+    "softmax":  sig = softmax(x W_r) over all experts, float32, no
+                bias;  S = top_k(sig)
     w_e = sig_e / (sum_S sig + sum_eps)
     y   = scale * sum_{e in S and held} w_e act(x G_e, x U_e) D_e
 
@@ -12,11 +14,14 @@ products of the experts this chip HOLDS.
 
 `held = (first, count)` names the held experts: `ffn["W_g"]`, `W_u`
 ([count, d, f]) and `W_d` ([count, f, d]) are theirs alone, while
-`W_r` [d, n_experts] and `b` [n_experts] keep the router's width. The
-layer drops no token at any imbalance. What differs between
-architectures is numbers, not code: how many experts and how many a
-token (the router's shapes), `sum_eps` in the normalising sum, the
-activation and the scale.
+`W_r` [d, n_experts] and `b` [n_experts] (the sigmoid rule's alone)
+keep the router's width. The layer drops no token at any imbalance.
+What differs between architectures is numbers and the scoring rule,
+not code: how many experts and how many a token (the router's
+shapes), `sum_eps` in the normalising sum, the activation and the
+scale. A shared expert, gated or not, is the model's own
+(`BlockSparseMoELM` adds an ungated one, `GatedDeltaMoELM` one with a
+sigmoid gate a token): every chip computes it whole.
 
 Two product paths, chosen by the row count (static): up to
 `dense_rows` rows every held expert runs over every row (one dense
@@ -46,11 +51,13 @@ def swigluoai(g, u, alpha=1.702, limit=7.0):
 
 
 def routed_experts(ffn, x, prec, *, held, experts_per_token,
-                   dense_rows=DENSE_ROWS, sum_eps=0.0, act=None, scale=1.0):
+                   dense_rows=DENSE_ROWS, sum_eps=0.0, act=None, scale=1.0,
+                   scoring="sigmoid"):
     """The held experts' part of a routed layer for x [N, d], and the
     held experts' assignment counts [count] (from which a step's three
     counters come: their sum, how many are not zero, their maximum).
-    `act` None is silu(g) * u; `scale` multiplies the held part."""
+    `act` None is silu(g) * u; `scale` multiplies the held part;
+    `scoring` is "sigmoid" (with the bias `ffn["b"]`) or "softmax"."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -59,11 +66,18 @@ def routed_experts(ffn, x, prec, *, held, experts_per_token,
     K = experts_per_token
     N = x.shape[0]
     all_held = first == 0 and E == ffn["W_r"].shape[-1]
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring {scoring!r}: sigmoid or softmax")
     with jax.named_scope("moe_router"):
-        sig = jax.nn.sigmoid(jnp.matmul(
+        logits = jnp.matmul(
             x.astype(jnp.float32), ffn["W_r"].astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        _, idx = lax.top_k(sig + ffn["b"], K)              # [N,K]
+            precision=lax.Precision.HIGHEST)
+        if scoring == "sigmoid":
+            sig = jax.nn.sigmoid(logits)
+            _, idx = lax.top_k(sig + ffn["b"], K)          # [N,K]
+        else:
+            sig = jax.nn.softmax(logits, -1)
+            _, idx = lax.top_k(sig, K)
         chosen = jnp.take_along_axis(sig, idx, -1)
         total = jnp.sum(chosen, -1, keepdims=True)
         if sum_eps:

@@ -41,8 +41,9 @@ from __future__ import annotations
 import numpy as np
 
 from .. import tensor
-from .drawn_lm import (RoutedDrawnLM, attend_cache, attend_prompts, dense_mlp,
-                       put_rows, rope)
+from .drawn_lm import (RoutedDrawnLM, attend_cache, attend_prompts,
+                       conv_columns, conv_prompt, conv_step, conv_tail,
+                       conv_tail_positions, dense_mlp, put_rows, rope)
 
 CONV, ATTN = "conv", "full_attention"
 
@@ -214,15 +215,6 @@ class ShortConvMoELM(RoutedDrawnLM):
                 h = h + dense_mlp(ffn, x, prec)
         return self._rms(h, params["ln_f"]), counters
 
-    def _conv_prompt(self, u, w):
-        """z_t = sum_j w[j] u_{t-(L-1)+j} over a prompt's positions
-        0..S-1 (u [B,S,d]; nothing before position 0)."""
-        import jax.numpy as jnp
-
-        L, S = self.conv_L, u.shape[1]
-        up = jnp.pad(u.astype(jnp.float32), ((0, 0), (L - 1, 0), (0, 0)))
-        return sum(w[j] * up[:, j:j + S] for j in range(L))
-
     def _head(self, params, h):
         import jax
         import jax.numpy as jnp
@@ -245,7 +237,7 @@ class ShortConvMoELM(RoutedDrawnLM):
         h, _ = self._stack(
             params, ids, pos,
             lambda li, q, k, v: attend_prompts(q, k, v, prec),
-            lambda li, u, w: self._conv_prompt(u, w))
+            lambda li, u, w: conv_prompt(u, w))
         return self._head(params, h)
 
     # -- the slab: states beside contexts ----------------------------------
@@ -311,21 +303,12 @@ class ShortConvMoELM(RoutedDrawnLM):
             return attend_cache(q[:, 0], k_all, v_all, pos, prec,
                                 values="bhdt")[:, None]
 
-        # column c of a row's state holds u_{p-(L-1)+j} for the one j
-        # with (p + j) mod (L-1) = c; the oldest's, j = 0, is where
-        # u_p goes. The taps are dealt to the columns by selects, so
-        # the state is read where it lies and written by one more
-        col = jnp.arange(self.conv_L - 1)[None, :]
-        tap = ((col - pos[:, None]) % (self.conv_L - 1))[:, :, None]
+        tap = conv_columns(pos, self.conv_L)
 
         def convolve(li, u, w):
-            state = slab[li]["u"]                           # [B, L-1, d]
-            new[li] = {"u": jnp.where(tap == 0, u, state)}
-            w_col = sum(jnp.where(tap == j, w[j], 0.0)
-                        for j in range(self.conv_L - 1))
-            z = (w_col * state.astype(jnp.float32)).sum(1) \
-                + w[-1] * u[:, 0].astype(jnp.float32)
-            return z[:, None]
+            z, state = conv_step(slab[li]["u"], u, w, tap)
+            new[li] = {"u": state}
+            return z
 
         h, counters = self._stack(params, tok[:, None], pos[:, None],
                                   attend, convolve)
@@ -346,11 +329,7 @@ class ShortConvMoELM(RoutedDrawnLM):
 
         prec = tensor.get_matmul_precision()
         Bp, Pb = ids.shape
-        # column r of a row's state <- the one position p in the last
-        # L-1 with p mod (L-1) = r
-        r = jnp.arange(self.conv_L - 1)[None, :]
-        last = n_real[:, None] - 1
-        back = last - (last - r) % (self.conv_L - 1)        # [Bp, L-1]
+        back = conv_tail_positions(n_real, self.conv_L)     # [Bp, L-1]
         new = [None] * len(slab)
 
         def attend(li, q, k, v):
@@ -361,11 +340,9 @@ class ShortConvMoELM(RoutedDrawnLM):
             return attend_prompts(q, k, v, prec)
 
         def convolve(li, u, w):
-            held = jnp.take_along_axis(
-                u, jnp.clip(back, 0, Pb - 1)[:, :, None], 1)
-            held = jnp.where((back >= 0)[:, :, None], held, 0)
-            new[li] = {"u": put_rows(slab[li]["u"], held, slots)}
-            return self._conv_prompt(u, w)
+            new[li] = {"u": put_rows(slab[li]["u"], conv_tail(u, back),
+                                     slots)}
+            return conv_prompt(u, w)
 
         pos = jnp.broadcast_to(jnp.arange(Pb), (Bp, Pb))
         h, _ = self._stack(params, ids, pos, attend, convolve)

@@ -1350,3 +1350,79 @@ def selected_blocks_attend(q, k, v, sel, n_sel, pos, block):
         interpret=_interpret(),
     )(sel.reshape(-1).astype(jnp.int32), n_sel.reshape(-1).astype(jnp.int32),
       pos.astype(jnp.int32), q, *([k] * G), *([v] * G))
+
+
+# -- one step of the gated delta rule a row and value head, in place ----------
+_GATED_DELTA_HEADS = 16     # value heads a grid step: 1 MiB of float32 states
+
+
+def _gated_delta_kernel(qk_ref, vgb_ref, s_ref, o_ref, s_out, *, heads):
+    """Grid step (b, j): value heads j*hb .. j*hb + hb - 1 of row b. A
+    head's rows of `qk` are q and k, of `vgb` v, exp(g) and beta (the
+    last two the same in every lane); its state S [Dk, Dv] crosses
+    VMEM once: S <- e S;  S <- S + k (x) beta (v - S^T k);  o = S^T q,
+    in float32 on the vector units. A row r [1, Dk] becomes the column
+    r^T [Dk, 1] by the identity's mask and a sum along the lanes, so
+    no operation moves data across the tile."""
+    dk = s_ref.shape[2]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+
+    def column(row):
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+    for h in range(heads):
+        qc = column(qk_ref[0, 2 * h:2 * h + 1, :])          # [Dk, 1]
+        kc = column(qk_ref[0, 2 * h + 1:2 * h + 2, :])
+        v = vgb_ref[0, 3 * h:3 * h + 1, :]                  # [1, Dv]
+        e = vgb_ref[0, 3 * h + 1:3 * h + 2, :]
+        beta = vgb_ref[0, 3 * h + 2:3 * h + 3, :]
+        s = s_ref[0, h].astype(jnp.float32) * e             # [Dk, Dv]
+        d = beta * (v - jnp.sum(s * kc, axis=0, keepdims=True))
+        s = s + kc * d
+        o_ref[0, h:h + 1, :] = jnp.sum(s * qc, axis=0, keepdims=True)
+        s_out[0, h] = s.astype(s_out.dtype)
+
+
+@jax.jit
+def gated_delta_step(q, k, v, g, beta, state):
+    """One position of the gated delta rule for every row b and value
+    head h: q, k [B, H, Dk] (q already l2-normed and scaled, k
+    l2-normed), v [B, H, Dv], g and beta [B, H] (float32), the states
+    `state` [B, H, Dk, Dv] (float32, or the dtype the slab holds them
+    in: read and written in it, computed in float32):
+
+        S <- exp(g) S;   S <- S + k (x) beta (v - S^T k);   o = S^T q
+
+    Returns (o [B, H, Dv] float32, the new states). The states are
+    updated IN PLACE: the result aliases `state` (donate it), and each
+    row's states cross VMEM once in and once out, `_GATED_DELTA_HEADS`
+    heads a grid step."""
+    B, H, Dk, Dv = state.shape
+    hb = min(_GATED_DELTA_HEADS, H)
+    if H % hb:
+        raise ValueError(f"gated_delta_step: {H} value heads do not divide "
+                         f"into blocks of {hb}")
+    f32 = jnp.float32
+    wide = jnp.ones((B, H, Dv), f32)
+    # a head's rows side by side: q and k, then v, exp(g) and beta
+    qk = jnp.stack([q.astype(f32), k.astype(f32)], 2).reshape(B, H * 2, Dk)
+    vgb = jnp.stack([v.astype(f32), jnp.exp(g.astype(f32))[..., None] * wide,
+                     beta.astype(f32)[..., None] * wide], 2).reshape(
+                         B, H * 3, Dv)
+    return pl.pallas_call(
+        functools.partial(_gated_delta_kernel, heads=hb),
+        out_shape=(jax.ShapeDtypeStruct((B, H, Dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
+        grid=(B, H // hb),
+        in_specs=[pl.BlockSpec((1, 2 * hb, Dk), lambda b, j: (b, j, 0)),
+                  pl.BlockSpec((1, 3 * hb, Dv), lambda b, j: (b, j, 0)),
+                  pl.BlockSpec((1, hb, Dk, Dv), lambda b, j: (b, j, 0, 0))],
+        out_specs=(pl.BlockSpec((1, hb, Dv), lambda b, j: (b, j, 0)),
+                   pl.BlockSpec((1, hb, Dk, Dv), lambda b, j: (b, j, 0, 0))),
+        input_output_aliases={2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=_interpret(),
+        name="gated_delta_step",
+    )(qk, vgb, state)

@@ -29,6 +29,9 @@ here for each form alike, at toy widths on the CPU:
                       value, not zero (the minimax cell); a query reads
                       block 0, its own and the best-scoring one, so the
                       rows past 12 positions drop blocks
+  gateddelta          `GatedDeltaMoELM`: float32 matrix states of the
+                      delta rule and convolution states, neither
+                      growing, beside contexts (the qwen3-next cell)
 
 A form that cannot meet a point says so as a skipped case, with the
 model's own reason. The next decode-tier model adds one row to FORMS.
@@ -39,6 +42,7 @@ import pytest
 from singa_tpu import device, serve, stats, tensor
 from singa_tpu.models.block_sparse_moe import BlockSparseMoELM
 from singa_tpu.models.chunked_attn import ChunkedAttnLM
+from singa_tpu.models.gated_delta_moe import GatedDeltaMoELM
 from singa_tpu.models.hybrid_moe import HybridWindowMoELM
 from singa_tpu.models.shortconv_moe import ShortConvMoELM
 from singa_tpu.models.transformer import TransformerLM
@@ -48,7 +52,7 @@ MAXLEN = 64
 WINDOW = 4
 FORMS = ["lm-layernorm-tied", "lm-rmsnorm-untied", "lm-int8",
          "hybrid-dense", "hybrid-sorted", "shortconv", "chunked",
-         "blocksparse"]
+         "blocksparse", "gateddelta"]
 
 
 @pytest.fixture(autouse=True)
@@ -71,19 +75,30 @@ class Form:
         # the models drawn on the device (`DrawnDecodeLM`): a slab of
         # more than one kind of entry, slots first in every leaf
         self.hybrid = name.startswith(("hybrid", "shortconv", "chunked",
-                                       "blocksparse"))
+                                       "blocksparse", "gateddelta"))
         self.int8 = name == "lm-int8"
         # what does not climb the ladder, what does, and how many
         # positions an entry of what does stands for
         self.fixed_kind = {"hybrid": "ring", "shortc": "state",
-                           "chunke": "window"}.get(name[:6])
+                           "chunke": "window", "gatedd": "state"}.get(
+                               name[:6])
         self.grows, self.per = (("summary", 2) if name == "chunked"
                                 else ("context", 1))
         # every kind the slab states, and the positions an entry of
         # each kind that climbs the ladder stands for
         self.kinds = {self.fixed_kind or "ring", self.grows}
         self.pers = {self.per}
-        if name == "blocksparse":
+        if name == "gateddelta":
+            self.kinds = {"recurrent", "state", "context"}
+            # heads of 12 and 8, 48 convolution channels: no axis but a
+            # rung's is 16 or 32
+            m = GatedDeltaMoELM(
+                V, d_model=48, num_layers=2, full_interval=2, num_heads=4,
+                kv_heads=2, head_dim=12, rotary_dim=4, lin_k_heads=2,
+                lin_v_heads=4, lin_k_dim=8, lin_v_dim=8, d_ff_expert=16,
+                d_ff_shared=16, n_experts=8, experts_per_token=2,
+                held=(2, 4), max_len=MAXLEN, init_std=0.3)
+        elif name == "blocksparse":
             self.kinds, self.pers = {"context", "blockkey"}, {1, 4}
             # d_model 48, heads of 12: no axis but a rung's is 16 or 32
             m = BlockSparseMoELM(

@@ -234,16 +234,40 @@ def _build_blocksparse(seed=3):
     return m
 
 
+def _build_gateddelta(seed=3):
+    """The Gated DeltaNet mixture-of-experts LM at a toy size: its tree
+    holds the decays' A_log and dt_bias, the convolution's taps, the
+    gated norm's weight, the router, the held experts and the gated
+    shared expert."""
+    from singa_tpu.models.gated_delta_moe import GatedDeltaMoELM
+
+    dev = device.get_default_device()
+    dev.SetRandSeed(seed)
+    m = GatedDeltaMoELM(
+        V, d_model=D, num_layers=2, full_interval=2, num_heads=4,
+        kv_heads=2, head_dim=8, rotary_dim=4, lin_k_heads=2, lin_v_heads=4,
+        lin_k_dim=8, lin_v_dim=8, d_ff_expert=16, d_ff_shared=16,
+        n_experts=8, experts_per_token=2, held=(2, 4), max_len=MAXLEN,
+        init_std=0.3)
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32),
+                                 device=dev)],
+              is_train=False, use_graph=False)
+    m.eval()
+    return m
+
+
 @pytest.mark.parametrize("norm,quant", [("layer", "off"),
                                         ("layer", "int8"),
                                         ("rms", "off"),
                                         ("hybrid", "off"),
                                         ("shortconv", "off"),
                                         ("chunked", "off"),
-                                        ("blocksparse", "off")])
+                                        ("blocksparse", "off"),
+                                        ("gateddelta", "off")])
 def test_warmed_engine_counts_no_host_leaf(norm, quant):
     drawn = {"hybrid": _build_hybrid, "shortconv": _build_shortconv,
-             "chunked": _build_chunked, "blocksparse": _build_blocksparse}
+             "chunked": _build_chunked, "blocksparse": _build_blocksparse,
+             "gateddelta": _build_gateddelta}
     m = drawn[norm]() if norm in drawn else _build(norm)
     prompt = np.array([[3, 1, 4]], np.int32)
     device.set_inference_quant(quant)

@@ -735,3 +735,73 @@ def test_minimax_programs_hold_the_slab_in_place(minimax, program,
         # would be 64 times that)
         assert not _whole_layer_moves(text, int(np.prod(slab[0]["k"].shape)))
         assert not re.search(r"f32\[1,4,512,16,(?:4096|32768)\]", text)
+
+
+# -- Qwen3-Next's served programs at their real widths --------------------------
+QWEN = os.path.join(os.path.dirname(CONFIG), "qwen3-next-80b-a3b.json")
+QW_SLOTS, QW_RUNG, QW_BUCKET = 256, 4096, 256
+
+
+@pytest.fixture(scope="module")
+def qwen(one_chip):
+    """`qwen3-next-serve-decode256`'s geometry: 256 slots on the 4,096
+    rung, prompts from the 256 bucket up."""
+    yield from _served(QWEN, QW_SLOTS, QW_RUNG, one_chip)
+
+
+def _large_moves(text, elements=4_000_000):
+    """(opcode, shape) of every `copy` or `transpose` of the program's
+    own (not inside a fusion) of at least `elements` elements: a whole
+    matrix state of a layer is 134 M, W_qkvz 25 M, a layer's context
+    268 M."""
+    body = text[text.index("\nENTRY"):]
+    out = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* "
+                         r"(copy|transpose)\(", body, re.M):
+        if int(np.prod([int(d) for d in m.group(2).split(",") if d])) \
+                >= elements:
+            out.append((m.group(3), f"{m.group(1)}[{m.group(2)}]"))
+    return out
+
+
+@pytest.mark.parametrize("program,temporaries", [
+    ("block2", 0.3e9), ("prefill1", 0.1e9)])
+def test_qwen_programs_hold_the_slab_in_place(qwen, program, temporaries,
+                                              monkeypatch):
+    """A run-ahead block of 2 steps and the prefill of one prompt in the
+    smallest bucket, 256 positions (the prefill of two in the largest,
+    2,048, compiles alike with 1.27 GB of temporaries), over 2.36 GB
+    of weights and the 7.59 GB slab (six layers' matrix states, 3.22
+    GB, and convolution states beside two layers' contexts): each fits
+    16 GB, the slab is aliased whole, and the temporaries are what is
+    stated (0.23 and 0.06 GB compiled for a described v5e; no chip, no
+    device metric). A decode program runs the delta rule through `gated_delta_step`, one
+    call a layer and step, and copies nothing large: no state, no
+    context, and no projection's matrix re-laid every step (gathering
+    q, k and v out of W_qkvz's key-head groups did that, 6 copies of
+    50 MB a step)."""
+    model, params, slab, sds = qwen
+    compiled = _engine_program(
+        model, params, slab, sds, monkeypatch, QW_SLOTS, program,
+        (int(program[7:]), QW_BUCKET) if program[:7] == "prefill" else None)
+    m = _fits(compiled, f"Qwen3-Next {program}")
+    by_kind = model.slab_bytes(slab)
+    assert by_kind == {"recurrent": 6 * 256 * 32 * 128 * 128 * 4,
+                       "state": 6 * 256 * 3 * 8192 * 2,
+                       "context": 2 * 2 * 256 * 2 * 256 * 4096 * 2}
+    assert m.alias_size_in_bytes >= sum(by_kind.values())
+    assert m.temp_size_in_bytes < temporaries, m.temp_size_in_bytes / 1e9
+    text = compiled.as_text()
+    if program[:7] == "prefill":
+        # the prompt's states written into its row in place: no
+        # layer's matrix states moved whole
+        assert not [mv for mv in _large_moves(text)
+                    if mv[1] == "f32[256,32,128,128]"]
+        return
+    calls = re.findall(r"^\s*%gated_delta_step\S* = .*$", text, re.M)
+    assert len(calls) == 6 * int(program[5:])
+    assert not _large_moves(text)
+    for scope in ("gdn_conv", "gdn_delta", "gdn_norm", "attn_full",
+                  "shared_expert", "moe_router", "moe_experts"):
+        assert re.search(rf'op_name="jit\(slot_\w+\)/[^"]*\b{scope}/',
+                         text), scope
